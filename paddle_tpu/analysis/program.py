@@ -155,7 +155,7 @@ def jaxpr_primitives(jaxpr) -> List[Tuple[str, dict]]:
 
 
 def _subjaxprs(v):
-    from jax.core import Jaxpr, ClosedJaxpr  # local: keep import cheap
+    from jax.extend.core import ClosedJaxpr, Jaxpr  # local: keep import cheap
 
     if isinstance(v, (Jaxpr, ClosedJaxpr)):
         yield v
@@ -275,10 +275,9 @@ def collect(target, args: Sequence[Any] = (), name: Optional[str] = None,
 
     if (jaxpr is None or jaxpr) and jaxpr_fn_args is not None:
         fn, fa = jaxpr_fn_args
-        try:
-            art.jaxpr_prims = jaxpr_primitives(jax.make_jaxpr(fn)(*fa))
-        except Exception:
-            art.jaxpr_prims = []
+        # the same fn/args just lowered, so a failure here is a broken
+        # walker, not an untraceable program: let it raise
+        art.jaxpr_prims = jaxpr_primitives(jax.make_jaxpr(fn)(*fa))
     return art
 
 

@@ -46,7 +46,9 @@ from . import rule
 _CAST_BUILTINS = {"float", "int", "bool"}
 _SYNC_METHODS = {"numpy", "item", "tolist"}
 _SYNC_NP_FUNCS = {"asarray", "array"}
-_CALLBACK_PRIMS = ("callback", "infeed", "outfeed", "device_get")
+# jax 0.9 lowers jax.debug.print to its own ``debug_print`` primitive
+_CALLBACK_PRIMS = ("callback", "debug_print", "infeed", "outfeed",
+                   "device_get")
 
 
 def _source_of(fn) -> Optional[str]:
@@ -168,7 +170,7 @@ def check_host_sync(art: ProgramArtifacts, config: dict) -> List[Finding]:
             findings.append(Finding(
                 rule="host-sync",
                 severity=Severity.WARNING,
-                subject=f"primitive {prim_name}",
+                subject=f"host callback primitive {prim_name}",
                 message=(f"traced program contains host callback "
                          f"{detail!r} — a device->host round trip baked "
                          "into the compiled step"),

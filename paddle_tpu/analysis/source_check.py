@@ -1,12 +1,11 @@
 """Repo-source AST check: every ``shard_map``/``pcast`` call site must
 route through the :mod:`paddle_tpu.framework.jax_compat` seam.
 
-The seam exists so ONE probe decides the jax 0.4/0.5 dialect
-(``check_rep`` vs ``check_vma``, ``auto=`` vs ``axis_names=``, pcast
-identity pre-VMA).  A direct ``jax.experimental.shard_map`` import
-anywhere else silently re-introduces the split the seam closed — an
-invariant that previously lived in review discipline (PR 1) and now in
-this machine check, part of the tier-1 ``analysis`` suite.
+The seam keeps the package's manual-SPMD defaults (``check_vma=False``)
+and its one private jax accessor in a single file, so the next jax
+surface change is a one-file edit.  A direct ``jax.shard_map`` call
+anywhere else silently takes jax's own defaults instead — an invariant
+this machine check holds as part of the tier-1 ``analysis`` suite.
 
 Flags, per file (excluding ``framework/jax_compat.py`` itself):
 
@@ -31,7 +30,7 @@ __all__ = ["check_jax_compat_seam", "check_source_text"]
 _SEAM_FILE = os.path.join("framework", "jax_compat.py")
 
 _FIX = ("route through paddle_tpu.framework.jax_compat "
-        "(shard_map / pcast) so the jax 0.4/0.5 dialect probe stays "
+        "(shard_map / pcast) so the manual-SPMD defaults stay "
         "single-homed")
 
 
@@ -56,7 +55,7 @@ class _SeamVisitor(ast.NodeVisitor):
             severity=Severity.ERROR,
             subject=what,
             message=(f"direct {what} bypasses the framework/jax_compat "
-                     "version seam"),
+                     "seam"),
             fix=_FIX,
             source=f"{self.relpath}:{getattr(node, 'lineno', 0)}",
         ))

@@ -22,20 +22,27 @@ Wired through ``jit.TrainStep(persistent_cache=...)`` /
 so a relaunched child's first step deserializes its executable instead of
 re-invoking XLA (checkpoint load + trace time, not compile time).
 
-Env: ``PADDLE_TPU_COMPILE_CACHE`` (root, default ``~/.cache/paddle_tpu/xla``),
+One resolver places every cache (:func:`cache_dir`):
+``JAX_COMPILATION_CACHE_DIR`` when set, else ``.compile_cache/`` in the
+checkout.  :func:`enable_persistent_cache` points JAX's own persistent
+compilation cache there, so the serving and ``generate()`` programs are
+cached too; the AOT store sits in ``aot/`` beneath it.
+
+Env: ``PADDLE_TPU_COMPILE_CACHE`` (explicit AOT root),
 ``PADDLE_TPU_COMPILE_CACHE_MAX`` (disk LRU entries, default 32),
 ``PADDLE_TPU_JIT_CACHE_MAX`` (in-process LRU entries, default 64).
 """
 
-from .aot import (AOTFunction, fingerprint, resolve_cache,  # noqa: F401
-                  serialization_safe)
-from .cache import ExecutableCache, default_root  # noqa: F401
+from .aot import AOTFunction, fingerprint, resolve_cache  # noqa: F401
+from .cache import (ExecutableCache, PersistentCacheStats,  # noqa: F401
+                    cache_dir, default_root, enable_persistent_cache)
 from .metrics import (compile_begin, compile_end,  # noqa: F401
                       compile_info_detail, crosscheck_stepmeter, flops_of)
 
 __all__ = [
-    "AOTFunction", "fingerprint", "resolve_cache", "serialization_safe",
-    "ExecutableCache", "default_root",
+    "AOTFunction", "fingerprint", "resolve_cache",
+    "ExecutableCache", "default_root", "cache_dir",
+    "enable_persistent_cache", "PersistentCacheStats",
     "flops_of", "compile_begin", "compile_end", "crosscheck_stepmeter",
     "compile_info_detail",
 ]

@@ -35,7 +35,6 @@ import hashlib
 import json
 import os
 import pickle
-import re
 import time
 from typing import Any, Callable, Dict, Optional
 
@@ -44,8 +43,7 @@ import jax
 from . import metrics
 from .cache import ExecutableCache
 
-__all__ = ["fingerprint", "AOTFunction", "resolve_cache",
-           "serialization_safe"]
+__all__ = ["fingerprint", "AOTFunction", "resolve_cache"]
 
 
 def fingerprint(stablehlo_text: str, extras: Optional[Dict[str, Any]] = None,
@@ -92,33 +90,6 @@ def fingerprint(stablehlo_text: str, extras: Optional[Dict[str, Any]] = None,
     h.update(stablehlo_text.encode())
     h.update(json.dumps(env, sort_keys=True, default=repr).encode())
     return h.hexdigest()[:32]
-
-
-_PROGRAM_SPAN_RE = re.compile(
-    r"mhlo\.num_(?:partitions|replicas) = (\d+)")
-
-
-def serialization_safe(stablehlo_text: str, devices=None) -> bool:
-    """Whether executable serialization round-trips safely for THIS
-    program. On the CPU backend, a MULTI-device program (the
-    8-virtual-device test mesh: ``mhlo.num_partitions > 1`` in the
-    lowered module) has been observed to segfault inside jaxlib 0.4.36
-    when chained deserialized executables hand donated sharded state to
-    each other — a crash no try/except can catch, so the AOT service
-    degrades those programs to always-cold rather than risk the process.
-    Single-device programs (even on a multi-device backend) and real
-    accelerator platforms are unaffected.
-    ``PADDLE_TPU_AOT_CPU_MULTIDEVICE=1`` force-enables for debugging."""
-    if devices is None:
-        devices = jax.devices()
-    if devices[0].platform != "cpu":
-        return True
-    span = max((int(m) for m in _PROGRAM_SPAN_RE.findall(stablehlo_text)),
-               default=1)
-    if span > 1:
-        return os.environ.get("PADDLE_TPU_AOT_CPU_MULTIDEVICE",
-                              "0") in ("1", "true")
-    return True
 
 
 def resolve_cache(persistent_cache) -> Optional[ExecutableCache]:
@@ -211,18 +182,13 @@ class AOTFunction:
         fp = fingerprint(text, extras=self._resolved_extras())
         metrics.compile_begin(self._name, fp)
 
-        persist_ok = self._cache is not None and serialization_safe(text)
-        if self._cache is not None and not persist_ok:
-            metrics.cache_event("serialization_unsafe_topology",
-                                fingerprint=fp, program=self._name)
-        compiled = self._try_deserialize(fp) if persist_ok else None
+        compiled = self._try_deserialize(fp, lowered)
         persisted = None
         remats = None
         if compiled is None:
             mode = "cold"
             compiled, remats = self._compile_with_diagnostics(lowered)
-            persisted = self._try_serialize(fp, compiled) if persist_ok \
-                else False
+            persisted = self._try_serialize(fp, compiled)
         else:
             mode = "warm"
         seconds = time.perf_counter() - t0
@@ -269,9 +235,12 @@ class AOTFunction:
         except Exception:
             return compiled, None
 
-    def _try_deserialize(self, fp: str):
+    def _try_deserialize(self, fp: str, lowered):
         """Warm path: payload → (exe bytes, in_tree, out_tree) →
-        executable. Any failure drops the entry and falls back cold."""
+        executable, loaded onto the devices THIS program was lowered for
+        (without ``execution_devices`` jax loads it as spanning every
+        local device, and a one-device program then refuses its
+        arguments). Any failure drops the entry and falls back cold."""
         if self._cache is None:
             return None
         blob = self._cache.get(fp)
@@ -281,7 +250,11 @@ class AOTFunction:
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = pickle.loads(blob)
-            return se.deserialize_and_load(payload, in_tree, out_tree)
+            # jax exposes the device assignment of a Lowered nowhere
+            # public; it is the list compile() itself would use
+            devices = list(lowered._lowering._device_list)
+            return se.deserialize_and_load(payload, in_tree, out_tree,
+                                           execution_devices=devices)
         except Exception as e:
             self._cache.drop(fp, reason=f"deserialize: {e!r:.120}")
             return None

@@ -26,7 +26,8 @@ Retention is LRU over at most ``max_entries`` entries (env
 ``PADDLE_TPU_COMPILE_CACHE_MAX``, default 32; executables for a 7B model
 run hundreds of MB, so the cap is bytes-motivated). ``get`` refreshes an
 entry's mtime; ``put`` evicts the stalest sidecars past the cap. Cache
-root: ``PADDLE_TPU_COMPILE_CACHE`` (default ``~/.cache/paddle_tpu/xla``).
+root: :func:`default_root` — ``aot/`` under the one compile-cache
+directory :func:`cache_dir` resolves.
 """
 
 from __future__ import annotations
@@ -36,16 +37,78 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["ExecutableCache", "default_root"]
+__all__ = ["ExecutableCache", "default_root", "cache_dir",
+           "enable_persistent_cache", "PersistentCacheStats"]
 
 _DEFAULT_MAX_ENTRIES = 32
 _PAYLOAD_EXT = ".xbin"
 _SIDECAR_EXT = ".json"
 
 
+# the checkout: the directory that holds the ``paddle_tpu`` package
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def cache_dir() -> str:
+    """The one directory every compile cache of this program lives under.
+
+    ``JAX_COMPILATION_CACHE_DIR`` when set: the cache is then placed from
+    outside (the chip tool's machine may come with it set so that one
+    call's compiles are found by the next) and no code names another
+    directory.  Otherwise a fixed, git-ignored path inside the checkout —
+    fixed, never a temp dir, pid or timestamp, because a cache that moves
+    never hits."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        os.path.join(_CHECKOUT, ".compile_cache")
+
+
 def default_root() -> str:
+    """Root of the AOT :class:`ExecutableCache`: ``aot/`` under
+    :func:`cache_dir`, beside JAX's own persistent-cache entries.
+    ``PADDLE_TPU_COMPILE_CACHE`` names another root explicitly (the
+    Supervisor hands one to its relaunches; the test suite isolates each
+    run with it)."""
     return os.environ.get("PADDLE_TPU_COMPILE_CACHE") or \
-        os.path.expanduser(os.path.join("~", ".cache", "paddle_tpu", "xla"))
+        os.path.join(cache_dir(), "aot")
+
+
+class PersistentCacheStats:
+    """Hit / miss counts of JAX's persistent compilation cache since
+    :func:`enable_persistent_cache` returned this object."""
+
+    def __init__(self, directory: str):
+        self.dir = directory
+        self.hits = 0
+        self.misses = 0
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def as_dict(self) -> Dict[str, Any]:
+        return {"dir": self.dir, "hits": self.hits, "misses": self.misses}
+
+
+def enable_persistent_cache() -> PersistentCacheStats:
+    """Turn on JAX's persistent compilation cache under :func:`cache_dir`
+    for EVERY program this process compiles — the serving and
+    ``generate()`` programs as much as the train step.  Call before the
+    first compile.  Every compile is kept (no minimum compile time or
+    size): on the chip a cold start is dominated by many mid-sized
+    programs, not one large one."""
+    import jax
+
+    directory = cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    stats = PersistentCacheStats(directory)
+    jax.monitoring.register_event_listener(stats._on_event)
+    return stats
 
 
 def _storage():

@@ -22,7 +22,7 @@ on the main thread instead of dying with the daemon writer, and
 - :func:`gc_checkpoints` — keep-N retention sweep;
 - :func:`is_committed` — commit-marker check for one directory;
 - :class:`CheckpointError` / :class:`CheckpointCorruptionError` /
-  :class:`AsyncSaveError` — the failure taxonomy loads/saves raise.
+  :class:`AsyncSaveError` — the failure classes loads/saves raise.
 """
 
 from . import faults  # noqa: F401  (fault-injection API: faults.inject(...))

@@ -1,4 +1,4 @@
-"""Checkpoint error taxonomy.
+"""Checkpoint error classes.
 
 Failure modes get distinct, catchable types with actionable messages
 (the reference surfaces half-written checkpoints as raw ``pickle``

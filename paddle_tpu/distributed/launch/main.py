@@ -49,9 +49,13 @@ relaunch instead of first-failure pod teardown, because a lease-routed
 frontend fences a dead replica and replays its work on survivors while
 the relaunch (new fencing epoch) takes new traffic.
 
-On TPU the normal deployment is ONE process per host owning all local chips
+On TPU the deployment is ONE process per host owning all local chips
 (`--nproc_per_node 1`, the default); multi-process-per-host is used by the
-CPU "fake cluster" tests."""
+CPU "fake cluster" tests.  It cannot run on a chip host today: children
+are not pinned to devices, the first one to initialise JAX takes every
+local chip and the second finds none.  That includes ``--mode serve`` with
+several replicas per host — ROADMAP R3 (one process driving one replica
+per device) is the design that runs on chips."""
 
 from __future__ import annotations
 
@@ -222,6 +226,12 @@ class _PodWatch:
 def launch(argv=None) -> int:
     args = _parse(argv if argv is not None else sys.argv[1:])
     nproc = args.nproc_per_node
+    # every child (train rank or serving replica) compiles into the one
+    # placed cache: jax reads this variable at import, and a directory
+    # set from outside wins
+    from ...compile.cache import cache_dir
+
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir())
     # SDC quarantine (exclude-list relaunch): the FleetSupervisor exports
     # the physical slots it quarantined; this launcher skips them and the
     # surviving slots get DENSE ranks 0..world-1 — downstream the

@@ -1,19 +1,11 @@
-"""Version compatibility for jax APIs this codebase targets.
+"""The one home for the jax manual-SPMD surface this codebase uses
+(jax 0.9: ``jax.shard_map`` with ``check_vma``/``axis_names``,
+``jax.lax.pcast``, the tracing axis environment).
 
-The code is written against jax >= 0.5 (`jax.shard_map` with
-``check_vma``/``axis_names``, `jax.lax.pcast` VMA casts). On older jax
-(0.4.x) the same machinery lives in ``jax.experimental.shard_map`` with a
-different surface:
-
-- ``check_vma`` was named ``check_rep`` (we always pass False: the bodies
-  here use collectives the checker cannot type);
-- partial-manual ``axis_names={...}`` is expressed inversely via
-  ``auto=<the other axes>``;
-- ``pcast`` does not exist — pre-VMA tracing has no varying/manual
-  distinction, so the cast is the identity.
-
-Every shard_map/pcast call site in the package routes through here so one
-probe decides the dialect.
+Every shard_map/pcast call site in the package routes through here
+(``analysis/source_check.py`` enforces it), so the package's defaults —
+``check_vma=False``: the bodies here use collectives the checker cannot
+type — and its single use of a private jax accessor live in one file.
 """
 
 from __future__ import annotations
@@ -27,25 +19,13 @@ __all__ = ["shard_map", "pcast", "bound_axis_names"]
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False,
               axis_names: Optional[Set[str]] = None):
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def pcast(x, axes, to: str = "varying"):
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to=to)
-    return x  # pre-VMA jax: nothing to cast
+    return jax.lax.pcast(x, axes, to=to)
 
 
 def bound_axis_names() -> Set[str]:
@@ -53,23 +33,7 @@ def bound_axis_names() -> Set[str]:
     (empty when tracing/running outside one). The overlap layer uses this to
     refuse a nested shard_map — e.g. a TP layer invoked inside the compiled
     pipeline engine's manual "pipe" region, where opening a second manual
-    region would fail at trace time. Probes are version-layered like the
-    rest of this module; an unknown jax surface reports *no* axes (the
-    caller then behaves as it did before this seam existed)."""
-    try:  # jax >= 0.5 keeps an axis-env accessor on the public core
-        env = jax.core.get_axis_env()
-        return set(getattr(env, "axis_sizes", {}).keys())
-    except Exception:
-        pass
-    try:  # jax 0.4.x
-        from jax._src.core import get_axis_env
+    region would fail at trace time."""
+    from jax._src.core import get_axis_env  # no public accessor in jax 0.9
 
-        return set(get_axis_env().axis_sizes.keys())
-    except Exception:
-        pass
-    try:
-        from jax._src.core import unsafe_get_axis_names
-
-        return set(unsafe_get_axis_names())
-    except Exception:
-        return set()
+    return set(get_axis_env().axis_sizes)

@@ -84,7 +84,8 @@ def cached_attention(q, k_new, v_new, cache_k, cache_v, pos, pad_lens=None):
                 # path; the shard-local kernel wrapper is future work
                 kernel_fallback("decode_attention", "mesh", cache_len=C)
             elif blk is not None and decode_attention_supported(
-                    q.shape, cache_k.shape, block_k=blk):
+                    q.shape, cache_k.shape, block_k=blk,
+                    dtype=cache_k.dtype):
                 return decode_attention(q, k_new, v_new, cache_k, cache_v,
                                         pos, pad_lens, block_k=blk,
                                         interpret=interp)

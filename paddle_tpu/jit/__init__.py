@@ -797,7 +797,7 @@ class TrainStep:
             key = next_key()
         return (param_arrays, states, buffer_arrays, key, lr, batch_arrays)
 
-    def lower(self, *batch):
+    def lower(self, *batch, lowering_platforms=None):
         """AOT-lower the fused step program at these example batch
         shapes WITHOUT executing or compiling it — the entry point the
         static linter (:mod:`paddle_tpu.analysis`) and ahead-of-time
@@ -805,13 +805,20 @@ class TrainStep:
         and sharding pins of the step's own compiled variant (it IS the
         same jit object), so what the linter sees is what runs. Uses a
         fixed PRNG key (key VALUES never affect lowering) so a lint/
-        inspection pass does not advance the training RNG stream."""
+        inspection pass does not advance the training RNG stream.
+
+        ``lowering_platforms=("tpu",)`` cross-lowers from a host without
+        the chip: the Pallas -> Mosaic lowering runs and refuses a block
+        spec the TPU cannot take (tests/test_tpu_lowering.py)."""
         args = self._marshal_args(batch, key=jax.random.PRNGKey(0))
         target = self._compiled
         # unwrap the AOT service: AOTFunction.lower delegates, but going
         # straight to the jit object keeps this free of cache effects
         jitted = getattr(target, "_jitted", target)
-        return jitted.lower(*args)
+        if lowering_platforms is None:
+            return jitted.lower(*args)
+        return jitted.trace(*args).lower(
+            lowering_platforms=tuple(lowering_platforms))
 
     def __call__(self, *batch) -> Tensor:
         from ..framework.flags import get_flags

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
@@ -9,10 +11,9 @@ import jax
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialise raises here: it must not read as
+    # "not a TPU, take the XLA path"
+    return jax.devices()[0].platform == "tpu"
 
 
 def pallas_interpret_mode() -> bool:
@@ -23,24 +24,50 @@ def pallas_interpret_mode() -> bool:
     return bool(get_flags("pallas_interpret")["pallas_interpret"])
 
 
+_gspmd_trace: contextvars.ContextVar = contextvars.ContextVar(
+    "paddle_tpu_gspmd_trace", default=False)
+
+
+@contextlib.contextmanager
+def gspmd_program():
+    """Trace scope for a program GSPMD will partition over a mesh that is
+    NOT the hybrid mesh (``ServingEngine``'s TP / CP meshes).  GSPMD cannot
+    partition a Mosaic custom call and no shard_map wrapper knows that
+    mesh, so inside the scope every kernel takes the partitionable XLA path
+    and says so with a ``kernel_fallback(<flag>, "no_mesh")`` event."""
+    token = _gspmd_trace.set(True)
+    try:
+        yield
+    finally:
+        _gspmd_trace.reset(token)
+
+
 def pallas_eligible(flag_name: str) -> bool:
-    """True when the Pallas path should be used: TPU backend (multi-chip
-    composes through the shard_map wrappers in ``ops/sharded.py`` and
-    therefore needs a live hybrid mesh — without one, a bare Mosaic custom
-    call would land in a GSPMD program that cannot partition it, so we fall
-    back to the partitionable XLA path) or interpreter mode forced, and the
-    flag is on."""
+    """True when the Pallas path should be used: the flag is on and either
+    the backend is a TPU or interpreter mode is forced.
+
+    With a live hybrid mesh the kernels compose through the shard_map
+    wrappers in ``ops/sharded.py``; with none, the program being traced is
+    a one-device program (plain ``TrainStep`` / ``generate()`` /
+    ``ServingEngine``) however many chips the host holds, and it gets the
+    local kernel — the same program on a one-chip and a four-chip host.
+    A partitioned program built outside the hybrid mesh declares itself
+    with :func:`gspmd_program`; one that does not is refused by jax at
+    lowering ("Mosaic kernels cannot be automatically partitioned"), never
+    run quietly without its kernels."""
     from ..framework.flags import get_flags
 
-    if _on_tpu():
-        if len(jax.devices()) > 1:
-            from .sharded import active_mesh
-
-            if active_mesh() is None:
-                return False
-    elif not pallas_interpret_mode():
+    interpret = pallas_interpret_mode()
+    if not interpret and not _on_tpu():
         return False
-    return bool(get_flags(flag_name)[flag_name])
+    if not get_flags(flag_name)[flag_name]:
+        return False
+    if _gspmd_trace.get() and not interpret:  # interpreted kernels are jnp
+        from ..telemetry import kernel_fallback
+
+        kernel_fallback(flag_name, "no_mesh")
+        return False
+    return True
 
 
 def pallas_mode(flag_name: str):

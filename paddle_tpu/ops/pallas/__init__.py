@@ -19,26 +19,10 @@ def sds_like(shape, dtype, like):
     vma set is empty and this degrades to a plain ShapeDtypeStruct."""
     import jax
 
-    try:
-        vma = getattr(jax.typeof(like), "vma", None)
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except Exception:
-        pass
+    vma = jax.typeof(like).vma
+    if vma:
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def tpu_compiler_params(**kwargs):
-    """Version seam for the pallas TPU compiler-params class: jax >= 0.5
-    calls it ``pltpu.CompilerParams``; 0.4.x named it
-    ``TPUCompilerParams`` (same fields). Every kernel's pallas_call routes
-    through here so one probe decides the dialect (the jax_compat
-    pattern)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
 
 
 from .flash_attention import (flash_attention, flash_attention_supported,

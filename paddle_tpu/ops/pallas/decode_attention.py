@@ -8,9 +8,9 @@ XLA einsum path (`generation.cached_attention`) is numerically fine but its
 `dynamic_update_slice` inside the decode scan materializes a full copy of
 the cache every step (measured ~1.6 ms at 8K context on v5e — the 0.576 MBU
 ceiling in BENCH_r05).  Here the cache arrays are passed through
-``input_output_aliases``: the kernel writes exactly ONE ``block_k`` block
-back (the block containing ``pos``) and the rest of the aliased HBM buffer
-is never touched, so the compiled scan keeps the cache resident in place.
+``input_output_aliases``: the kernel writes exactly the new token's rows
+back and the rest of the aliased HBM buffer is never touched, so the
+compiled scan keeps the cache resident in place.
 
 Shape contract (paddle flash-attn layout):
 
@@ -25,14 +25,25 @@ Shape contract (paddle flash-attn layout):
 Returns ``(out [b, 1, h, d], new_cache_k, new_cache_v)`` where the new
 caches alias the inputs.
 
-Kernel structure: grid ``(b, kv, C // block_k)``; the GQA head group
-(``g = h // kv`` query rows, zero-padded to >= 8 sublanes) runs the
-online-softmax loop over cache blocks in f32 scratch, folds the NEW token's
-score in at the last block (the cache block content at ``pos`` is stale and
-masked with ``col < pos``), and the block containing ``pos`` is copied
-through VMEM once with the new row inserted — that copy is one block, not
-the cache.  ``pos``/``pad_lens`` ride scalar prefetch so the output block
-index map can target the append block dynamically.
+Kernel structure (``decode_attention``): the cache is viewed as
+``[b, C*kv, d]`` — rows ordered (col, kv head), a free reshape because a
+whole number of ``(kv, d)`` tiles sits under every col — and streamed in
+``(block_k*kv, d)`` blocks on a ``(b, C // block_k)`` grid.  Mosaic cannot
+block a single head out of the second-minor ``kv`` axis (the block's last
+two dims must be tile-divisible or whole), so every block carries ALL kv
+heads and the per-head structure is a mask, not a slice: one
+``[h, d] x [d, block_k*kv]`` matmul scores every query head against every
+(col, kv head) row and an additive group bias keeps only the rows of the
+head's own kv group.  The online-softmax loop runs in f32 scratch, folds
+the NEW token's score in at the last block (the cache content at ``pos``
+is stale and masked with ``col < pos``), and the append writes just the
+new token's ``(kv, d)`` row group through the aliased output.
+``pos``/``pad_lens`` ride scalar prefetch so the output block index map can
+target the append rows dynamically.
+
+The int8 / fp8 variants below keep the older one-head-per-program block
+``(1, block_k, 1, d)``, which the TPU lowering refuses for ``kv > 1``; they
+have no product caller (ROADMAP D2) and run in interpret mode only.
 
 No VJP: decode runs under ``no_grad`` by construction.
 """
@@ -44,8 +55,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from . import tpu_compiler_params
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,11 +66,14 @@ _MIN_SUBLANES = 8
 
 DEFAULT_BLOCK_K = 256
 
+# K and V blocks, double-buffered, plus the f32 score panel and the group
+# bias must fit the 16 MiB scoped-VMEM default with room for the compiler
+_VMEM_BUDGET = 12 * 1024 * 1024
 
-def decode_attention_supported(q_shape, cache_shape, *,
-                               block_k: int = DEFAULT_BLOCK_K) -> bool:
-    """Shapes the decode kernel handles; callers fall back to the XLA
-    grouped-einsum path (``generation.cached_attention``) otherwise."""
+
+def _decode_shape_ok(q_shape, cache_shape, block_k: int) -> bool:
+    """Shape algebra every decode variant shares (single query, matching
+    head dims, GQA divisibility, cache tiled by ``block_k``)."""
     if len(q_shape) != 4 or len(cache_shape) != 4:
         return False
     b, s, h, d = q_shape
@@ -69,11 +83,39 @@ def decode_attention_supported(q_shape, cache_shape, *,
             and C >= block_k and C % block_k == 0)
 
 
-def _decode_kernel(pos_ref, pad_ref, q_ref, kn_ref, vn_ref, ck_ref, cv_ref,
+def _sublane_rows(dtype) -> int:
+    """Rows of one native VMEM tile for ``dtype`` (f32 8, bf16 16, 8-bit
+    32): narrower types pack more rows per sublane."""
+    return _MIN_SUBLANES * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def decode_attention_supported(q_shape, cache_shape, *,
+                               block_k: int = DEFAULT_BLOCK_K,
+                               dtype=jnp.bfloat16) -> bool:
+    """Shapes the decode kernel handles; callers fall back to the XLA
+    grouped-einsum path (``generation.cached_attention``) otherwise.
+    Beyond the shared shape algebra the TPU lowering needs ``kv`` to fill
+    whole sublane tiles of the cache ``dtype`` (the ``[b, C*kv, d]`` view
+    is then a bitcast and the append block ``(kv, d)`` is tile-aligned),
+    and the streamed blocks must fit scoped VMEM."""
+    if not _decode_shape_ok(q_shape, cache_shape, block_k):
+        return False
+    _, _, h, d = q_shape
+    kv = cache_shape[2]
+    if kv % _sublane_rows(dtype) != 0:
+        return False
+    n = block_k * kv
+    hp = max(h, _MIN_SUBLANES)
+    vmem = 4 * n * d * jnp.dtype(dtype).itemsize + 3 * hp * n * 4
+    return vmem <= _VMEM_BUDGET
+
+
+def _decode_kernel(pos_ref, pad_ref, q_ref, knh_ref, vnh_ref, kn_ref, vn_ref,
+                   bias_ref, col_ref, ck_ref, cv_ref,
                    o_ref, cko_ref, cvo_ref, acc_ref, m_ref, l_ref, *,
                    scale: float, block_k: int):
-    ib, ik = pl.program_id(0), pl.program_id(2)
-    nk = pl.num_programs(2)
+    ib, ik = pl.program_id(0), pl.program_id(1)
+    nk = pl.num_programs(1)
     pos = pos_ref[0]
     pad = pad_ref[ib]
 
@@ -86,9 +128,10 @@ def _decode_kernel(pos_ref, pad_ref, q_ref, kn_ref, vn_ref, ck_ref, cv_ref,
     def _bcast(col):
         return jnp.broadcast_to(col, (col.shape[0], _LANES))
 
-    def _online(s_col, v_rows):
-        """Fold a masked score panel ``s_col`` (g, n) with values ``v_rows``
-        (n, d) into the running (m, l, acc) online-softmax state."""
+    def _online(s_col, pv_of):
+        """Fold a masked score panel ``s_col`` (hp, n) into the running
+        (m, l, acc) online-softmax state; ``pv_of(p)`` is the panel's
+        (hp, d) value contribution for probabilities ``p``."""
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
         m_cur = jnp.max(s_col, axis=1, keepdims=True)
@@ -102,43 +145,49 @@ def _decode_kernel(pos_ref, pad_ref, q_ref, kn_ref, vn_ref, ck_ref, cv_ref,
         alpha = jnp.exp(m_prev - m_ok)
         l_ref[:] = _bcast(l_prev * alpha + jnp.sum(p, axis=1, keepdims=True))
         m_ref[:] = _bcast(m_new)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v_rows.dtype), v_rows, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * alpha + pv_of(p)
 
     # cache cols live in this block iff any col satisfies pad <= col < pos
     @pl.when((ik * block_k < pos) & ((ik + 1) * block_k > pad))
     def _attend():
-        q = q_ref[0, 0]                                # (g, d)
-        k = ck_ref[0, :, 0, :]                         # (block_k, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        k = ck_ref[0]                                  # (block_k*kv, d)
+        v = cv_ref[0]
+        s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        col = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where((col < pos) & (col >= pad), s, _NEG_INF)
-        _online(s, cv_ref[0, :, 0, :])
+        col = ik * block_k + col_ref[...]              # (1, block_k*kv)
+        # bias is 0 on the head's own kv group and -inf elsewhere
+        s = jnp.where((col < pos) & (col >= pad), s + bias_ref[...],
+                      _NEG_INF)
+        _online(s, lambda p: jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
 
     # the NEW token (always valid: it is being written at ``pos``) folds in
-    # at the last block, then the output row finalizes
+    # at the last block, then the output row finalizes and the append
+    # writes the token's (kv, d) row group through the aliased buffer
     @pl.when(ik == nk - 1)
     def _finalize():
-        q = q_ref[0, 0]
-        kn = kn_ref[0, 0]                              # (1, d) sublane row
-        s_new = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-        _online(s_new, vn_ref[0, 0])                   # (g, 1) x (1, d)
-        l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        # knh/vnh are k_new/v_new expanded to one row per QUERY head, so
+        # the single-column fold is elementwise (no kv-wide matmul)
+        s_new = jnp.sum(q_ref[0].astype(jnp.float32)
+                        * knh_ref[0].astype(jnp.float32),
+                        axis=1, keepdims=True) * scale  # (hp, 1)
+        vnh = vnh_ref[0].astype(jnp.float32)
+        _online(s_new, lambda p: p * vnh)
+        o_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+        cko_ref[0] = kn_ref[0].astype(cko_ref.dtype)
+        cvo_ref[0] = vn_ref[0].astype(cvo_ref.dtype)
 
-    # in-place append: only the block containing ``pos`` streams through
-    # VMEM and back; every other block of the aliased buffer is untouched
-    @pl.when(ik == pos // block_k)
-    def _append():
-        row = pos % block_k
-        cko_ref[0, :, 0, :] = ck_ref[0, :, 0, :]
-        cvo_ref[0, :, 0, :] = cv_ref[0, :, 0, :]
-        cko_ref[0, pl.ds(row, 1), 0, :] = kn_ref[0, 0].astype(cko_ref.dtype)
-        cvo_ref[0, pl.ds(row, 1), 0, :] = vn_ref[0, 0].astype(cvo_ref.dtype)
+
+def _per_head(x, g: int, hp: int):
+    """[b, 1, kv, d] → [b, hp, d]: each kv row repeated for the ``g``
+    query heads of its group, zero-padded to ``hp`` rows."""
+    b, _, kv, d = x.shape
+    xh = jnp.repeat(x[:, 0], g, axis=1)
+    if hp != kv * g:
+        xh = jnp.concatenate(
+            [xh, jnp.zeros((b, hp - kv * g, d), xh.dtype)], axis=1)
+    return xh
 
 
 def decode_attention(q, k_new, v_new, cache_k, cache_v, pos,
@@ -150,88 +199,100 @@ def decode_attention(q, k_new, v_new, cache_k, cache_v, pos,
     _, C, kv, _ = cache_k.shape
     assert s == 1, "decode kernel is single-query (s == 1)"
     g = h // kv
-    gp = max(g, _MIN_SUBLANES)
+    hp = max(h, _MIN_SUBLANES)
+    n = block_k * kv
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
+    cdt = cache_k.dtype
 
-    # [b, 1, h, d] -> [b, kv, gp, d]: head index = ikv * g + ig (the grouped
-    # layout of cached_attention's einsum); pad the group to >= 8 sublanes
-    q4 = q.reshape(b, kv, g, d)
-    if gp != g:
-        q4 = jnp.concatenate(
-            [q4, jnp.zeros((b, kv, gp - g, d), q4.dtype)], axis=2)
-    kn3 = jnp.transpose(k_new, (0, 2, 1, 3))           # [b, kv, 1, d]
-    vn3 = jnp.transpose(v_new, (0, 2, 1, 3))
+    # query heads as rows, in the cache dtype (the einsum path casts too);
+    # rows past h are zero and sliced away
+    q3 = q[:, 0].astype(cdt)
+    if hp != h:
+        q3 = jnp.concatenate([q3, jnp.zeros((b, hp - h, d), cdt)], axis=1)
+    knh, vnh = _per_head(k_new, g, hp), _per_head(v_new, g, hp)
+    kn3, vn3 = k_new[:, 0], v_new[:, 0]                # [b, kv, d]
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
     pad_arr = (jnp.zeros((b,), jnp.int32) if pad_lens is None
                else jnp.asarray(pad_lens, jnp.int32).reshape(b))
+    # block row r is (col r // kv, kv head r % kv); query head i belongs
+    # to kv group i // g (padded rows reuse the last group: finite, unused)
+    rows = np.arange(n)
+    group = np.minimum(np.arange(hp) // g, kv - 1)
+    bias = jnp.asarray(np.where(group[:, None] == rows[None, :] % kv,
+                                0.0, _NEG_INF), jnp.float32)
+    col = jnp.asarray((rows // kv)[None, :], jnp.int32)
+    ck2 = cache_k.reshape(b, C * kv, d)
+    cv2 = cache_v.reshape(b, C * kv, d)
 
     nk = C // block_k
     kernel = functools.partial(_decode_kernel, scale=sc, block_k=block_k)
-    grid = (b, kv, nk)
+
+    def per_row(ib, ik, pos_r, pad_r):
+        return (ib, 0, 0)
+
+    def const(ib, ik, pos_r, pad_r):
+        return (0, 0)
+
+    def stream(ib, ik, pos_r, pad_r):
+        return (ib, ik, 0)
+
+    def append(ib, ik, pos_r, pad_r):
+        # block size kv over the C*kv row axis: block index == col index.
+        # CONSTANT over the inner grid dim, so the revolving out buffer
+        # writes back once per batch row — (kv, d) of HBM write per step
+        return (ib, pos_r[0], 0)
 
     out, ck_out, cv_out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(b, nk),
             in_specs=[
-                pl.BlockSpec((1, 1, gp, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, 1, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, 1, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ik, ikv, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ik, ikv, 0)),
+                pl.BlockSpec((1, hp, d), per_row),     # q
+                pl.BlockSpec((1, hp, d), per_row),     # k_new per q head
+                pl.BlockSpec((1, hp, d), per_row),     # v_new per q head
+                pl.BlockSpec((1, kv, d), per_row),     # k_new
+                pl.BlockSpec((1, kv, d), per_row),     # v_new
+                pl.BlockSpec((hp, n), const),          # group bias
+                pl.BlockSpec((1, n), const),           # col of each row
+                pl.BlockSpec((1, n, d), stream),       # cache_k
+                pl.BlockSpec((1, n, d), stream),       # cache_v
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, gp, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                # the append block: a CONSTANT index over the inner grid dim,
-                # so the revolving out buffer writes back exactly once per
-                # (b, kv) group — one block of HBM write traffic per step
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, pos_r[0] // block_k, ikv, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, pos_r[0] // block_k, ikv, 0)),
+                pl.BlockSpec((1, hp, d), per_row),
+                pl.BlockSpec((1, kv, d), append),
+                pl.BlockSpec((1, kv, d), append),
             ],
             scratch_shapes=[
-                pltpu.VMEM((gp, d), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, d), jnp.float32),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, kv, gp, d), q.dtype),
-            jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
-            jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
+            jax.ShapeDtypeStruct((b, hp, d), q.dtype),
+            jax.ShapeDtypeStruct(ck2.shape, cdt),
+            jax.ShapeDtypeStruct(cv2.shape, cache_v.dtype),
         ],
         # operand indices count the scalar-prefetch args: pos=0, pad=1,
-        # q=2, k_new=3, v_new=4, cache_k=5, cache_v=6
-        input_output_aliases={5: 1, 6: 2},
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        # q=2, knh=3, vnh=4, k_new=5, v_new=6, bias=7, col=8,
+        # cache_k=9, cache_v=10
+        input_output_aliases={9: 1, 10: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * C * d,
-            bytes_accessed=(2 * b * C * kv * d * cache_k.dtype.itemsize
-                            + 2 * block_k * kv * d * cache_k.dtype.itemsize
+            # every head scores every kv group's rows (masked after)
+            flops=4 * b * hp * C * kv * d,
+            bytes_accessed=(2 * b * C * kv * d * cdt.itemsize
+                            + 2 * b * kv * d * cdt.itemsize
                             + b * h * d * q.dtype.itemsize),
-            transcendentals=b * h * C),
+            transcendentals=b * hp * C * kv),
+        name="decode_attention",
         interpret=interpret,
-    )(pos_arr, pad_arr, q4, kn3, vn3, cache_k, cache_v)
+    )(pos_arr, pad_arr, q3, knh, vnh, kn3, vn3, bias, col, ck2, cv2)
 
-    out = out[:, :, :g, :].reshape(b, 1, h, d)
-    return out, ck_out, cv_out
+    return (out[:, :h].reshape(b, 1, h, d),
+            ck_out.reshape(cache_k.shape), cv_out.reshape(cache_v.shape))
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +322,7 @@ def decode_attention_int8_supported(q_shape, cache_shape, *,
         return _reject("rank", q_rank=len(q_shape))
     b, s, h, d = q_shape
     _, C, kv, dc = cache_shape
-    if not decode_attention_supported(q_shape, cache_shape, block_k=block_k):
+    if not _decode_shape_ok(q_shape, cache_shape, block_k):
         return _reject("shape", q_shape=list(q_shape), cache_len=C,
                        block_k=block_k)
     if block_k % _LANES != 0:
@@ -458,7 +519,7 @@ def decode_attention_int8(q, k_new, v_new, cache_k, cache_v, k_scale,
         # q=2, k_new=3, v_new=4, ck=5, cv=6, ks=7, vs=8 — the int8 arenas
         # AND their scale planes all update in place
         input_output_aliases={5: 1, 6: 2, 7: 3, 8: 4},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * C * d,
@@ -501,7 +562,7 @@ def decode_attention_fp8_supported(q_shape, cache_shape, *,
         return _reject("rank", q_rank=len(q_shape))
     b, s, h, d = q_shape
     _, C, kv, dc = cache_shape
-    if not decode_attention_supported(q_shape, cache_shape, block_k=block_k):
+    if not _decode_shape_ok(q_shape, cache_shape, block_k):
         return _reject("shape", q_shape=list(q_shape), cache_len=C,
                        block_k=block_k)
     if block_k % _LANES != 0 or block_k % _FP8_MIN_ROWS != 0:
@@ -671,7 +732,7 @@ def decode_attention_fp8(q, k_new, v_new, cache_k, cache_v, pos,
         # operand indices count the scalar-prefetch args: pos=0, pad=1,
         # q=2, k_new=3, v_new=4, cache_k=5, cache_v=6
         input_output_aliases={5: 1, 6: 2},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * C * d,

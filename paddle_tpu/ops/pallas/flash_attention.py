@@ -33,7 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from . import sds_like, tpu_compiler_params
+from . import sds_like
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -97,13 +97,14 @@ def _causal_mask(s, iq, ik, block_q, block_k, offset):
 # ---------------------------------------------------------------------------
 def _fwd_kernel(*refs, scale: float, causal: bool,
                 block_q: int, block_k: int, offset: int, padded: bool):
-    # with ``padded`` a per-row valid-length scalar rides in SMEM ahead of
-    # the tensor operands (varlen serving prefill; left-pad convention)
+    # with ``padded`` the per-row left-pad lengths ride scalar prefetch
+    # ahead of the tensor operands (varlen serving prefill): the whole [b]
+    # vector sits in SMEM and the kernel indexes its own batch row
     if padded:
         (pad_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
          acc_ref, m_ref, l_ref) = refs
+        pad = pad_ref[pl.program_id(0)]
     else:
-        pad_ref = None
         (q_ref, k_ref, v_ref, o_ref, lse_ref,
          acc_ref, m_ref, l_ref) = refs
     iq, ik = pl.program_id(2), pl.program_id(3)
@@ -118,7 +119,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool,
     live = _causal_live(iq, ik, block_q, block_k, offset) if causal else True
     if padded:
         # blocks entirely left of the row's first valid key are dead
-        live = jnp.logical_and(live, (ik + 1) * block_k > pad_ref[0])
+        live = jnp.logical_and(live, (ik + 1) * block_k > pad)
 
     @pl.when(live)
     def _step():
@@ -132,7 +133,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool,
         if padded:
             k_pos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos >= pad_ref[0], s, _NEG_INF)
+            s = jnp.where(k_pos >= pad, s, _NEG_INF)
         m_prev = m_ref[:, :1]                      # (Bq, 1)
         l_prev = l_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -173,41 +174,45 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
                                offset=sk - sq, padded=pad_lens is not None)
-    pad_specs = [] if pad_lens is None else [
-        pl.BlockSpec((1,), lambda ib, ih, iq, ik: (ib,),
-                     memory_space=pltpu.SMEM)]
+    # index maps take the scalar-prefetch ref (if any) after the grid ids
     pad_args = [] if pad_lens is None else [
         jnp.asarray(pad_lens, jnp.int32).reshape(b)]
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=pad_specs + [
-            pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda ib, ih, iq, ik: (ib, ih // rep, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda ib, ih, iq, ik: (ib, ih // rep, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES),
-                         lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(pad_args),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, d),
+                             lambda ib, ih, iq, ik, *_: (ib, ih, iq, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda ib, ih, iq, ik, *_: (ib, ih // rep, ik, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda ib, ih, iq, ik, *_: (ib, ih // rep, ik, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, d),
+                             lambda ib, ih, iq, ik, *_: (ib, ih, iq, 0)),
+                pl.BlockSpec((1, 1, block_q, _LANES),
+                             lambda ib, ih, iq, ik, *_: (ib, ih, iq, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=[
             sds_like((b, hq, sq, d), q.dtype, q),
             sds_like((b, hq, sq, _LANES), jnp.float32, q),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * hq * sq * sk * d // (2 if causal else 1),
             bytes_accessed=(b * sq * hq * d + 2 * b * sk * hkv * d) * q.dtype.itemsize,
             transcendentals=b * hq * sq * sk),
+        name="flash_fwd",
         interpret=interpret,
     )(*pad_args, q, k, v)
     return out, lse
@@ -340,8 +345,9 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, do):
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, out, do, lse)
 
@@ -378,9 +384,10 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, do):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

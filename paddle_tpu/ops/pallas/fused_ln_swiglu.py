@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import sds_like, tpu_compiler_params
+from . import sds_like
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -144,7 +144,7 @@ def _ln_bwd(eps, interpret, res, cts):
         ],
         scratch_shapes=[pltpu.VMEM((1, h), jnp.float32),
                         pltpu.VMEM((1, h), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(s2, weight.reshape(1, h), mu, rstd, dy.reshape(n, h),

@@ -13,7 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import sds_like, tpu_compiler_params
+from . import sds_like
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -100,6 +100,7 @@ def _rms_fwd(x, weight, eps, interpret):
             sds_like((n, h), x.dtype, x),
             sds_like((n, 1), jnp.float32, x),
         ],
+        name="rms_norm_fwd",
         interpret=interpret,
     )(x2, weight.reshape(1, h))
     return out.reshape(x.shape), (x, weight, rstd)
@@ -127,8 +128,9 @@ def _rms_bwd(eps, interpret, res, dy):
             sds_like((1, h), weight.dtype, x),
         ],
         scratch_shapes=[pltpu.VMEM((1, h), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
+        name="rms_norm_bwd",
         interpret=interpret,
     )(x2, weight.reshape(1, h), rstd, dy.reshape(n, h))
     return dx.reshape(x.shape), dw.reshape(weight.shape)

@@ -61,6 +61,7 @@ def _rope_raw(q, k, cos_s, sin_s, interpret):
             sds_like(q.shape, q.dtype, q),
             sds_like(k.shape, k.dtype, k),
         ],
+        name="fused_rope",
         interpret=interpret,
     )(q, k, cos_s, sin_s)
 

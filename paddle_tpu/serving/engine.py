@@ -1604,18 +1604,29 @@ class ServingEngine:
         return jax.device_put(x, NamedSharding(self._mesh,
                                                PartitionSpec()))
 
-    def _run_decode(self, tokens, positions, tables, n_tok):
+    def _compile(self, fn, args):
+        """Lower and compile one engine program (arenas donated).  Under
+        a TP / CP mesh GSPMD partitions it over a mesh the kernel
+        wrappers do not know, which the Pallas dispatchers are told."""
+        import contextlib
+
         import jax
 
+        from ..ops import gspmd_program
+
+        scope = gspmd_program() if self._mesh is not None \
+            else contextlib.nullcontext()
+        with _SWAP_LOCK, scope:
+            return jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+
+    def _run_decode(self, tokens, positions, tables, n_tok):
         pa, ba = self._param_arrays()
         args = (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(positions), self._repl(tables),
                 self._repl(n_tok))
         if self._decode_exec is None:
             self._decode_compiles += 1
-            jitted = jax.jit(self._decode_fn, donate_argnums=(2,))
-            with _SWAP_LOCK:
-                self._decode_exec = jitted.lower(*args).compile()
+            self._decode_exec = self._compile(self._decode_fn, args)
             if self._lint:
                 self.lint_report = check_decode_donation(
                     self._decode_exec, self._arena_bytes,
@@ -1624,16 +1635,12 @@ class ServingEngine:
         return logits
 
     def _run_prefill(self, tokens, chunk_start, tables, take_idx):
-        import jax
-
         pa, ba = self._param_arrays()
         args = (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(chunk_start), self._repl(tables),
                 self._repl(take_idx))
         if self._prefill_exec is None:
-            jitted = jax.jit(self._prefill_fn, donate_argnums=(2,))
-            with _SWAP_LOCK:
-                self._prefill_exec = jitted.lower(*args).compile()
+            self._prefill_exec = self._compile(self._prefill_fn, args)
         logits, self._arenas = self._prefill_exec(*args)
         return logits
 
@@ -1773,17 +1780,13 @@ class ServingEngine:
         prompt length (``nc_pad`` chunks — prompts that pad to the same
         multiple of ``cp`` share an executable; ``take_idx`` is traced,
         so the exact prompt length never recompiles)."""
-        import jax
-
         pa, ba = self._param_arrays()
         args = (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(tables), self._repl(take_idx))
         sig = int(tokens.shape[1])
         exec_ = self._cp_execs.get(sig)
         if exec_ is None:
-            jitted = jax.jit(self._cp_prefill_fn, donate_argnums=(2,))
-            with _SWAP_LOCK:
-                exec_ = jitted.lower(*args).compile()
+            exec_ = self._compile(self._cp_prefill_fn, args)
             self._cp_execs[sig] = exec_
             if self._lint:
                 # arenas are replicated over the ring (shards=1: every

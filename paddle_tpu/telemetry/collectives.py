@@ -32,17 +32,29 @@ __all__ = ["record_collective", "collective_stats", "ici_cost_estimate",
            "PEAK_TFLOPS", "ICI_GBPS_ONEWAY", "PEAK_HBM_GBPS", "chip_lookup"]
 
 # ---------------------------------------------------------------------------
-# chip tables (public specs; single home — bench.py prices against these)
+# chip tables (single home — bench.py and chip_smoke.py price against these)
+#
+# Keyed by substrings of ``device.device_kind`` ("TPU v5 lite", "TPU v5e",
+# "TPU v5p", "TPU v4", "TPU v6 lite" / "TPU v6e").  Sources:
+# - bf16 peak and HBM bandwidth: Google Cloud TPU documentation, the
+#   per-generation system-architecture pages ("TPU v5e": 197 TFLOP/s bf16,
+#   819 GB/s; "TPU v5p": 459, 2765; "TPU v4": 275, 1228; "TPU v6e": 918,
+#   1640).
+# - ICI: jax-ml.github.io/scaling-book, TPU chapter — one-directional
+#   bandwidth PER LINK (v5e 4.5e10 B/s; a v5e chip has four such links,
+#   which is the documentation's 1,600 Gbit/s per chip both ways).
+# - the "cpu" rows are nominal placeholders so a CPU test run can price a
+#   step at all; they describe no machine, and :func:`chip_lookup` reaches
+#   them only for ``device.platform == "cpu"``.
 
 # chip kind → peak bf16 TFLOP/s
 PEAK_TFLOPS = {
     "v5 lite": 197.0, "v5e": 197.0, "v5litepod": 197.0,
     "v5p": 459.0, "v4": 275.0, "v6e": 918.0, "v6": 918.0,
-    "cpu": 0.5,  # nominal, so CPU smoke runs still report
+    "cpu": 0.5,
 }
 
-# chip kind → per-chip one-directional ICI bandwidth, GB/s
-# (jax-ml.github.io/scaling-book: v5e 4.5e10 B/s per link one-way)
+# chip kind → per-link one-directional ICI bandwidth, GB/s
 ICI_GBPS_ONEWAY = {
     "v5 lite": 45.0, "v5e": 45.0, "v5litepod": 45.0,
     "v5p": 90.0, "v4": 45.0, "v6e": 90.0, "v6": 90.0,
@@ -59,12 +71,19 @@ PEAK_HBM_GBPS = {
 
 def chip_lookup(device, table: dict) -> float:
     """Match device_kind substrings against a chip table ('v5 lite' vs
-    'v5e' naming quirks live HERE, once)."""
-    kind = getattr(device, "device_kind", "cpu").lower()
+    'v5e' naming quirks live HERE, once).  A device the table does not
+    know is an error, never a default: a number priced against the wrong
+    peak is worse than no number."""
+    if device.platform == "cpu":
+        return table["cpu"]
+    kind = device.device_kind.lower()
     for key, val in table.items():
-        if key in kind:
+        if key != "cpu" and key in kind:
             return val
-    return table["cpu"]
+    raise KeyError(
+        f"device_kind {device.device_kind!r} (platform "
+        f"{device.platform!r}) is not in the chip table; add its peak, "
+        "with the source, to paddle_tpu/telemetry/collectives.py")
 
 
 # ring-cost wire factor per participant count n
@@ -100,11 +119,9 @@ def _ici_gbps() -> float:
     # would force backend init)
     global _ici_gbps_cache
     if _ici_gbps_cache is None:
-        try:
-            import jax
-            _ici_gbps_cache = chip_lookup(jax.devices()[0], ICI_GBPS_ONEWAY)
-        except Exception:
-            return ICI_GBPS_ONEWAY["cpu"]
+        import jax
+
+        _ici_gbps_cache = chip_lookup(jax.devices()[0], ICI_GBPS_ONEWAY)
     return _ici_gbps_cache
 
 

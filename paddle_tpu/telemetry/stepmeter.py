@@ -82,17 +82,13 @@ class StepMeter:
         else:
             self.jsonl_path = jsonl_path
         if peak_tflops is None or peak_hbm_gbps is None:
-            try:
-                import jax
-                dev = jax.devices()[0]
-            except Exception:
-                dev = None
+            import jax
+
+            dev = jax.devices()[0]
             if peak_tflops is None:
-                peak_tflops = chip_lookup(dev, PEAK_TFLOPS) if dev else \
-                    PEAK_TFLOPS["cpu"]
+                peak_tflops = chip_lookup(dev, PEAK_TFLOPS)
             if peak_hbm_gbps is None:
-                peak_hbm_gbps = chip_lookup(dev, PEAK_HBM_GBPS) if dev else \
-                    PEAK_HBM_GBPS["cpu"]
+                peak_hbm_gbps = chip_lookup(dev, PEAK_HBM_GBPS)
         self.peak_tflops = peak_tflops
         self.peak_hbm_gbps = peak_hbm_gbps
         # recent records only (full history is the JSONL file) — a 1M-step

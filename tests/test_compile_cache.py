@@ -203,6 +203,53 @@ class TestExecutableCache:
             resolve_cache(123)
 
 
+class TestCachePlacement:
+    """One resolver places every compile cache (compile.cache_dir)."""
+
+    def test_outside_placement_wins_and_nothing_else_is_named(
+            self, tmp_path, monkeypatch):
+        from paddle_tpu.compile import cache_dir, default_root
+
+        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE", raising=False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cache_dir() == str(tmp_path)
+        assert default_root() == str(tmp_path / "aot")
+
+    def test_default_is_one_fixed_path_in_the_checkout(self, monkeypatch):
+        from paddle_tpu.compile import cache_dir, default_root
+
+        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__)))
+        assert cache_dir() == os.path.join(checkout, ".compile_cache")
+        assert default_root() == os.path.join(checkout, ".compile_cache",
+                                              "aot")
+
+    def test_second_process_hits_what_the_first_compiled(self, tmp_path):
+        """enable_persistent_cache(): every jit of the process lands in
+        the placed directory, and the next process finds it."""
+        child = (
+            "import jax, jax.numpy as jnp, json\n"
+            "from paddle_tpu.compile import enable_persistent_cache\n"
+            "stats = enable_persistent_cache()\n"
+            "jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((64, 64)))"
+            ".block_until_ready()\n"
+            "print(json.dumps(stats.as_dict()))\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+        runs = []
+        for _ in range(2):
+            out = subprocess.run([sys.executable, "-c", child], env=env,
+                                 capture_output=True, text=True, timeout=120)
+            assert out.returncode == 0, out.stderr[-800:]
+            runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+        assert all(r["dir"] == str(tmp_path) for r in runs)
+        assert runs[0]["hits"] == 0 and runs[0]["misses"] >= 1
+        assert runs[1]["hits"] >= 1 and runs[1]["misses"] == 0
+        assert os.listdir(tmp_path)
+
+
 class TestJitCompileCacheBound:
     def test_env_bound_and_eviction_counter(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_JIT_CACHE_MAX", "2")
@@ -306,50 +353,43 @@ class TestAOTTrainStep:
         assert aot2.last_compile["mode"] == "warm"
 
 
-class TestSerializationSafetyGate:
-    """jaxlib 0.4.36 CPU segfaults when chained deserialized multi-device
-    executables hand donated sharded state to each other — the AOT service
-    must degrade those programs to always-cold, while single-device
-    programs on the same multi-device backend stay warm-able."""
+class TestMultiDeviceWarmLoad:
+    """A program sharded over the 8-virtual-device CPU mesh persists and
+    warm-loads onto ITS devices like any other (jax 0.9.0: the jaxlib
+    0.4.36 crash that once forced these programs cold is gone, and
+    ``deserialize_and_load`` is handed the program's own device list)."""
 
-    def _sharded_lowered(self):
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-        mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("a", "b"))
-        sh = NamedSharding(mesh, P("a", None))
-        return jax.jit(lambda x: x * 2, in_shardings=sh).lower(
-            jax.device_put(jnp.ones((8, 8), jnp.float32), sh))
-
-    def test_program_span_detection(self):
-        from paddle_tpu.compile import serialization_safe
-
-        assert serialization_safe(
-            jax.jit(lambda x: x * 2).lower(jnp.ones(4)).as_text()) is True
-        assert serialization_safe(self._sharded_lowered().as_text()) is False
-
-    def test_env_opt_in(self, monkeypatch):
-        from paddle_tpu.compile import serialization_safe
-
-        monkeypatch.setenv("PADDLE_TPU_AOT_CPU_MULTIDEVICE", "1")
-        assert serialization_safe(self._sharded_lowered().as_text()) is True
-
-    def test_aot_function_degrades_multidevice_to_cold(self, tmp_path):
+    def test_sharded_program_warm_loads(self, tmp_path):
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("a", "b"))
         sh = NamedSharding(mesh, P("a", None))
         cache = ExecutableCache(str(tmp_path))
         x = jax.device_put(jnp.ones((8, 8), jnp.float32), sh)
-        t0 = telemetry.runtime.now()["mono_ns"]
-        for _ in range(2):  # both instances cold: nothing persisted/loaded
-            aot = AOTFunction(jax.jit(lambda v: v * 2, in_shardings=sh),
-                              cache=cache, name="gated")
-            np.testing.assert_allclose(np.asarray(aot(x)), 2.0)
-            assert aot.last_compile["mode"] == "cold"
-            assert aot.last_compile["persisted"] is False
-        assert len(cache) == 0
-        assert any(e.get("name") == "serialization_unsafe_topology"
-                   for e in telemetry.get_flight_recorder().events(t0))
+        modes = []
+        for _ in range(2):
+            aot = AOTFunction(jax.jit(lambda v: v * 2, in_shardings=sh,
+                                      donate_argnums=0),
+                              cache=cache, name="sharded")
+            y = aot(jax.device_put(jnp.ones((8, 8), jnp.float32), sh))
+            # chain: the warm executable consumes donated sharded state
+            np.testing.assert_allclose(np.asarray(aot(y)), 4.0)
+            modes.append(aot.last_compile["mode"])
+        assert modes == ["cold", "warm"]
+        assert len(cache) == 1
+
+    def test_one_device_program_on_second_device(self, tmp_path):
+        """The execute_sharded regression: a one-device program must load
+        onto the device it was lowered for, not 'every local device'."""
+        dev = jax.devices()[3]
+        cache = ExecutableCache(str(tmp_path))
+        x = jax.device_put(jnp.ones((4,), jnp.float32), dev)
+        for want in ("cold", "warm"):
+            aot = AOTFunction(jax.jit(lambda v: v + 1), cache=cache,
+                              name="dev3")
+            out = aot(x)
+            assert aot.last_compile["mode"] == want
+            assert out.devices() == {dev}
 
 
 class TestSupervisorTimeToFirstStep:

@@ -50,8 +50,11 @@ def _oracle(q, k_new, v_new, ck, cv, pos, pad=None):
 
 
 class TestDecodeAttention:
-    @pytest.mark.parametrize("h,kv", [(4, 4), (4, 2), (8, 1)])
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    # kv fills whole sublane tiles of the cache dtype (f32 8, bf16 16) —
+    # the shapes the TPU lowering takes; MHA and GQA of each
+    @pytest.mark.parametrize("h,kv,dtype", [
+        (8, 8, jnp.float32), (16, 8, jnp.float32),
+        (16, 16, jnp.bfloat16), (32, 16, jnp.bfloat16)])
     def test_matches_oracle(self, h, kv, dtype):
         b, d, C, blk, pos = 2, 32, 64, 32, 21
         q = _rand(0, (b, 1, h, d), dtype)
@@ -59,7 +62,8 @@ class TestDecodeAttention:
         vn = _rand(2, (b, 1, kv, d), dtype)
         ck = _rand(3, (b, C, kv, d), dtype)
         cv = _rand(4, (b, C, kv, d), dtype)
-        assert decode_attention_supported(q.shape, ck.shape, block_k=blk)
+        assert decode_attention_supported(q.shape, ck.shape, block_k=blk,
+                                          dtype=dtype)
         out, ck2, cv2 = decode_attention(q, kn, vn, ck, cv, pos,
                                          block_k=blk, interpret=True)
         ro, rck, rcv = _oracle(q, kn, vn, ck, cv, pos)
@@ -76,7 +80,7 @@ class TestDecodeAttention:
 
     def test_ragged_valid_lengths(self):
         """Per-row left-padding: padded slots never contribute."""
-        b, h, kv, d, C, blk, pos = 3, 4, 2, 16, 96, 32, 40
+        b, h, kv, d, C, blk, pos = 3, 16, 8, 16, 96, 32, 40
         pads = jnp.asarray([0, 7, 33], jnp.int32)
         q = _rand(5, (b, 1, h, d))
         kn = _rand(6, (b, 1, kv, d))
@@ -92,7 +96,7 @@ class TestDecodeAttention:
     def test_fully_padded_row_attends_only_new_token(self):
         """pad >= pos leaves a row NO valid cache cols — it must attend
         exactly its own new token (the einsum semantics), not go NaN."""
-        b, h, kv, d, C, blk, pos = 2, 4, 2, 16, 64, 32, 8
+        b, h, kv, d, C, blk, pos = 2, 16, 8, 16, 64, 32, 8
         pads = jnp.asarray([0, pos], jnp.int32)   # row 1: cache fully masked
         q = _rand(13, (b, 1, h, d))
         kn = _rand(14, (b, 1, kv, d))
@@ -109,7 +113,7 @@ class TestDecodeAttention:
     def test_traced_pos_under_scan(self):
         """The decode scan carries ``pos`` as a traced scalar; the cache
         threads through the aliased kernel step after step."""
-        b, h, kv, d, C, blk = 1, 2, 1, 16, 32, 16
+        b, h, kv, d, C, blk = 1, 8, 8, 16, 32, 16
         q = _rand(10, (b, 1, h, d))
         kn = _rand(11, (b, 1, kv, d))
         vn = _rand(12, (b, 1, kv, d))
@@ -130,16 +134,25 @@ class TestDecodeAttention:
         assert not np.asarray(cv2)[:, 4:].any()  # untouched slots stay zero
 
     def test_gate_rejects_bad_shapes(self):
-        assert decode_attention_supported((2, 1, 4, 32), (2, 64, 2, 32),
-                                          block_k=32)
+        f32 = dict(dtype=jnp.float32)
+        assert decode_attention_supported((2, 1, 8, 32), (2, 64, 8, 32),
+                                          block_k=32, **f32)
         assert not decode_attention_supported(
-            (2, 1, 4, 32), (2, 64, 2, 32))  # default block 256 > C=64
-        assert not decode_attention_supported((2, 2, 4, 32), (2, 64, 2, 32),
-                                              block_k=32)  # s != 1
-        assert not decode_attention_supported((2, 1, 4, 30), (2, 64, 2, 30),
-                                              block_k=32)  # d % 8
-        assert not decode_attention_supported((2, 1, 4, 32), (2, 60, 2, 32),
-                                              block_k=32)  # C % block
+            (2, 1, 8, 32), (2, 64, 8, 32), **f32)  # default block 256 > C=64
+        assert not decode_attention_supported((2, 2, 8, 32), (2, 64, 8, 32),
+                                              block_k=32, **f32)  # s != 1
+        assert not decode_attention_supported((2, 1, 8, 30), (2, 64, 8, 30),
+                                              block_k=32, **f32)  # d % 8
+        assert not decode_attention_supported((2, 1, 8, 32), (2, 60, 8, 32),
+                                              block_k=32, **f32)  # C % block
+        # kv must fill whole sublane tiles of the cache dtype
+        assert not decode_attention_supported((2, 1, 4, 32), (2, 64, 2, 32),
+                                              block_k=32, **f32)
+        assert not decode_attention_supported((2, 1, 8, 32), (2, 64, 8, 32),
+                                              block_k=32)  # bf16 wants 16
+        # the streamed K/V blocks must fit scoped VMEM
+        assert not decode_attention_supported(
+            (1, 1, 64, 256), (1, 2048, 64, 256), block_k=1024)
 
 
 class TestVarlenFlash:
@@ -191,7 +204,10 @@ class TestKernelDispatchParity:
         from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 
         paddle.seed(3)
+        # f32 cache: 8 kv heads fill the sublane tile the kernel gate asks
         cfg = llama_tiny(num_hidden_layers=2, vocab_size=96,
+                         hidden_size=128, num_attention_heads=8,
+                         num_key_value_heads=8,
                          max_position_embeddings=128)
         m = LlamaForCausalLM(cfg)
         m.eval()
@@ -243,13 +259,13 @@ class TestKernelDispatchParity:
         paddle.set_flags({"pallas_interpret": True})
         try:
             # C=60 not tileable → decode kernel gate rejects → einsum path
-            q = jnp.zeros((1, 1, 4, 30))
-            kn = jnp.zeros((1, 1, 2, 30))
-            out, _, _ = cached_attention(q, kn, kn, jnp.zeros((1, 60, 2, 30)),
-                                         jnp.zeros((1, 60, 2, 30)), 3)
+            q = jnp.zeros((1, 1, 8, 30))
+            kn = jnp.zeros((1, 1, 8, 30))
+            out, _, _ = cached_attention(q, kn, kn, jnp.zeros((1, 60, 8, 30)),
+                                         jnp.zeros((1, 60, 8, 30)), 3)
         finally:
             paddle.set_flags(prior)
-        assert out.shape == (1, 1, 4, 30)
+        assert out.shape == (1, 1, 8, 30)
         counts = tel.counters()
         assert counts.get("kernel_fallback.decode_attention.shape", 0) >= 1
         events = [e for e in tel.get_flight_recorder().events()

@@ -306,11 +306,13 @@ class TestPrefixEngine:
 class TestTPDecode:
     def test_sharded_dispatch_gate(self):
         ok = decode_attention_sharded_supported
-        assert ok((4, 1, 8, 64), (4, 256, 4, 64), tp=2)
-        assert ok((4, 1, 8, 64), (4, 256, 4, 64), tp=1)
+        # the bf16 kernel needs whole (16, 128) tiles of kv heads per shard
+        assert ok((4, 1, 32, 64), (4, 256, 32, 64), tp=2)
+        assert ok((4, 1, 32, 64), (4, 256, 32, 64), tp=1)
+        assert not ok((4, 1, 8, 64), (4, 256, 4, 64), tp=2)   # kv/shard = 2
         assert ok((4, 1, 8, 64), (4, 256, 4, 64), tp=4, int8=True)
         assert not ok((4, 1, 8, 64), (4, 256, 4, 64), tp=3)   # ragged
-        assert not ok((4, 1, 8, 64), (4, 128, 4, 64), tp=2)   # C < block_k
+        assert not ok((4, 1, 32, 64), (4, 128, 32, 64), tp=2)  # C < block_k
         assert not ok((4, 1, 8), (4, 256, 4, 64), tp=2)       # rank
 
     def test_ragged_tp_raises_at_construction(self, tp_model):

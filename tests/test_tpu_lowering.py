@@ -1,0 +1,249 @@
+"""Cross-lowering for TPU from the CPU-only test host.
+
+``jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))`` runs the
+Pallas -> Mosaic lowering without a chip, and that lowering is where a block
+spec the TPU cannot take is refused ("last two dimensions of your block
+shape [must be] divisible by 8 and 128 ... or equal to the ... array").  Two
+kernels on default-on product paths shipped with such specs because every
+test ran them in interpret mode, which checks nothing of the kind:
+``decode_attention`` blocked one kv head out of ``[b, C, kv, d]`` and
+``flash_attention_varlen`` blocked one element out of a rank-1 ``(b,)``.
+This file is the test that would have caught both, at the bench's shapes:
+
+- every Pallas kernel a default-on flag dispatches to;
+- every shape a ``*_supported`` gate accepts must lower;
+- the full train step, the serving decode and the serving prefill programs
+  at two layers and the full Llama-670M widths, with the kernels in them.
+
+What Mosaic then makes of a kernel that lowers (VMEM, layouts) only the chip
+can say: ``python chip_smoke.py`` through the chip tool.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.ops.pallas import (decode_attention,
+                                   decode_attention_supported,
+                                   flash_attention, flash_attention_supported,
+                                   flash_attention_varlen,
+                                   flash_attention_varlen_supported,
+                                   fused_rms_norm, fused_rope)
+
+BF16 = jnp.bfloat16
+# Llama-670M widths (bench.py, chip_smoke.py); depth cut to two layers
+WIDTHS = dict(vocab_size=32000, hidden_size=2048, intermediate_size=8192,
+              num_hidden_layers=2, num_attention_heads=16,
+              num_key_value_heads=16)
+
+
+def tpu_text(fn, *args, **jit_kw) -> str:
+    return jax.jit(fn, **jit_kw).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def kernels_in(text: str) -> dict:
+    names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rms_norm_fwd",
+             "rms_norm_bwd", "fused_rope", "decode_attention")
+    return {n: text.count(f'kernel_name = "{n}"') for n in names}
+
+
+def sds(*shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Dispatch as on the chip: the functional layer picks the Pallas
+    kernels (not interpreted) although the backend here is the CPU."""
+    import paddle_tpu.ops as ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    from paddle_tpu.distributed import topology
+
+    monkeypatch.setattr(topology, "_hcg", None)
+
+
+class TestKernelsLower:
+    @pytest.mark.parametrize("b,s,blocks", [(4, 2048, (512, 512)),
+                                            (1, 8192, (1024, 512))])
+    def test_flash_forward_and_backward(self, b, s, blocks):
+        q = sds(b, s, 16, 128)
+        bq, bk = blocks
+        assert flash_attention_supported(q.shape, q.shape, has_mask=False,
+                                         dropout_p=0.0, causal=True,
+                                         block_q=bq, block_k=bk)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, None, True, bq, bk, False) \
+                .astype(jnp.float32).sum()
+
+        k = kernels_in(tpu_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
+        assert k["flash_fwd"] == k["flash_bwd_dq"] == k["flash_bwd_dkv"] == 1
+
+    @pytest.mark.parametrize("shape,blocks", [
+        ((8, 1024, 16, 128), (512, 512)),    # chip_smoke's bucketed prefill
+        ((2, 128, 4, 64), (128, 128)),
+        ((3, 64, 8, 128), (64, 32))])
+    def test_flash_varlen(self, shape, blocks):
+        """pad_lens rides scalar prefetch; a rank-1 (1,) SMEM block over
+        the (b,) vector is what the lowering refused."""
+        q = sds(*shape)
+        bq, bk = blocks
+        assert flash_attention_varlen_supported(q.shape, q.shape,
+                                                block_q=bq, block_k=bk)
+        text = tpu_text(lambda q, k, v, p: flash_attention_varlen(
+            q, k, v, p, block_q=bq, block_k=bk), q, q, q,
+            sds(shape[0], dtype=jnp.int32))
+        assert kernels_in(text)["flash_fwd"] == 1
+
+    @pytest.mark.parametrize("b,C,h,kv,d,blk,dtype", [
+        (8, 160, 16, 16, 128, 160, BF16),     # chip_smoke generate, 2K
+        (8, 256, 16, 16, 128, 256, BF16),     # bench_llama_decode
+        (4, 8192, 16, 16, 128, 256, BF16),    # the 8K point (ROADMAP S4)
+        (2, 512, 32, 16, 64, 256, BF16),      # GQA, head_dim 64
+        (2, 512, 32, 32, 128, 128, BF16),     # 32 MHA heads
+        (2, 256, 16, 8, 128, 64, jnp.float32)])
+    def test_decode_attention(self, b, C, h, kv, d, blk, dtype):
+        """All kv heads ride every block ((block_k*kv, d) rows of the
+        [b, C*kv, d] view); a block of ONE kv head out of [b, C, kv, d] is
+        what the lowering refused for kv > 1."""
+        q, kn = sds(b, 1, h, d, dtype=dtype), sds(b, 1, kv, d, dtype=dtype)
+        cache = sds(b, C, kv, d, dtype=dtype)
+        assert decode_attention_supported(q.shape, cache.shape, block_k=blk,
+                                          dtype=dtype)
+        text = tpu_text(
+            lambda q, kn, vn, ck, cv, pos, pad: decode_attention(
+                q, kn, vn, ck, cv, pos, pad, block_k=blk),
+            q, kn, kn, cache, cache, sds(dtype=jnp.int32),
+            sds(b, dtype=jnp.int32), donate_argnums=(3, 4))
+        assert kernels_in(text)["decode_attention"] == 1
+
+    def test_decode_gate_rejects_what_the_lowering_rejects(self):
+        """kv heads that do not fill whole sublane tiles of the cache
+        dtype make the append block (kv, d) unaligned: gate AND lowering
+        say no."""
+        q, kn, cache = sds(2, 1, 8, 128), sds(2, 1, 4, 128), \
+            sds(2, 256, 4, 128)
+        assert not decode_attention_supported(q.shape, cache.shape,
+                                              block_k=64, dtype=BF16)
+        with pytest.raises(Exception, match="divisible by 8 and 128"):
+            tpu_text(lambda q, kn, vn, ck, cv: decode_attention(
+                q, kn, vn, ck, cv, 3, None, block_k=64),
+                q, kn, kn, cache, cache)
+
+    def test_rms_norm_and_rope(self):
+        x, w = sds(4, 2048, 2048), sds(2048, dtype=jnp.float32)
+
+        def norm_loss(x, w):
+            return fused_rms_norm(x, w).astype(jnp.float32).sum()
+
+        k = kernels_in(tpu_text(jax.grad(norm_loss, argnums=(0, 1)), x, w))
+        assert k["rms_norm_fwd"] == k["rms_norm_bwd"] == 1
+        # decode-sized rows (ServingEngine max_batch 8, one token each)
+        assert kernels_in(tpu_text(fused_rms_norm, sds(8, 1, 2048), w)
+                          )["rms_norm_fwd"] == 1
+
+        q, t = sds(4, 2048, 16, 128), sds(2048, 128, dtype=jnp.float32)
+
+        def rope_loss(q, k, c, s):
+            oq, ok = fused_rope(q, k, c, s)
+            return (oq.astype(jnp.float32).sum()
+                    + ok.astype(jnp.float32).sum())
+
+        assert kernels_in(tpu_text(fused_rope, q, q, t, t))["fused_rope"] == 1
+        # the backward is the same kernel with the sine table negated
+        k = kernels_in(tpu_text(jax.grad(rope_loss, argnums=(0, 1)),
+                                q, q, t, t))
+        assert k["fused_rope"] >= 1
+
+
+@pytest.mark.usefixtures("on_tpu")
+class TestProgramsLower:
+    """The programs chip_smoke.py runs, two layers deep at full widths."""
+
+    @pytest.fixture(autouse=True)
+    def no_kernel_fell_back(self):
+        """No dispatcher in the traced program took an XLA path quietly."""
+        import paddle_tpu.telemetry as telemetry
+
+        before = telemetry.counters().get("kernel_fallback.total", 0)
+        yield
+        assert telemetry.counters().get("kernel_fallback.total", 0) == before
+
+    @pytest.fixture(scope="class")
+    def model_and_opt(self):
+        """One full-width model for the whole class: nothing here runs a
+        program, so the train step and the serving engine can share it."""
+        import paddle_tpu.nn as nn
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig(
+            **WIDTHS, max_position_embeddings=2048, recompute=False))
+        opt = paddle.optimizer.AdamW(
+            1e-4, parameters=model.parameters(),
+            grad_clip=nn.ClipGradByGlobalNorm(1.0))
+        return paddle.amp.decorate(model, opt, level="O2", dtype="bfloat16")
+
+    @pytest.fixture
+    def eval_model(self, model_and_opt):
+        model_and_opt[0].eval()
+        yield model_and_opt[0]
+        model_and_opt[0].train()
+
+    def test_train_step(self, model_and_opt):
+        model, opt = model_and_opt
+        step = paddle.jit.TrainStep(
+            model, lambda m, x, y: m(x, labels=y)[0], opt)
+        ids = paddle.to_tensor(np.zeros((4, 2048), np.int32))
+        k = kernels_in(step.lower(ids, ids, lowering_platforms=("tpu",))
+                       .as_text())
+        for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                     "rms_norm_fwd", "rms_norm_bwd", "fused_rope"):
+            assert k[name] >= 2, k           # one per layer at least
+
+    def test_serving_decode_and_prefill(self, eval_model):
+        from paddle_tpu.serving import ServingEngine
+
+        eng = ServingEngine(eval_model, max_batch=8, page_tokens=128,
+                            num_pages=129, max_pages_per_seq=16)
+        pa, ba = eng._param_arrays()
+        R, MP, P = 8, 16, 128
+        tables = jnp.zeros((R, MP), jnp.int32)
+        decode = tpu_text(
+            eng._decode_fn, pa, ba, eng._arenas,
+            jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
+            tables, jnp.ones((R,), jnp.int32), donate_argnums=(2,))
+        assert kernels_in(decode)["rms_norm_fwd"] >= 2
+        prefill = tpu_text(
+            eng._prefill_fn, pa, ba, eng._arenas,
+            jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
+            jnp.int32(P - 1), donate_argnums=(2,))
+        assert kernels_in(prefill)["rms_norm_fwd"] >= 2
+
+    def test_generate_decode_step(self, eval_model):
+        """One decode step of ``generate()``'s loop — the model called the
+        way the compiled scan calls it — holds the decode kernel."""
+        from paddle_tpu.jit import _StateSwap
+
+        cfg = eval_model.config
+        params = [p for _, p in eval_model.named_parameters()]
+        buffers = [b for _, b in eval_model.named_buffers()]
+        cache = [(jnp.zeros((8, 160, 16, cfg.head_dim), BF16),) * 2
+                 for _ in range(cfg.num_hidden_layers)]
+
+        def step(p_arr, b_arr, tok, caches, offset):
+            with _StateSwap(params, p_arr), _StateSwap(buffers, b_arr), \
+                    paddle.no_grad():
+                logits, caches = eval_model(paddle.Tensor(tok),
+                                            kv_cache=caches,
+                                            position_offset=offset)
+            return logits.value, caches
+
+        text = tpu_text(step, [p.value for p in params],
+                        [b.value for b in buffers],
+                        jnp.zeros((8, 1), jnp.int32), cache, jnp.int32(128))
+        assert kernels_in(text)["decode_attention"] == cfg.num_hidden_layers
