@@ -34,7 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jit import TrainStep, _StateSwap
+from ..jit import (CHECKED_STEP_PROGRAM, GUARDED_STEP_PROGRAM, STEP_PROGRAM,
+                   TrainStep, _StateSwap, named_program)
 from ..nn.layer.layers import Layer
 from ..tensor.tensor import Tensor
 from .topology import HybridCommunicateGroup
@@ -215,16 +216,15 @@ class DistributedTrainStep(TrainStep):
         # every compiled variant must pin the SAME shardings (else XLA is
         # free to re-lay state out and the next differently-compiled step
         # rejects it) — one source of truth for the pinning tuples
-        import functools as _ft
-
         self._compiled = self._maybe_aot(jax.jit(
-            self._step,
+            named_program(self._step, STEP_PROGRAM),
             donate_argnums=(0, 1) if donate else (),
             **self._sharding_pins(),
         ), "step")
         # check_nan_inf variant: no donation — state must survive a raise
         self._compiled_checked = jax.jit(
-            _ft.partial(self._step, check_numerics=True),
+            named_program(self._step, CHECKED_STEP_PROGRAM,
+                          check_numerics=True),
             **self._sharding_pins(extra_out=True),
         )
 
@@ -246,11 +246,10 @@ class DistributedTrainStep(TrainStep):
     def _make_guarded_jit(self):
         """Health-guarded variant, same pinned shardings; donation stays
         on — skips are selected in-program, never recovered host-side."""
-        import functools as _ft
-
         mon = getattr(self, "_sdc_monitor", None)
         return self._maybe_aot(jax.jit(
-            _ft.partial(self._step, health_probe=True),
+            named_program(self._step, GUARDED_STEP_PROGRAM,
+                          health_probe=True),
             donate_argnums=(0, 1) if self._donate else (),
             **self._sharding_pins(extra_out=True,
                                   extra_in=mon is not None and mon.active),

@@ -34,6 +34,7 @@ from ..autograd.tape import TapeNode, is_grad_enabled
 from ..framework.random import key_scope, next_key
 from ..nn.clip import ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue
 from ..nn.layer.layers import Layer
+from ..profiler import span as _span
 from ..tensor.tensor import Tensor
 
 __all__ = ["to_static", "TrainStep", "not_to_static", "ignore_module", "save",
@@ -166,6 +167,19 @@ def _stamp_first_step() -> None:
             f.write(repr(time.time()))
     except OSError:
         pass
+
+
+def named_program(fn: Callable, name: str, **fixed) -> Callable:
+    """``fn`` (with ``fixed`` keyword arguments bound) under a ``__name__``
+    of its own.  ``jax.jit`` names the compiled module ``jit_<__name__>``:
+    a bound method lends its Python name and a ``functools.partial`` has
+    none (``jit__unknown``), while a device trace's readers find a program
+    by that name — so it is a constant of the program, pinned by tests.
+    A named ``partial`` and no wrapper function: every frame between
+    ``jit`` and the traced body is paid per traced operation (tracebacks)."""
+    program = functools.partial(fn, **fixed)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 class _StateSwap:
@@ -379,6 +393,14 @@ def ignore_module(modules):
     pass
 
 
+# The step's compiled variants under names of their own (``named_program``):
+# the plain step, the one with the fused health probe (HealthGuard /
+# SDCMonitor armed) and the ``check_nan_inf`` diagnosis variant.
+STEP_PROGRAM = "train_step"
+GUARDED_STEP_PROGRAM = "train_step_guarded"
+CHECKED_STEP_PROGRAM = "train_step_checked"
+
+
 class TrainStep:
     """Fused train step: grads + clip + optimizer update in ONE compiled XLA
     program with donated state (the TPU answer to the reference's static
@@ -448,15 +470,16 @@ class TrainStep:
 
         self._asp_masks = [ASPHelper._masks.get(id(p)) for p in self._params]
         self._compiled = self._maybe_aot(
-            jax.jit(self._step, donate_argnums=(0, 1) if donate else ()),
+            jax.jit(named_program(self._step, STEP_PROGRAM),
+                    donate_argnums=(0, 1) if donate else ()),
             "step")
         # FLAGS_check_nan_inf variant: same step + per-grad finite flags
         # (covers the compiled path the eager apply_op hook can't see —
         # reference nan_inf_utils_detail checks inside every kernel launch).
         # NO donation: on a detected NaN we raise BEFORE rebinding state, and
         # the old params/opt-state must still be alive.
-        self._compiled_checked = jax.jit(
-            functools.partial(self._step, check_numerics=True))
+        self._compiled_checked = jax.jit(named_program(
+            self._step, CHECKED_STEP_PROGRAM, check_numerics=True))
 
     # -- health guard ------------------------------------------------------
     def attach_health_guard(self, guard) -> None:
@@ -494,7 +517,8 @@ class TrainStep:
         a skipped step's old state feeds the in-program select, never a
         post-hoc host decision (DistributedTrainStep pins shardings)."""
         return self._maybe_aot(
-            jax.jit(functools.partial(self._step, health_probe=True),
+            jax.jit(named_program(self._step, GUARDED_STEP_PROGRAM,
+                                  health_probe=True),
                     donate_argnums=(0, 1) if self._donate else ()),
             "guarded_step")
 
@@ -821,7 +845,60 @@ class TrainStep:
             lowering_platforms=tuple(lowering_platforms))
 
     def __call__(self, *batch) -> Tensor:
-        from ..framework.flags import get_flags
+        with _span("train.step", step=self.optimizer._step_count + 1):
+            with _span("train.marshal"):
+                args = self._checked_args(batch)
+            # launched from this frame, not a helper's: the first call traces
+            # the step, and tracing pays for every frame above it
+            variant, args = self._variant(args)
+            with _span("train.launch", program=variant):
+                if variant == GUARDED_STEP_PROGRAM:
+                    loss, new_params, new_states, new_buf, probe = \
+                        self._get_guarded()(*args)
+                elif variant == CHECKED_STEP_PROGRAM:
+                    loss, new_params, new_states, new_buf, finite = \
+                        self._compiled_checked(*args)
+                    self._raise_on_non_finite(finite)
+                    probe = None
+                else:
+                    loss, new_params, new_states, new_buf = \
+                        self._compiled(*args)
+                    probe = None
+            with _span("train.rebind"):
+                for p, arr, st in zip(self._params, new_params, new_states):
+                    mw = st.pop("@master", None)
+                    if mw is not None:
+                        self.optimizer._master_weights[id(p)] = mw
+                    p._value = arr
+                    p._producer = None
+                    self.optimizer._accumulators[id(p)] = st
+                for b, arr in zip(self._buffers, new_buf):
+                    b._value = arr
+                    b._producer = None
+                self.optimizer._step_count += 1
+            with _span("train.guard"):
+                self._after_step(probe)
+            # supervisor goodput probe: first completed step of this process
+            # (relaunch → here is time_to_first_step_s in restart events)
+            _stamp_first_step()
+            # fleet fault domain: per-step heartbeat stamp (straggler
+            # detection)
+            _note_fleet_step(self.optimizer._step_count)
+            try:  # telemetry: step event for the flight recorder +
+                # prometheus.  No host sync here — loss stays a device value.
+                from .. import telemetry
+
+                if telemetry.enabled():
+                    telemetry.bump("train_step_calls_total")
+                    telemetry.record_event(
+                        "step", type(self).__name__,
+                        step=self.optimizer._step_count)
+            except Exception:
+                pass
+            return Tensor(loss)
+
+    def _checked_args(self, batch):
+        """``_marshal_args`` behind the checks every call makes first."""
         from ..incubate.asp import ASPHelper
 
         # ASP masks are baked into the compiled program as constants; a
@@ -835,53 +912,49 @@ class TrainStep:
                     "asp.prune_model BEFORE building the TrainStep (or "
                     "rebuild it)")
         args = self._marshal_args(batch)
-        batch_arrays = args[-1]
         if self._merge_k > 1:
-            for a in batch_arrays:
+            for a in args[-1]:
                 if a.ndim == 0 or a.shape[0] % self._merge_k:
                     raise ValueError(
                         f"gradient_merge k={self._merge_k} needs every batch "
                         f"arg's dim0 divisible by k, got shape {a.shape}")
+        return args
+
+    def _variant(self, args):
+        """Which compiled variant this step runs, and its arguments."""
+        from ..framework.flags import get_flags
+
         guard = self._health_guard
         mon = self._sdc_monitor
-        probe = None
         if (guard is not None and guard.active) or \
                 (mon is not None and mon.active):
             # guarded path wins over check_nan_inf: it subsumes the check
             # (detects the same non-finites) and recovers instead of raising
-            call_args = args
             if mon is not None and mon.active:
                 # this step's number (post-increment) against the vote
                 # cadence: off-cadence steps skip the fingerprint work
                 # in-program (lax.cond on this dynamic flag — no retrace)
                 nxt = self.optimizer._step_count + 1
-                call_args = args + (
-                    nxt % max(1, mon.policy.every) == 0,)
-            loss, new_params, new_states, new_buf, probe = \
-                self._get_guarded()(*call_args)
-        elif get_flags("check_nan_inf")["check_nan_inf"]:
-            loss, new_params, new_states, new_buf, finite = \
-                self._compiled_checked(*args)
-            flags = list(map(bool, finite))
-            if not all(flags):
-                bad = (["loss"] if not flags[0] else []) + [
-                    self._param_names[i] for i, ok in enumerate(flags[1:]) if not ok]
-                raise RuntimeError(
-                    "check_nan_inf: non-finite values in compiled train step "
-                    f"(gradients of: {', '.join(bad)})")
-        else:
-            loss, new_params, new_states, new_buf = self._compiled(*args)
-        for p, arr, st in zip(self._params, new_params, new_states):
-            mw = st.pop("@master", None)
-            if mw is not None:
-                self.optimizer._master_weights[id(p)] = mw
-            p._value = arr
-            p._producer = None
-            self.optimizer._accumulators[id(p)] = st
-        for b, arr in zip(self._buffers, new_buf):
-            b._value = arr
-            b._producer = None
-        self.optimizer._step_count += 1
+                args = args + (nxt % max(1, mon.policy.every) == 0,)
+            return GUARDED_STEP_PROGRAM, args
+        if get_flags("check_nan_inf")["check_nan_inf"]:
+            return CHECKED_STEP_PROGRAM, args
+        return STEP_PROGRAM, args
+
+    def _raise_on_non_finite(self, finite) -> None:
+        flags = list(map(bool, finite))
+        if not all(flags):
+            bad = (["loss"] if not flags[0] else []) + [
+                self._param_names[i] for i, ok in enumerate(flags[1:]) if not ok]
+            raise RuntimeError(
+                "check_nan_inf: non-finite values in compiled train step "
+                f"(gradients of: {', '.join(bad)})")
+
+    def _after_step(self, probe) -> None:
+        """The host-side hooks behind a completed step: the guards resolve
+        their probe, the snapshotter keeps its cadence."""
+        guard = self._health_guard
+        mon = self._sdc_monitor
         if probe is not None:
             # state is already rebound (skips selected in-program); the
             # guard resolves the probe max_lag steps late and may raise
@@ -908,23 +981,6 @@ class TrainStep:
                     mon.note_checkpoint(self.optimizer._step_count)
             except Exception:
                 pass  # degraded RPO must never kill the step
-        # supervisor goodput probe: first completed step of this process
-        # (relaunch → here is time_to_first_step_s in restart events)
-        _stamp_first_step()
-        # fleet fault domain: per-step heartbeat stamp (straggler detection)
-        _note_fleet_step(self.optimizer._step_count)
-        try:  # telemetry: step event for the flight recorder + prometheus.
-            # No host sync here — loss stays a device value.
-            from .. import telemetry
-
-            if telemetry.enabled():
-                telemetry.bump("train_step_calls_total")
-                telemetry.record_event(
-                    "step", type(self).__name__,
-                    step=self.optimizer._step_count)
-        except Exception:
-            pass
-        return Tensor(loss)
 
 
 class InputSpec:

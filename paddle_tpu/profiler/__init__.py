@@ -13,8 +13,11 @@ this facade:
   spans) in-process and exports them as a chrome trace JSON you can open in
   ``chrome://tracing`` / Perfetto — same artifact the reference's
   ``export_chrome_tracing`` produces;
-- forwards every ``RecordEvent`` scope to ``jax.profiler.TraceAnnotation``
-  so the names also appear inside XLA device traces when one is active;
+- enters ``jax.profiler.TraceAnnotation`` for every ``RecordEvent`` /
+  ``span`` scope, always: whoever holds a profiler session (this facade,
+  ``jax.profiler.start_trace``, a TensorBoard capture) sees the program's
+  own phases (``serve.*``, ``train.*``; PERF.md has the table) beside the
+  device lines, and with no session live a scope costs about two microseconds;
 - captures the XLA device trace per RECORD window when ``targets`` include
   ``ProfilerTarget.TPU`` (written under ``<log_dir>/xplane`` for
   TensorBoard).
@@ -33,8 +36,10 @@ import time
 from enum import Enum
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Union
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = [
-    "ProfilerState", "ProfilerTarget", "Profiler", "RecordEvent",
+    "ProfilerState", "ProfilerTarget", "Profiler", "RecordEvent", "span",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
     "SortedKeys", "benchmark",
 ]
@@ -188,41 +193,59 @@ _active_profiler: Optional["Profiler"] = None
 
 
 class RecordEvent:
-    """User-defined scope: shows up in the host chrome trace and, when an XLA
-    trace is live, inside the device trace (via TraceAnnotation). Reference:
-    ``python/paddle/profiler/utils.py`` RecordEvent.
+    """A named scope on the profiler's clock: THE span primitive of this
+    package (reference ``python/paddle/profiler/utils.py`` RecordEvent).
 
-    Usable as a context manager or via explicit ``begin()``/``end()``."""
+    It always enters ``jax.profiler.TraceAnnotation(name, **facts)``.  A
+    TraceMe is inert unless a profiler session is live — a paddle
+    :class:`Profiler` with a device target, a plain
+    ``jax.profiler.start_trace``, a TensorBoard capture — so "tracing on"
+    means "a session is live" and nothing else: there is no switch.  In a
+    live session the scope lands on plane ``/host:CPU`` of the xplane, on
+    the same nanosecond clock as the device lines, with ``facts`` as its
+    stats.  In addition it is appended to the paddle ``Profiler``'s host
+    timeline (the chrome-trace export) while one is recording.
 
-    def __init__(self, name: str, event_type: str = "UserDefined"):
+    Usable as a context manager or via explicit ``begin()``/``end()``;
+    :func:`span` is the short spelling the program's own phases use."""
+
+    __slots__ = ("name", "event_type", "facts", "_start_ns", "_annotation")
+
+    def __init__(self, name: str, event_type: str = "UserDefined", **facts):
         self.name = name
         self.event_type = event_type
+        self.facts = facts
         self._start_ns: Optional[int] = None
         self._annotation = None
 
     def begin(self) -> None:
+        self._annotation = _TraceAnnotation(self.name, **self.facts)
+        self._annotation.__enter__()
         prof = _active_profiler
         if prof is not None and prof._recording and not prof._timer_only:
             self._start_ns = time.perf_counter_ns()
-            try:
-                import jax
-                self._annotation = jax.profiler.TraceAnnotation(self.name)
-                self._annotation.__enter__()
-            except Exception:
-                self._annotation = None
+
+    def note(self, **facts) -> None:
+        """Facts known only once the work is done (how many were admitted,
+        how many bytes came back): attached to the open scope."""
+        self.facts.update(facts)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**facts)
 
     def end(self) -> None:
-        if self._start_ns is None:
+        annotation, self._annotation = self._annotation, None
+        if annotation is None:
             return
-        if self._annotation is not None:
-            self._annotation.__exit__(None, None, None)
-            self._annotation = None
+        # the annotation closes whatever became of the paddle Profiler
+        # between begin() and end()
+        annotation.__exit__(None, None, None)
+        start_ns, self._start_ns = self._start_ns, None
         prof = _active_profiler
-        if prof is not None and prof._recording:
-            prof._timeline.add(_Event(self.name, self._start_ns,
+        if start_ns is not None and prof is not None and prof._recording:
+            prof._timeline.add(_Event(self.name, start_ns,
                                       time.perf_counter_ns(),
-                                      threading.get_ident(), self.event_type))
-        self._start_ns = None
+                                      threading.get_ident(), self.event_type,
+                                      dict(self.facts)))
 
     def __enter__(self) -> "RecordEvent":
         self.begin()
@@ -230,6 +253,12 @@ class RecordEvent:
 
     def __exit__(self, *exc) -> None:
         self.end()
+
+
+def span(name: str, **facts) -> RecordEvent:
+    """``with span("serve.decode", rows=5):`` — a :class:`RecordEvent`
+    whose keyword arguments are the scope's facts."""
+    return RecordEvent(name, "UserDefined", **facts)
 
 
 class Profiler:

@@ -74,6 +74,8 @@ import numpy as np
 from ..distributed.checkpoint import faults as _faults
 from ..distributed.checkpoint.replicator import env_int as _env_int
 from ..distributed.fleet.fault_domain import _env_float
+from ..jit import named_program
+from ..profiler import span as _span
 from ..telemetry import record_event as _event
 from ..telemetry import tracing
 from ..telemetry.runtime import bump as _bump
@@ -90,6 +92,13 @@ from .prefix_cache import PrefixCache
 __all__ = ["Request", "ServingEngine", "check_decode_donation"]
 
 QUEUED, RUNNING, FINISHED, SHED = "queued", "running", "finished", "shed"
+
+# The engine's compiled programs under names of their own: XLA calls the
+# module ``jit_<name>``, and that is what a device trace's ``XLA Modules``
+# line (and the benchmark's ``_decode_fn`` / ``_prefill_fn`` patterns) shows.
+DECODE_PROGRAM = "serve_decode_fn"
+PREFILL_PROGRAM = "serve_prefill_fn"
+CP_PREFILL_PROGRAM = "serve_cp_prefill_fn"
 
 # Tracing a program swaps tracers into the model's param Tensors
 # (``_StateSwap`` in ``_forward``), so two engines sharing one model object
@@ -702,23 +711,25 @@ class ServingEngine:
         After ``PADDLE_TPU_SERVE_MAX_STEP_FAILURES`` consecutive failures
         the error propagates."""
         self.steps_total += 1
-        try:
-            did_work = self._step_inner()
-        except OSError as e:
-            self._step_failures += 1
-            self.admission.breaker.note_failure()
-            _event("serve_step_error", type(e).__name__,
-                        error=repr(e)[:200],
-                        consecutive=self._step_failures)
-            _bump("serving.step_failures_total")
-            if self._step_failures >= self._max_step_failures:
-                raise
-            return
-        if did_work:
-            self._step_failures = 0
-            self.admission.breaker.note_success()
-            if self.first_step_wall is None:
-                self.first_step_wall = time.time()
+        with _span("serve.step", step=self.steps_total,
+                   active=len(self._active), queued=len(self._queue)):
+            try:
+                did_work = self._step_inner()
+            except OSError as e:
+                self._step_failures += 1
+                self.admission.breaker.note_failure()
+                _event("serve_step_error", type(e).__name__,
+                       error=repr(e)[:200],
+                       consecutive=self._step_failures)
+                _bump("serving.step_failures_total")
+                if self._step_failures >= self._max_step_failures:
+                    raise
+                return
+            if did_work:
+                self._step_failures = 0
+                self.admission.breaker.note_success()
+                if self.first_step_wall is None:
+                    self.first_step_wall = time.time()
 
     def _undelivered(self) -> bool:
         """Tokens or journal records still awaiting a successful flush."""
@@ -726,12 +737,20 @@ class ServingEngine:
             self.journal is not None and self.journal.pending > 0)
 
     def _step_inner(self) -> bool:
-        self._shed_scan()
-        self._admit()
+        with _span("serve.shed_scan"):
+            self._shed_scan()
+        with _span("serve.admit") as sp:
+            occupied = len(self._active)
+            self._admit()
+            sp.note(admitted=len(self._active) - occupied)
         did_work = self._undelivered()   # a retried flush is real work:
         # succeeding must reset the failure streak and close the breaker
         for r in [r for r in self._active.values() if not r.generated]:
-            self._prefill(r)
+            with _span("serve.prefill", rid=r.rid, trace=r.trace_id or "",
+                       prompt_tokens=len(r.prompt),
+                       chunks=-(-len(r.prompt) // self.page_tokens),
+                       cached_tokens=r.cached_tokens):
+                self._prefill(r)
             did_work = True
             self._retire_if_done(r)
         if self._active:
@@ -1085,26 +1104,31 @@ class ServingEngine:
         # cap guarantees c0 < n_chunks — the last prompt token's logits
         # are always computed fresh
         c0 = min(r.cached_tokens // self.page_tokens, n_chunks - 1)
-        if self._cp_accepts(len(prompt), cached_tokens=r.cached_tokens):
-            logits = self._cp_prefill_run(prompt, self.pool.table(r.rid))
-        else:
-            table = jnp.asarray(self._padded_table(r.rid)[None])
-            logits = self._prefill_chunks(prompt, table, c0)
-        tok = int(np.argmax(np.asarray(logits)))
-        r.generated.append(tok)
-        self.meter.first_token(r.rid)
-        self._deliver(r, tok)
-        if self.prefix is not None:
-            # register this prompt's FULL pages for future requests (the
-            # chunks matched at admission just get their LRU refreshed)
-            self.prefix.insert(r.prompt, self.pool.table(r.rid))
-        if self.spec is not None:
-            # (re)build the drafter here so eviction replay and crash
-            # recovery get a fresh one primed with exactly the tokens a
-            # first-admission drafter would have seen
-            r.drafter = self.spec.make_drafter()
-            r.drafter.begin([int(t) for t in r.prompt])
-            r.drafter.observe([tok])
+        with _span("serve.prefill.dispatch"):
+            if self._cp_accepts(len(prompt), cached_tokens=r.cached_tokens):
+                logits = self._cp_prefill_run(prompt, self.pool.table(r.rid))
+            else:
+                table = jnp.asarray(self._padded_table(r.rid)[None])
+                logits = self._prefill_chunks(prompt, table, c0)
+        with _span("serve.prefill.to_host"):
+            logits = np.asarray(logits)
+        with _span("serve.prefill.sample"):
+            tok = int(np.argmax(logits))
+            r.generated.append(tok)
+            self.meter.first_token(r.rid)
+            self._deliver(r, tok)
+            if self.prefix is not None:
+                # register this prompt's FULL pages for future requests
+                # (the chunks matched at admission just get their LRU
+                # refreshed)
+                self.prefix.insert(r.prompt, self.pool.table(r.rid))
+            if self.spec is not None:
+                # (re)build the drafter here so eviction replay and crash
+                # recovery get a fresh one primed with exactly the tokens
+                # a first-admission drafter would have seen
+                r.drafter = self.spec.make_drafter()
+                r.drafter.begin([int(t) for t in r.prompt])
+                r.drafter.observe([tok])
 
     def _import_kv(self, r: Request) -> None:
         """Disaggregated admission (ISSUE 19 leg 2): instead of running
@@ -1208,6 +1232,33 @@ class ServingEngine:
         program), and the causal mask hides anything beyond its window."""
         import jax.numpy as jnp
 
+        with _span("serve.decode") as sp:
+            with _span("serve.decode.prep"):
+                batch = self._decode_prep()
+            if batch is None:
+                sp.note(rows=0, n_tok=0)
+                for r in list(self._active.values()):
+                    self._retire_if_done(r)
+                return
+            stepped, tokens, n_tok, positions, tables, drafts = batch
+            sp.note(rows=len(stepped), n_tok=int(n_tok.sum()))
+            _faults.fire("serve_decode", f"step{self.steps_total}")
+            _faults.fire("slow_serve", f"{self.fault_scope}/decode")
+            with _span("serve.decode.dispatch"):
+                logits = self._run_decode(jnp.asarray(tokens),
+                                          jnp.asarray(positions),
+                                          jnp.asarray(tables),
+                                          jnp.asarray(n_tok))
+            with _span("serve.decode.to_host") as to_host:
+                logits = np.asarray(logits)               # [R, S, V]
+                to_host.note(bytes=logits.nbytes)
+            self.last_decode_logits = logits
+            with _span("serve.decode.sample"):
+                self._decode_sample(stepped, logits, n_tok, drafts)
+
+    def _decode_prep(self):
+        """Host-side inputs of one decode step: ``(stepped rows, tokens,
+        n_tok, positions, tables, drafts)``, or None when no row steps."""
         R, MP, S = self.max_batch, self.max_pages_per_seq, self._spec_width
         tokens = np.zeros((R, S), np.int32)
         positions = np.zeros((R,), np.int32)
@@ -1242,17 +1293,10 @@ class ServingEngine:
             tables[row] = self._padded_table(r.rid)
             stepped.append(r)
         if not stepped:
-            for r in list(self._active.values()):
-                self._retire_if_done(r)
-            return
-        _faults.fire("serve_decode", f"step{self.steps_total}")
-        _faults.fire("slow_serve", f"{self.fault_scope}/decode")
-        logits = self._run_decode(jnp.asarray(tokens),
-                                  jnp.asarray(positions),
-                                  jnp.asarray(tables),
-                                  jnp.asarray(n_tok))
-        logits = np.asarray(logits)                       # [R, S, V]
-        self.last_decode_logits = logits
+            return None
+        return stepped, tokens, n_tok, positions, tables, drafts
+
+    def _decode_sample(self, stepped, logits, n_tok, drafts) -> None:
         proposed_total = accepted_total = emitted_total = 0
         for r in stepped:
             nv = int(n_tok[r.row])
@@ -1319,22 +1363,23 @@ class ServingEngine:
         disk BEFORE any of the tokens they cover reach the sink.  On a
         flush failure everything stays pending — the step-failure path
         retries, and a crash instead re-generates the tokens exactly."""
-        if self.journal is not None:
-            self.journal.flush()
-        if self._on_token is not None:
-            for rid, idx, tok in self._pending_delivery:
-                self._on_token(rid, idx, tok)
-        if self._pending_delivery:
-            # one deliver span per request per flush (not per token): the
-            # trace shows WHEN tokens became client-visible, the journal
-            # holds the per-token detail
-            per_rid: Dict[int, int] = {}
-            for rid, _idx, _tok in self._pending_delivery:
-                per_rid[rid] = per_rid.get(rid, 0) + 1
+        per_rid: Dict[int, int] = {}
+        for rid, _idx, _tok in self._pending_delivery:
+            per_rid[rid] = per_rid.get(rid, 0) + 1
+        with _span("serve.deliver", requests=len(per_rid),
+                   tokens=len(self._pending_delivery)):
+            if self.journal is not None:
+                self.journal.flush()
+            if self._on_token is not None:
+                for rid, idx, tok in self._pending_delivery:
+                    self._on_token(rid, idx, tok)
+            # one flight-recorder instant per request per flush (not per
+            # token): it shows WHEN tokens became client-visible, the
+            # journal holds the per-token detail
             for rid, n in per_rid.items():
                 _event("serve_deliver", str(rid), tokens=n,
                        trace=self.meter.trace_of(rid))
-        self._pending_delivery.clear()
+            self._pending_delivery.clear()
 
     def recover(self) -> dict:
         """Replay the journal into this (fresh) engine after a crash:
@@ -1604,10 +1649,11 @@ class ServingEngine:
         return jax.device_put(x, NamedSharding(self._mesh,
                                                PartitionSpec()))
 
-    def _compile(self, fn, args):
-        """Lower and compile one engine program (arenas donated).  Under
-        a TP / CP mesh GSPMD partitions it over a mesh the kernel
-        wrappers do not know, which the Pallas dispatchers are told."""
+    def _compile(self, fn, args, name: str):
+        """Lower and compile one engine program (arenas donated) as the
+        module ``jit_<name>``.  Under a TP / CP mesh GSPMD partitions it
+        over a mesh the kernel wrappers do not know, which the Pallas
+        dispatchers are told."""
         import contextlib
 
         import jax
@@ -1616,8 +1662,9 @@ class ServingEngine:
 
         scope = gspmd_program() if self._mesh is not None \
             else contextlib.nullcontext()
-        with _SWAP_LOCK, scope:
-            return jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+        with _span("serve.compile", program=name), _SWAP_LOCK, scope:
+            return jax.jit(named_program(fn, name), donate_argnums=(2,)) \
+                .lower(*args).compile()
 
     def _run_decode(self, tokens, positions, tables, n_tok):
         pa, ba = self._param_arrays()
@@ -1626,7 +1673,8 @@ class ServingEngine:
                 self._repl(n_tok))
         if self._decode_exec is None:
             self._decode_compiles += 1
-            self._decode_exec = self._compile(self._decode_fn, args)
+            self._decode_exec = self._compile(self._decode_fn, args,
+                                               DECODE_PROGRAM)
             if self._lint:
                 self.lint_report = check_decode_donation(
                     self._decode_exec, self._arena_bytes,
@@ -1640,7 +1688,8 @@ class ServingEngine:
                 self._repl(chunk_start), self._repl(tables),
                 self._repl(take_idx))
         if self._prefill_exec is None:
-            self._prefill_exec = self._compile(self._prefill_fn, args)
+            self._prefill_exec = self._compile(self._prefill_fn, args,
+                                                PREFILL_PROGRAM)
         logits, self._arenas = self._prefill_exec(*args)
         return logits
 
@@ -1786,7 +1835,8 @@ class ServingEngine:
         sig = int(tokens.shape[1])
         exec_ = self._cp_execs.get(sig)
         if exec_ is None:
-            exec_ = self._compile(self._cp_prefill_fn, args)
+            exec_ = self._compile(self._cp_prefill_fn, args,
+                                  CP_PREFILL_PROGRAM)
             self._cp_execs[sig] = exec_
             if self._lint:
                 # arenas are replicated over the ring (shards=1: every
