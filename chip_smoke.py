@@ -16,7 +16,8 @@ from a seed):
 - generate   ``model.generate()`` (compiled static-cache loop) at a 2K and
              at an 8K cache, Pallas decode kernel against the einsum path
 - serve      ``ServingEngine`` over 8 requests, first tokens against
-             ``generate()`` and a plain forward
+             ``generate()`` and a plain forward; the decode program holds
+             one ``paged_decode_attention`` call a layer
 - four_chips (only when the host holds >= 4 chips) ``DistributedTrainStep``
              over ``LlamaForCausalLMHybrid`` under mp2 x pp2 and
              sharding2 x sep2 (ZeRO-3)
@@ -261,6 +262,56 @@ def _attention_parity(sz: Sizes, batch: int, cache_len: int) -> float:
     return err
 
 
+def _paged_parity(sz: Sizes) -> float:
+    """``paged_decode_attention`` on one random decode step over the serve
+    phase's pool (ragged rows, the last one idle), against the gather of
+    every row's whole padded table and a dense masked softmax."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas import paged_decode_attention
+
+    R, P = sz.serve_max_batch, sz.serve_page_tokens
+    MP, N = sz.serve_pages_per_seq, sz.serve_pages
+    d = sz.hidden // sz.heads
+    dt = jnp.bfloat16 if sz.amp else jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(keys[0], (R, 1, sz.heads, d)).astype(dt)
+    k, v = (jax.random.normal(key, (N, P, sz.kv_heads, d)).astype(dt)
+            for key in keys[1:])
+    positions = np.asarray([sz.serve_prompts[r % len(sz.serve_prompts)]
+                            for r in range(R)], np.int32)
+    n_tok = np.ones((R,), np.int32)
+    n_tok[-1] = positions[-1] = 0
+    tables, page = np.zeros((R, MP), np.int32), 1
+    for r in range(R):
+        for j in range(-(-(positions[r] + n_tok[r]) // P)):
+            tables[r, j], page = page, page + 1
+    require(page <= N, "the serve pool cannot hold the parity rows")
+    got = jax.jit(lambda *a: paged_decode_attention(
+        *a, interpret=not sz.mosaic))(q, k, v, tables, positions, n_tok)
+
+    def dense(q, k, v, tables, positions):
+        g = sz.heads // sz.kv_heads
+        kk, vv = (x[tables].reshape(R, MP * P, sz.kv_heads, d)
+                  for x in (k, v))
+        s = jnp.einsum("bkgd,bckd->bkgc",
+                       q.reshape(R, sz.kv_heads, g, d), kk,
+                       preferred_element_type=jnp.float32) / math.sqrt(d)
+        s = jnp.where(jnp.arange(MP * P)[None, None, None, :]
+                      <= positions[:, None, None, None], s, -jnp.inf)
+        return jnp.einsum("bkgc,bckd->bkgd",
+                          jax.nn.softmax(s, -1).astype(dt), vv,
+                          preferred_element_type=jnp.float32) \
+            .reshape(R, 1, sz.heads, d)
+
+    want = jax.jit(dense)(q, k, v, tables, positions)
+    require(not bool(jnp.any(got[-1] != 0)), "the idle row is not zero")
+    return _close("paged_decode_attention", got[:-1], want[:-1],
+                  2e-2 if sz.amp else 2e-5)
+
+
 def kernels(sz: Sizes) -> dict:
     """Every Pallas kernel a default-on flag dispatches to, alone, at the
     shapes the other phases use plus the seq-8192 train point
@@ -338,6 +389,11 @@ def kernels(sz: Sizes) -> dict:
         report[f"decode_{cache}"] = _attention_parity(sz, b, cache)
         say("kernels", kernel="decode_attention", batch=b, cache=cache,
             max_err=report[f"decode_{cache}"])
+
+    report["paged_decode"] = _paged_parity(sz)
+    say("kernels", kernel="paged_decode_attention",
+        rows=sz.serve_max_batch, table=sz.serve_pages_per_seq,
+        max_err=report["paged_decode"])
 
     x = rand(20, sz.train_batch, sz.train_seq, sz.hidden)
     w = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(21), (sz.hidden,))
@@ -615,6 +671,15 @@ def serve(sz: Sizes) -> dict:
             f"{eng._decode_compiles} decode compiles, expected one")
     require(eng.lint_report is not None and eng.lint_report.ok,
             "decode program failed the donation lint")
+    # the decode program walks live pages: one Mosaic call a layer
+    # (interpreted kernels leave none to count)
+    if sz.mosaic:
+        calls = len(re.findall(r"%paged_decode_attention[.\d]* = ",
+                               eng._decode_exec.as_text()))
+        say("serve", paged_decode_attention_calls=calls)
+        require(calls == sz.layers,
+                f"{calls} paged_decode_attention calls in the decode "
+                f"program, expected {sz.layers}")
     # the engine raises on a non-finite live row every step; the last
     # step's logits are its host copy
     logits = eng.last_decode_logits

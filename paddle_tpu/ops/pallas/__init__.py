@@ -34,6 +34,8 @@ from .decode_attention import (decode_attention, decode_attention_fp8,
                                decode_attention_int8_supported,
                                decode_attention_sharded_supported,
                                decode_attention_supported)
+from .paged_decode_attention import (paged_decode_attention,
+                                     paged_decode_attention_refusal)
 from .fused_norm import fused_rms_norm
 from .rope import fused_rope
 
@@ -43,4 +45,5 @@ __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention_fp8", "decode_attention_fp8_supported",
            "decode_attention_int8", "decode_attention_int8_supported",
            "decode_attention_sharded_supported",
+           "paged_decode_attention", "paged_decode_attention_refusal",
            "fused_rms_norm", "fused_rope"]

@@ -1236,12 +1236,18 @@ class ServingEngine:
             with _span("serve.decode.prep"):
                 batch = self._decode_prep()
             if batch is None:
-                sp.note(rows=0, n_tok=0)
+                sp.note(rows=0, n_tok=0, live_pages=0, table_pages=0)
                 for r in list(self._active.values()):
                     self._retire_if_done(r)
                 return
             stepped, tokens, n_tok, positions, tables, drafts = batch
-            sp.note(rows=len(stepped), n_tok=int(n_tok.sum()))
+            # live_pages: what the rows' queries can see (idle rows: none),
+            # the decode kernel's own rule; table_pages: what the padded
+            # tables hold
+            live = np.where(n_tok > 0, -(-(positions + n_tok)
+                                         // self.page_tokens), 0)
+            sp.note(rows=len(stepped), n_tok=int(n_tok.sum()),
+                    live_pages=int(live.sum()), table_pages=tables.size)
             _faults.fire("serve_decode", f"step{self.steps_total}")
             _faults.fire("slow_serve", f"{self.fault_scope}/decode")
             with _span("serve.decode.dispatch"):
@@ -1481,9 +1487,12 @@ class ServingEngine:
         return self._arenas["v"]
 
     def _paged_attention(self, q, k_new, v_new, arenas, li, tables,
-                         positions, n_tok):
+                         positions, n_tok, walk=None):
         """Scatter this step's k/v into layer ``li``'s page arenas and
-        attend each row over its gathered pages.  ``n_tok`` [R] is the
+        attend each row over its pages: gathered by the whole padded table
+        for the einsum below (prefill; decode where no kernel runs), or,
+        with ``walk`` (:meth:`_page_walk`: the decode program on a TPU),
+        only the live ones, read in place by ``paged_decode_attention``.  ``n_tok`` [R] is the
         per-row count of VALID tokens in the s-window (speculative verify
         rows carry 1 + k_r; idle rows 0) — invalid slots scatter to the
         trash page.  Mirrors ``generation.cached_attention``'s grouped
@@ -1525,6 +1534,13 @@ class ServingEngine:
         else:
             kp = kp.at[page, slot].set(k_new.astype(kp.dtype))
             vp = vp.at[page, slot].set(v_new.astype(vp.dtype))
+        if walk is not None:
+            from ..ops.pallas.paged_decode_attention import \
+                paged_decode_attention
+
+            out = paged_decode_attention(q, kp, vp, tables, positions, n_tok,
+                                         **walk)
+            return out, {"k": kp, "v": vp}
         C = MP * P
         if quant:
             kk = dequantize_kv(kp[tables].reshape(R, C, kv, d),
@@ -1560,11 +1576,12 @@ class ServingEngine:
         return out, new
 
     def _forward(self, param_arrays, buffer_arrays, arenas, tokens,
-                 positions, tables, n_tok):
+                 positions, tables, n_tok, walk=None):
         """Shared transformer step for both programs.  ``tokens`` [R, s]
         (decode/verify: s=spec width; prefill: R=1, s=page_tokens);
         ``positions`` [R] absolute position of each row's first token;
-        ``n_tok`` [R] valid tokens per row (rest scatter to trash)."""
+        ``n_tok`` [R] valid tokens per row (rest scatter to trash);
+        ``walk``: :meth:`_paged_attention`'s."""
         import jax.numpy as jnp
 
         from ..autograd import no_grad
@@ -1598,7 +1615,7 @@ class ServingEngine:
                 qv, kv_ = rotate_half_apply(q._value, k._value, cos_s, sin_s)
                 out_v, new = self._paged_attention(
                     qv, kv_, v._value, arenas, li, tables, positions,
-                    n_tok)
+                    n_tok, walk)
                 for key in new:
                     new_arenas[key].append(new[key])
                 x = x + layer.self_attn.o_proj(
@@ -1618,8 +1635,46 @@ class ServingEngine:
         ``n_tok`` carries each row's live width — adapting k never
         recompiles.  Returns logits [R, S, V]."""
         logits, arenas = self._forward(param_arrays, buffer_arrays, arenas,
-                                       tokens, positions, tables, n_tok)
+                                       tokens, positions, tables, n_tok,
+                                       self._page_walk(*tokens.shape))
         return logits, arenas
+
+    def _page_walk(self, rows: int, width: int):
+        """How the decode program attends, decided at trace time by what
+        the engine can observe: the keyword arguments of
+        ``paged_decode_attention`` where the Pallas dispatch is local (a
+        TPU, or the interpreter), the pages hold the compute dtype and the
+        kernel's gate takes the shape; else None, the gather + einsum — with
+        a ``kernel_fallback`` event wherever a kernel could have run.  Many
+        short rows against the prefill's one row of ``page_tokens``
+        queries: the two programs share the mask rule and nothing else, so
+        prefill keeps the einsum."""
+        from ..ops import pallas_mode
+        from ..ops.pallas.paged_decode_attention import (
+            KERNEL_NAME, paged_decode_attention_refusal)
+
+        mode = pallas_mode("use_decode_attention")
+        if mode is None:
+            return None
+        kind, _, interpret = mode
+        cfg = self.model.config
+        pages = self._arenas["k"][0]
+        if kind != "local":
+            reason = "hybrid_mesh"
+        elif self.kv_dtype != "bf16":
+            reason = "kv_dtype"
+        else:
+            reason = paged_decode_attention_refusal(
+                (rows, width, cfg.num_attention_heads, cfg.head_dim),
+                pages.shape, (rows, self.max_pages_per_seq), pages.dtype,
+                interpret=interpret)
+        if reason is None:
+            return {"interpret": interpret}
+        from ..telemetry import kernel_fallback
+
+        kernel_fallback(KERNEL_NAME, reason, kv_dtype=self.kv_dtype,
+                        rows=rows, width=width)
+        return None
 
     def _prefill_fn(self, param_arrays, buffer_arrays, arenas, tokens,
                     chunk_start, tables, take_idx):

@@ -1,0 +1,229 @@
+"""The page-walking decode kernel against the gather + einsum it replaces in
+the serving engine's decode program (interpret mode, CPU).
+
+Kernel level: ``paged_decode_attention`` must give what
+``ServingEngine._paged_attention``'s einsum gives on every VALID query of
+every row (a row's queries past ``n_tok`` are junk on both paths), zero for
+idle rows, and never touch a page past a row's live ones.  Engine level: a
+tiny Llama served with ``pallas_interpret`` on emits the einsum engine's
+tokens from one decode compile, and quantized pools fall back loudly.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+import paddle_tpu.telemetry as telemetry
+from paddle_tpu.models import LlamaForCausalLM, llama_tiny
+from paddle_tpu.ops.pallas import (paged_decode_attention,
+                                   paged_decode_attention_refusal)
+from paddle_tpu.ops.pallas.paged_decode_attention import KERNEL_NAME
+from paddle_tpu.serving import ServingEngine, TRASH_PAGE
+
+pytestmark = pytest.mark.serving
+
+P, MP, N = 8, 5, 40           # page tokens, table slots a row, pool pages
+
+
+def einsum_attention(q, k, v, tables, positions):
+    """``_paged_attention`` after its scatter: gather the whole padded
+    table, dense scores, causal mask, softmax."""
+    R, s, h, d = q.shape
+    kv = k.shape[2]
+    C = tables.shape[1] * k.shape[1]
+    kk = k[tables].reshape(R, C, kv, d)
+    vv = v[tables].reshape(R, C, kv, d)
+    q5 = q.reshape(R, s, kv, h // kv, d).astype(kk.dtype)
+    scores = jnp.einsum("bskgd,bckd->bkgsc", q5, kk,
+                        preferred_element_type=jnp.float32) \
+        / jnp.sqrt(float(d))
+    pos_js = positions[:, None] + jnp.arange(s)[None, :]
+    scores = jnp.where(jnp.arange(C)[None, None, None, None, :]
+                       <= pos_js[:, None, None, :, None], scores,
+                       jnp.finfo(jnp.float32).min)
+    out = jnp.einsum("bkgsc,bckd->bskgd",
+                     jax.nn.softmax(scores, axis=-1).astype(vv.dtype), vv,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(R, s, h, d).astype(q.dtype)
+
+
+# rows of one decode step as (position, n_tok); n_tok 0 is an idle row
+ROWS = {
+    "one_live_page": [(3, 1)],
+    "page_boundary": [(P - 1, 1), (P, 1), (2 * P - 1, 1), (2 * P, 1)],
+    "full_table": [(MP * P - 1, 1)],
+    "idle_row": [(0, 0), (11, 1), (0, 0)],
+    "ragged_batch": [(0, 1), (MP * P - 1, 1), (0, 0), (17, 1), (P, 1)],
+    "all_idle": [(0, 0), (0, 0)],
+}
+SPEC_ROWS = {
+    # S = 3: a row's queries straddle a page boundary; a short row carries
+    # fewer valid queries than the width
+    "speculative": [(P - 2, 3), (20, 1), (0, 0), (MP * P - 3, 3), (5, 2)],
+}
+
+
+def _case(rows, width, h, kv, d, dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    R = len(rows)
+    q = jnp.asarray(rng.normal(size=(R, width, h, d)), dtype)
+    # the pool is POISONED where no row lives: a page the kernel must not
+    # visit (trash page, free pages) would show as NaN
+    k = np.full((N, P, kv, d), np.nan, np.float32)
+    v = np.full((N, P, kv, d), np.nan, np.float32)
+    tables = np.full((R, MP), TRASH_PAGE, np.int32)
+    free = list(rng.permutation(np.arange(1, N)))
+    for r, (pos, n) in enumerate(rows):
+        if n == 0:
+            continue
+        for j in range(-(-(pos + n) // P)):
+            page = free.pop()
+            tables[r, j] = page
+            k[page] = rng.normal(size=(P, kv, d))
+            v[page] = rng.normal(size=(P, kv, d))
+    positions = np.asarray([p for p, _ in rows], np.int32)
+    n_tok = np.asarray([n for _, n in rows], np.int32)
+    return (q, jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+            jnp.asarray(tables), jnp.asarray(positions), jnp.asarray(n_tok))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [(32, 8), (4, 4)], ids=["gqa32_8", "mha"])
+@pytest.mark.parametrize("name,width", [(n, 1) for n in ROWS]
+                         + [(n, 3) for n in SPEC_ROWS])
+def test_kernel_matches_einsum(name, width, heads, dtype):
+    h, kv = heads
+    rows = (ROWS | SPEC_ROWS)[name]
+    q, k, v, tables, positions, n_tok = _case(rows, width, h, kv, 16, dtype)
+    assert paged_decode_attention_refusal(
+        q.shape, k.shape, tables.shape, dtype, interpret=True) is None
+    got = np.asarray(paged_decode_attention(
+        q, k, v, tables, positions, n_tok, interpret=True), np.float32)
+    # the einsum sees every slot of the padded table: give it a pool with
+    # the poison cleared (masked columns must be finite there)
+    clear = lambda a: jnp.nan_to_num(a, nan=0.0)
+    want = np.asarray(einsum_attention(q, clear(k), clear(v), tables,
+                                       positions), np.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-6
+    assert np.all(np.isfinite(got)), "a page outside a row's live ones was read"
+    for r, (_, n) in enumerate(rows):
+        if n == 0:
+            assert not got[r].any()
+        else:
+            np.testing.assert_allclose(got[r, :n], want[r, :n], atol=tol,
+                                       rtol=tol)
+
+
+@pytest.mark.parametrize("case,reason", [
+    (dict(d=16, interpret=False), "head_dim"),       # one lane wide on chip
+    (dict(d=128, interpret=False), None),
+    (dict(h=6, kv=4), "shape"),
+    (dict(kv=1, dtype=jnp.bfloat16, page=8), "page_rows"),
+    (dict(rows=512, slots=64), "table_size"),
+    (dict(rows=256, width=8, h=32, kv=8, d=128, page=128), "vmem"),
+])
+def test_gate(case, reason):
+    c = dict(rows=4, width=1, h=8, kv=4, d=16, page=8, slots=MP,
+             dtype=jnp.float32, interpret=True) | case
+    assert paged_decode_attention_refusal(
+        (c["rows"], c["width"], c["h"], c["d"]),
+        (N, c["page"], c["kv"], c["d"]), (c["rows"], c["slots"]),
+        c["dtype"], interpret=c["interpret"]) == reason
+
+
+# -- the engine -------------------------------------------------------------
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(5)
+    m = LlamaForCausalLM(llama_tiny(num_hidden_layers=2, vocab_size=96,
+                                    max_position_embeddings=128))
+    m.eval()
+    return m
+
+
+@pytest.fixture
+def interpreted():
+    from paddle_tpu.distributed import topology
+
+    prior = paddle.get_flags(["pallas_interpret"])
+    prior_hcg = topology.get_hybrid_communicate_group()
+    topology._hcg = None          # an earlier distributed test's mesh
+    paddle.set_flags({"pallas_interpret": True})
+    telemetry.reset()
+    yield
+    paddle.set_flags(prior)
+    topology._hcg = prior_hcg
+
+
+def _serve(model, **engine_kw):
+    eng = ServingEngine(model, max_batch=3, page_tokens=8, num_pages=24,
+                        max_pages_per_seq=6, **engine_kw)
+    rng = np.random.default_rng(1)
+    rids = [eng.submit(rng.integers(1, 96, n).astype(np.int32),
+                       max_new_tokens=new)
+            for n, new in ((5, 12), (9, 20), (16, 7), (3, 30))]
+    outs = eng.run()
+    return eng, [outs[r].tolist() for r in rids]
+
+
+def _fallbacks():
+    return {k: v for k, v in telemetry.counters().items()
+            if k.startswith(f"kernel_fallback.{KERNEL_NAME}")}
+
+
+def test_engine_tokens_match_einsum_engine(model, interpreted):
+    eng, got = _serve(model)
+    paddle.set_flags({"pallas_interpret": False})
+    eng_plain, plain = _serve(model)
+    paddle.set_flags({"pallas_interpret": True})
+    assert got == plain
+    assert eng._decode_compiles == eng_plain._decode_compiles == 1
+    assert not _fallbacks()
+    # the kernel is in the interpreted engine's decode program and not in
+    # the plain one's, nor in any prefill program
+    pa, ba = eng._param_arrays()
+    args = (pa, ba, eng._arenas, jnp.zeros((3, 1), jnp.int32),
+            jnp.zeros((3,), jnp.int32), jnp.zeros((3, 6), jnp.int32),
+            jnp.ones((3,), jnp.int32))
+    assert KERNEL_NAME in str(jax.make_jaxpr(eng._decode_fn)(*args))
+    paddle.set_flags({"pallas_interpret": False})
+    assert KERNEL_NAME not in str(jax.make_jaxpr(eng._decode_fn)(*args))
+    paddle.set_flags({"pallas_interpret": True})
+    prefill = (pa, ba, eng._arenas, jnp.zeros((1, 8), jnp.int32),
+               jnp.int32(0), jnp.zeros((1, 6), jnp.int32), jnp.int32(7))
+    assert KERNEL_NAME not in str(jax.make_jaxpr(eng._prefill_fn)(*prefill))
+
+
+@pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
+def test_quantized_pools_fall_back_loudly(model, interpreted, kv_dtype):
+    eng, got = _serve(model, kv_dtype=kv_dtype)
+    assert all(len(g) for g in got) and eng._decode_compiles == 1
+    assert _fallbacks() == {f"kernel_fallback.{KERNEL_NAME}.kv_dtype": 1}
+
+
+def test_live_hybrid_mesh_falls_back_loudly(model, interpreted):
+    """The engine's program is a one-device program: with a hybrid mesh
+    live the dispatch says "mesh", and the decode program gathers."""
+    import paddle_tpu.distributed as dist
+    from paddle_tpu.distributed import topology
+
+    strategy = dist.fleet.DistributedStrategy()
+    strategy.hybrid_configs = {"dp_degree": -1}
+    dist.fleet.init(is_collective=True, strategy=strategy)
+    try:
+        eng, got = _serve(model)
+    finally:
+        topology._hcg = None
+    assert all(len(g) for g in got) and eng._decode_compiles == 1
+    assert _fallbacks() == {f"kernel_fallback.{KERNEL_NAME}.hybrid_mesh": 1}
+
+
+def test_speculative_width_walks_pages(model, interpreted):
+    eng, got = _serve(model, speculative=2)
+    paddle.set_flags({"pallas_interpret": False})
+    _, plain = _serve(model, speculative=2)
+    assert got == plain and eng._decode_compiles == 1
+    assert not _fallbacks()

@@ -33,7 +33,8 @@ SPANS = {
     "serve.prefill.dispatch": ("serve.prefill", set()),
     "serve.prefill.to_host": ("serve.prefill", set()),
     "serve.prefill.sample": ("serve.prefill", set()),
-    "serve.decode": ("serve.step", {"rows", "n_tok"}),
+    "serve.decode": ("serve.step", {"rows", "n_tok", "live_pages",
+                                    "table_pages"}),
     "serve.decode.prep": ("serve.decode", set()),
     "serve.decode.dispatch": ("serve.decode", set()),
     "serve.decode.to_host": ("serve.decode", {"bytes"}),
@@ -154,6 +155,17 @@ def recorded(engine_model, tmp_path_factory):
     rng = np.random.default_rng(0)
     ring_before = [e["kind"] for e in
                    telemetry.get_flight_recorder().events()]
+    # the pool's own count of the stepped rows' pages, decode step by step
+    pool_pages, prep = [], eng._decode_prep
+
+    def counted_prep():
+        batch = prep()
+        if batch is not None:
+            pool_pages.append(sum(len(eng.pool.table(r.rid))
+                                  for r in batch[0]))
+        return batch
+
+    eng._decode_prep = counted_prep
 
     out = str(tmp_path_factory.mktemp("xplane"))
     jax.profiler.start_trace(out)
@@ -174,7 +186,7 @@ def recorded(engine_model, tmp_path_factory):
         spans.setdefault(s.name, []).append((s.start, s.end, dict(s.facts)))
     ring_after = [e["kind"] for e in telemetry.get_flight_recorder().events()]
     return {"spans": spans, "engine": eng, "results": results,
-            "losses": losses, "ring_kinds": set(ring_after) - set(ring_before)}
+            "losses": losses, "pool_pages": pool_pages, "ring_kinds": set(ring_after) - set(ring_before)}
 
 
 @pytest.mark.parametrize("name", sorted(SPANS))
@@ -218,6 +230,17 @@ def test_serving_span_counts_match_steps_and_requests(recorded):
         {eng.max_batch * 1 * vocab * 4}
     assert sum(s[2]["tokens"] for s in spans["serve.deliver"]) == \
         len(PROMPTS) * NEW_TOKENS
+
+
+def test_decode_span_counts_live_pages_as_the_pool_does(recorded):
+    eng = recorded["engine"]
+    ran = [s[2] for s in recorded["spans"]["serve.decode"] if s[2]["rows"]]
+    assert [s["live_pages"] for s in ran] == recorded["pool_pages"]
+    for s in ran:
+        assert s["rows"] <= s["live_pages"] <= s["table_pages"] \
+            == eng.max_batch * eng.max_pages_per_seq
+    # prompts of 5, 9 and 3 tokens on pages of 4 grow past a page boundary
+    assert len({s["live_pages"] for s in ran}) > 1
 
 
 def test_prefill_span_carries_the_requests_trace_id(recorded):

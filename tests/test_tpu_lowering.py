@@ -30,7 +30,9 @@ from paddle_tpu.ops.pallas import (decode_attention,
                                    flash_attention, flash_attention_supported,
                                    flash_attention_varlen,
                                    flash_attention_varlen_supported,
-                                   fused_rms_norm, fused_rope)
+                                   fused_rms_norm, fused_rope,
+                                   paged_decode_attention,
+                                   paged_decode_attention_refusal)
 
 BF16 = jnp.bfloat16
 # Llama-670M widths (bench.py, chip_smoke.py); depth cut to two layers
@@ -46,12 +48,48 @@ def tpu_text(fn, *args, **jit_kw) -> str:
 
 def kernels_in(text: str) -> dict:
     names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rms_norm_fwd",
-             "rms_norm_bwd", "fused_rope", "decode_attention")
+             "rms_norm_bwd", "fused_rope", "decode_attention",
+             "paged_decode_attention")
     return {n: text.count(f'kernel_name = "{n}"') for n in names}
 
 
 def sds(*shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# the page-walking decode kernel at the benchmark's serving cells (Mistral:
+# GQA 32/8, 64 rows x 20 slots of 900 pages; 16 x 33 of 545), at
+# chip_smoke.py's serve phase (MHA 16/16) and at a speculative width
+PAGED_SHAPES = [(64, 1, (32, 8), 20, 900), (16, 1, (32, 8), 33, 545),
+                (8, 1, (16, 16), 16, 129), (64, 3, (32, 8), 20, 900)]
+PAGED_IDS = ["chat", "docqa", "smoke", "speculative"]
+
+
+def paged_args(rows, width, heads, slots, pages):
+    h, kv = heads
+    q, arena = sds(rows, width, h, 128), sds(pages, 128, kv, 128)
+    assert paged_decode_attention_refusal(
+        q.shape, arena.shape, (rows, slots), BF16) is None
+    return (q, arena, arena, sds(rows, slots, dtype=jnp.int32),
+            sds(rows, dtype=jnp.int32), sds(rows, dtype=jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e host: the TPU compiler is installed
+    here and compiles for it with no chip attached."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -133,6 +171,40 @@ class TestKernelsLower:
             tpu_text(lambda q, kn, vn, ck, cv: decode_attention(
                 q, kn, vn, ck, cv, 3, None, block_k=64),
                 q, kn, kn, cache, cache)
+
+    @pytest.mark.parametrize("rows,width,heads,slots,pages", PAGED_SHAPES,
+                             ids=PAGED_IDS)
+    def test_paged_decode_attention(self, rows, width, heads, slots, pages):
+        text = tpu_text(paged_decode_attention,
+                        *paged_args(rows, width, heads, slots, pages))
+        assert kernels_in(text)["paged_decode_attention"] == 1
+
+    @pytest.mark.parametrize("rows,width,heads,slots,pages", PAGED_SHAPES,
+                             ids=PAGED_IDS)
+    def test_paged_decode_attention_compiles_for_a_v5e(
+            self, one_chip, rows, width, heads, slots, pages):
+        """The whole way through Mosaic and XLA's TPU compiler, for a chip
+        that is described and not attached: VMEM, DMA and layouts, which
+        the cross-lowering above does not see.  The arenas' ``[N, P*kv,
+        d]`` view must stay a bitcast: a copy of the pool a layer would
+        cost more than the gather the kernel replaced."""
+        from jax.experimental.compilation_cache import compilation_cache
+
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                for a in paged_args(rows, width, heads, slots, pages)]
+        cache_was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            text = jax.jit(paged_decode_attention).lower(*args).compile() \
+                .as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_was)
+            compilation_cache.reset_cache()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        pool = f"bf16[{pages},128,"
+        assert not [line for line in text.splitlines()
+                    if pool in line and " copy(" in line], "the pool is copied"
 
     def test_rms_norm_and_rope(self):
         x, w = sds(4, 2048, 2048), sds(2048, dtype=jnp.float32)
@@ -218,11 +290,17 @@ class TestProgramsLower:
             jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
             tables, jnp.ones((R,), jnp.int32), donate_argnums=(2,))
         assert kernels_in(decode)["rms_norm_fwd"] >= 2
+        # decode walks live pages (one kernel, lowered once and called by
+        # every layer); prefill gathers
+        assert kernels_in(decode)["paged_decode_attention"] == 1
+        assert decode.count("call @paged_decode_attention") == \
+            WIDTHS["num_hidden_layers"]
         prefill = tpu_text(
             eng._prefill_fn, pa, ba, eng._arenas,
             jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
             jnp.int32(P - 1), donate_argnums=(2,))
         assert kernels_in(prefill)["rms_norm_fwd"] >= 2
+        assert kernels_in(prefill)["paged_decode_attention"] == 0
 
     def test_generate_decode_step(self, eval_model):
         """One decode step of ``generate()``'s loop — the model called the
