@@ -1,4 +1,5 @@
-"""Model families: Llama (flagship), GPT, ERNIE. Vision models live in
+"""Model families: Llama (flagship), GPT, ERNIE, Granite-4.0-H (Mamba-2 +
+attention hybrid). Vision models live in
 paddle_tpu.vision.models."""
 
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel, llama2_7b, llama2_13b,  # noqa: F401
@@ -6,3 +7,6 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel, llama2_7b, llama2
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel, gpt2_small, gpt3_1p3b, gpt_tiny  # noqa: F401
 from .ernie import (ErnieConfig, ErnieForMaskedLM, ErnieForSequenceClassification,  # noqa: F401
                     ErnieModel, ernie3_base, ernie_tiny)
+from .serve_protocol import AttentionLayer, StateLayer  # noqa: F401
+from .granite_hybrid import (GraniteHybridConfig, GraniteHybridForCausalLM,  # noqa: F401
+                             GraniteHybridModel, granite_hybrid_tiny)

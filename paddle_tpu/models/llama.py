@@ -24,6 +24,7 @@ from ..generation import GenerationMixin
 from ..nn import functional as F
 from ..tensor.manipulation import reshape
 from ..tensor.tensor import Tensor, apply_op
+from .serve_protocol import AttentionLayer
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama_tiny", "llama2_7b",
            "llama2_13b", "llama2_70b", "llama_moe_tiny", "mixtral_8x7b"]
@@ -392,6 +393,47 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
                 loss = loss + 0.01 * self.moe_aux_loss()
             return loss, logits
         return logits
+
+    # -- what ServingEngine asks of a model (serve_protocol.py) ------------
+    def serve_layers(self):
+        cfg = self.config
+        return [AttentionLayer(cfg.num_attention_heads,
+                               cfg.num_key_value_heads, cfg.head_dim)
+                for _ in self.llama.layers]
+
+    def serve_begin(self, tokens, positions):
+        """``tokens`` [R, s] ids, ``positions`` [R] the absolute position of
+        each row's first token: the embeddings, and the rows' rotary tables
+        that every layer shares."""
+        base = self.llama
+        s = tokens.shape[1]
+        cos, sin = base.rope_cos._value, base.rope_sin._value
+        pos_ids = jnp.clip(positions[:, None] + jnp.arange(s)[None, :],
+                           0, cos.shape[0] - 1)          # [R, s]
+        cos_s = jnp.take(cos, pos_ids, axis=0)[:, :, None, :]
+        sin_s = jnp.take(sin, pos_ids, axis=0)[:, :, None, :]
+        return base.embed_tokens(tokens), (cos_s, sin_s)
+
+    def serve_layer(self, i, x, shared, io):
+        layer = self.llama.layers[i]
+        cfg = self.config
+        h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                     cfg.head_dim)
+        R, s = x.shape[0], x.shape[1]
+        xin = layer.input_layernorm(x)
+        q = reshape(layer.self_attn.q_proj(xin), [R, s, h, d])
+        k = reshape(layer.self_attn.k_proj(xin), [R, s, kvh, d])
+        v = reshape(layer.self_attn.v_proj(xin), [R, s, kvh, d])
+        qv, kv_ = rotate_half_apply(q._value, k._value, *shared)
+        out_v = io.attend(qv, kv_, v._value)
+        x = x + layer.self_attn.o_proj(Tensor(out_v.reshape(R, s, h * d)))
+        return x + layer.mlp(layer.post_attention_layernorm(x))
+
+    def serve_end(self, x):
+        hidden = self.llama.norm(x)
+        if self.lm_head is not None:
+            return self.lm_head(hidden)
+        return F.linear(hidden, self.llama.embed_tokens.weight.T)
 
     def moe_aux_loss(self):
         """Sum of the routers' load-balance losses from the last forward

@@ -36,6 +36,7 @@ from .decode_attention import (decode_attention, decode_attention_fp8,
                                decode_attention_supported)
 from .paged_decode_attention import (paged_decode_attention,
                                      paged_decode_attention_refusal)
+from .ssm_state_update import ssm_state_update, ssm_state_update_refusal
 from .fused_norm import fused_rms_norm
 from .rope import fused_rope
 
@@ -46,4 +47,5 @@ __all__ = ["flash_attention", "flash_attention_supported",
            "decode_attention_int8", "decode_attention_int8_supported",
            "decode_attention_sharded_supported",
            "paged_decode_attention", "paged_decode_attention_refusal",
+           "ssm_state_update", "ssm_state_update_refusal",
            "fused_rms_norm", "fused_rope"]

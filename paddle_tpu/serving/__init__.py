@@ -60,6 +60,8 @@ from .kv_quant import (FP8_MAX, KV_DTYPES, default_fp8_scale,  # noqa: F401
                        dequantize_kv, dequantize_kv_fp8, kv_cache_dtype,
                        kv_page_bytes, kv_scale_page_bytes,
                        observe_kv_absmax, quantize_kv, quantize_kv_fp8)
+from ..models.serve_protocol import AttentionLayer, StateLayer  # noqa: F401
+from .state_pool import RowStatePool, StateLayersUnsupported  # noqa: F401
 from .metrics import FleetMeter, RequestClock, SLOMeter  # noqa: F401
 from .admission import (AdmissionController, CircuitBreaker, Deadline,  # noqa: F401
                         Overloaded)
@@ -80,6 +82,7 @@ from .disagg import (DisaggCoordinator, PrefillWorker,  # noqa: F401
 __all__ = [
     "PagedKVPool", "PoolExhausted", "TRASH_PAGE", "default_page_tokens",
     "OffloadPool", "default_offload_pages",
+    "AttentionLayer", "StateLayer", "RowStatePool", "StateLayersUnsupported",
     "KV_DTYPES", "kv_cache_dtype", "quantize_kv", "dequantize_kv",
     "quantize_kv_fp8", "dequantize_kv_fp8", "default_fp8_scale", "FP8_MAX",
     "observe_kv_absmax", "kv_page_bytes", "kv_scale_page_bytes",

@@ -10,10 +10,19 @@ the Pallas decode kernel / grouped einsum), the paged
 Design (TPU-first: *nothing* recompiles as traffic changes shape):
 
 - **Physical cache** — per layer, ``k_pages``/``v_pages`` arenas of shape
-  ``[num_pages, page_tokens, kv_heads, head_dim]``.  Both compiled
+  ``[num_pages, page_tokens, kv_heads, head_dim]`` (a token's heads merged,
+  ``[num_pages, page_tokens, kv_heads * head_dim]``, where a head is
+  narrower than the chip's 128 lanes).  Both compiled
   programs take the arenas DONATED, update them with scatter-writes, and
   return them; XLA aliases the buffers so the cache never copies (the
-  donation lint below enforces exactly this).
+  donation lint below enforces exactly this).  A model with state-space
+  layers keeps, beside the pages, FIXED-size state per request (a Mamba-2
+  layer: the convolution's tail and the ``[H, P, N]`` recurrent state):
+  per state layer an arena ``[max_batch, ...]`` indexed by the decode row
+  (:class:`~paddle_tpu.serving.state_pool.RowStatePool`), donated and
+  returned by both programs with the page arenas.  The prefill program
+  zeroes a request's slot when its first page runs and carries the state
+  from page to page; decode updates the live rows' slots in place.
 - **One decode program** per ``(max_batch, pages_per_seq)`` signature:
   every active request is a row; a row's block table gathers its pages
   into a ``[rows, pages_per_seq * page_tokens, kv, d]`` view, masked by
@@ -88,6 +97,8 @@ from .kv_quant import (default_fp8_scale, dequantize_kv, dequantize_kv_fp8,
                        quantize_kv, quantize_kv_fp8)
 from .metrics import SLOMeter
 from .prefix_cache import PrefixCache
+from ..models.serve_protocol import AttentionLayer, StateLayer
+from .state_pool import RowStatePool, StateLayersUnsupported
 
 __all__ = ["Request", "ServingEngine", "check_decode_donation"]
 
@@ -165,7 +176,8 @@ class Request:
 
 def check_decode_donation(compiled, arena_bytes: int,
                           name: str = "serving_decode", *,
-                          scale_bytes: int = 0, shards: int = 1):
+                          scale_bytes: int = 0, shards: int = 1,
+                          state_bytes: int = 0):
     """Shardlint gate for the serving path: run the ``donation`` rule over
     the compiled decode program and additionally require the KV arenas to
     be ALIASED (donated in, updated in place) — an unaliased arena means
@@ -174,6 +186,9 @@ def check_decode_donation(compiled, arena_bytes: int,
     buffers ride the same donation: an unaliased scale arena silently
     copies ``2 * layers * pages * page_tokens * kv_heads`` floats per
     step, so the gate requires ``arena_bytes + scale_bytes`` aliased.
+    ``state_bytes``: the row-state arenas of a model with state layers
+    (:class:`RowStatePool`), which must be aliased all the same — an
+    unaliased one copies every row's recurrent state every step.
     Under a ``shards``-way TP mesh (ISSUE 19) the compiled memory
     analysis is PER DEVICE and the arenas shard evenly over the kv-head
     axis, so each shard must alias its ``1/shards`` slice — the gate
@@ -192,10 +207,13 @@ def check_decode_donation(compiled, arena_bytes: int,
                "argument_bytes": int(ma.argument_size_in_bytes)}
     except Exception:
         pass
-    need = (int(arena_bytes) + int(scale_bytes)) // max(int(shards), 1)
+    need = (int(arena_bytes) + int(scale_bytes)) // max(int(shards), 1) \
+        + int(state_bytes)
     if mem is not None and mem["alias_bytes"] < need:
         what = "KV arenas" if not scale_bytes else \
             "KV arenas + int8 scale buffers"
+        if state_bytes:
+            what += f" + row-state arenas ({state_bytes} bytes)"
         raise RuntimeError(
             f"serving decode program does not alias its {what}: "
             f"{mem['alias_bytes']} bytes aliased < {need} required "
@@ -210,11 +228,74 @@ def check_decode_donation(compiled, arena_bytes: int,
     return report
 
 
+class _LayerIO:
+    """The engine's side of ONE layer inside a traced program: what
+    ``model.serve_layer(i, x, shared, io)`` may touch.  An attention layer
+    calls :meth:`attend`; a state layer reads and writes its rows' slots
+    (:meth:`read_state` / :meth:`write_state`) and looks at ``n_valid``
+    [R] (tokens of each row that are real) and ``live`` [R] (rows that
+    step at all).  The updated arenas land in the program's result."""
+
+    def __init__(self, engine, spec, arenas, index, tables, positions,
+                 n_tok, n_valid, row, fresh):
+        self._eng, self._spec, self._arenas = engine, spec, arenas
+        self._index = index
+        self._tables, self._positions, self._n_tok = tables, positions, n_tok
+        self._row, self._fresh = row, fresh
+        self.n_valid = n_valid
+        self.live = n_valid > 0
+
+    def attend(self, q, k, v):
+        """Scatter this step's ``k`` / ``v`` [R, s, kv, d] into the layer's
+        pages and attend ``q`` [R, s, h, d] over each row's pages."""
+        eng, spec = self._eng, self._spec
+        walk = eng._page_walk(q.shape[0], q.shape[1], spec) \
+            if self._row is None else None      # the decode program
+        out, new = eng._paged_attention(
+            q, k, v, self._arenas, self._index, self._tables,
+            self._positions, self._n_tok, walk, spec.scale)
+        for key, arena in new.items():
+            self._arenas[key][self._index] = arena
+        return out
+
+    def read_state(self, name: str):
+        """The rows' slots of state array ``name``, ``[R, *shape]``: in
+        decode the arena itself (row r is slot r); in prefill the
+        request's slot, zero where its first page is running."""
+        import jax
+        import jax.numpy as jnp
+
+        arena = self._arenas[name][self._index]
+        if self._row is None:
+            return arena
+        cur = jax.lax.dynamic_index_in_dim(arena, self._row, 0,
+                                           keepdims=True)
+        return jnp.where(self._fresh, jnp.zeros_like(cur), cur)
+
+    def write_state(self, name: str, value) -> None:
+        import jax
+
+        arena = self._arenas[name][self._index]
+        value = value.astype(arena.dtype)
+        self._arenas[name][self._index] = value if self._row is None else \
+            jax.lax.dynamic_update_index_in_dim(arena, value[0], self._row,
+                                                0)
+
+
 class ServingEngine:
-    """Continuous batching over a causal-LM with llama-family structure
-    (``model.llama.layers`` / ``embed_tokens`` / ``norm`` / rope buffers;
-    the flagship serving target).  Greedy decoding — determinism is what
-    makes eviction-replay byte-exact."""
+    """Continuous batching over a causal LM that describes itself layer by
+    layer (``serve_layers`` / ``serve_begin`` / ``serve_layer`` /
+    ``serve_end``: :mod:`~paddle_tpu.models.serve_protocol`).
+    The model owns its block math; the engine owns pages, tables, state
+    slots and the two things a layer may ask of it: attend over this
+    layer's pages, read and write this row's state.  ``LlamaForCausalLM``
+    (attention layers only: every feature below) and
+    ``GraniteHybridForCausalLM`` (Mamba-2 state layers beside attention
+    layers: what would need the state snapshotted, moved or rolled back —
+    prefix cache, offload, speculation, TP / CP meshes, quantized pages,
+    disaggregated prefill — raises :class:`StateLayersUnsupported`) are
+    served.  Greedy decoding — determinism is what makes eviction-replay
+    byte-exact."""
 
     def __init__(self, model, *, max_batch: Optional[int] = None,
                  page_tokens: Optional[int] = None,
@@ -231,12 +312,32 @@ class ServingEngine:
 
         from ..generation.speculative import AdaptiveK, SpecConfig
 
-        base = getattr(model, "llama", None)
-        if base is None or not hasattr(base, "layers"):
+        if not all(hasattr(model, m) for m in (
+                "serve_layers", "serve_begin", "serve_layer", "serve_end")):
             raise TypeError(
-                "ServingEngine serves llama-family causal LMs "
-                "(model.llama.layers); got " + type(model).__name__)
+                "ServingEngine serves causal LMs that describe their layers "
+                "to it (serve_layers / serve_begin / serve_layer / "
+                "serve_end, see models/serve_protocol.py: LlamaForCausalLM, "
+                "GraniteHybridForCausalLM); got " + type(model).__name__)
         self.model = model
+        # the model's layers as the engine sees them, and each layer's
+        # index within its own family of arenas
+        self._layers = list(model.serve_layers())
+        att = [sp for sp in self._layers if isinstance(sp, AttentionLayer)]
+        stl = [sp for sp in self._layers if isinstance(sp, StateLayer)]
+        if len(att) + len(stl) != len(self._layers) or not att:
+            raise TypeError(
+                "serve_layers() must name AttentionLayer / StateLayer "
+                "entries, at least one of them an AttentionLayer")
+        if len({(a.kv_heads, a.head_dim) for a in att}) != 1:
+            raise ValueError("every attention layer must keep K/V of one "
+                             "shape (kv_heads, head_dim): one page arena "
+                             "shape serves them all")
+        counts = {AttentionLayer: 0, StateLayer: 0}
+        self._family_index = []
+        for sp in self._layers:
+            self._family_index.append(counts[type(sp)])
+            counts[type(sp)] += 1
         self.max_batch = max_batch if max_batch is not None else \
             _env_int("PADDLE_TPU_SERVE_MAX_BATCH", 4)
         P = page_tokens if page_tokens is not None else default_page_tokens()
@@ -273,8 +374,11 @@ class ServingEngine:
                     if jnp.issubdtype(p._value.dtype, jnp.floating)),
                    jnp.float32)
         self._cdt = cdt
-        n_layers, kv_heads, head_dim = model._kv_cache_spec()
-        self._arena_shape = (N, P, kv_heads, head_dim)
+        n_layers, kv_heads, head_dim = \
+            len(att), att[0].kv_heads, att[0].head_dim
+        # fixed-size state per decode row, for the model's state layers
+        self.state: Optional[RowStatePool] = \
+            RowStatePool(self.max_batch, stl) if stl else None
         # TP-sharded decode (ISSUE 19 leg 1): tp > 1 compiles BOTH
         # programs under a 1-D "model" mesh — params Megatron-sharded in
         # place (q/k/v/gate/up out-dim, o/down in-dim), arenas sharded
@@ -286,9 +390,12 @@ class ServingEngine:
                       else _env_int("PADDLE_TPU_SERVE_TP", 1))
         self._mesh = None
         if self.tp > 1:
+            self._refuse_with_state(
+                "tp > 1", "the state layers' heads are not sharded over a "
+                "model mesh yet")
             from .disagg import decode_mesh, shard_llama_params
 
-            h_att = model.config.num_attention_heads
+            h_att = att[0].heads
             if kv_heads % self.tp or h_att % self.tp:
                 raise ValueError(
                     f"PADDLE_TPU_SERVE_TP={self.tp} must divide both "
@@ -306,6 +413,9 @@ class ServingEngine:
         self.cp = int(cp if cp is not None
                       else _env_int("PADDLE_TPU_SERVE_CP", 1))
         if self.cp > 1:
+            self._refuse_with_state(
+                "cp > 1", "the ring prefill carries no recurrent state "
+                "between its shards")
             import jax as _jax
             from jax.sharding import Mesh as _Mesh
 
@@ -335,7 +445,21 @@ class ServingEngine:
         # inside the same program; "fp8" stores f8e4m3fn pages under ONE
         # static scale baked into the programs (no scale arenas — exactly
         # half the bf16 page bytes)
+        # a page is [P, kv_heads, head_dim]; where a head is narrower than
+        # a lane register (128) the arena keeps a token's heads merged,
+        # [P, kv_heads * head_dim]: the chip tiles the two minor axes, and
+        # around every scatter and gather the compiler re-laid a 64-wide
+        # arena out, a copy of the whole pool each way (PERF.md, PR 26).
+        # Under the TP mesh the kv-head axis stays an axis: it is sharded.
+        self._flat_pages = head_dim % 128 != 0 and self.tp == 1
+        self._page_shape = (P, kv_heads, head_dim)
+        self._arena_shape = (N, P, kv_heads * head_dim) if self._flat_pages \
+            else (N,) + self._page_shape
         self.kv_dtype = kv_cache_dtype(kv_dtype)
+        if self.kv_dtype != "bf16":
+            self._refuse_with_state(
+                f"kv_dtype={self.kv_dtype!r}", "quantized pages beside a "
+                "float32 recurrent state have no measured tolerance yet")
         self._fp8_scale = default_fp8_scale() \
             if self.kv_dtype == "fp8" else None
         adt = (jnp.int8 if self.kv_dtype == "int8"
@@ -364,8 +488,7 @@ class ServingEngine:
             # the per-shard shapes on accel?  (CPU tier-1 always uses the
             # einsum path; a silent per-shard fallback must be visible.)
             decode_attention_sharded_supported(
-                (self.max_batch, 1, model.config.num_attention_heads,
-                 head_dim),
+                (self.max_batch, 1, att[0].heads, head_dim),
                 (self.max_batch, MP * P, kv_heads, head_dim),
                 tp=self.tp, int8=self.kv_dtype == "int8",
                 fp8=self.kv_dtype == "fp8",
@@ -379,9 +502,11 @@ class ServingEngine:
             rep = NamedSharding(self._mesh, PartitionSpec())
             arenas = {key: [_jax.device_put(a, rep) for a in arrs]
                       for key, arrs in arenas.items()}
-        self._arenas = arenas
         self._arena_bytes = 2 * n_layers * int(np.prod(self._arena_shape)) \
             * arenas["k"][0].dtype.itemsize
+        if self.state is not None:
+            arenas.update(self.state.zeros())
+        self._arenas = arenas
         self.pool.set_page_bytes(
             kv_page_bytes(P, kv_heads, head_dim, self.kv_dtype,
                           n_layers=n_layers),
@@ -403,6 +528,11 @@ class ServingEngine:
             prefix_cache = PrefixCache(self.pool, max_pages=prefix_cache)
         self.prefix: Optional[PrefixCache] = \
             prefix_cache if isinstance(prefix_cache, PrefixCache) else None
+        if self.prefix is not None:
+            self._refuse_with_state(
+                "prefix_cache", "a cached prefix's pages would be adopted "
+                "without the recurrent state at their end (no state "
+                "snapshots yet)")
 
         # host-RAM KV offload (long-context ladder): preemption swaps a
         # victim's private pages to the OffloadPool instead of discarding
@@ -420,6 +550,10 @@ class ServingEngine:
             offload = OffloadPool(max_pages=offload)
         self.offload: Optional[OffloadPool] = \
             offload if isinstance(offload, OffloadPool) else None
+        if self.offload is not None:
+            self._refuse_with_state(
+                "offload", "a swapped-out request's recurrent state is not "
+                "spilled with its pages yet")
         self._offload_lost: set = set()   # parked rids whose host frames
         # were LRU-dropped: recall is impossible, re-admission downgrades
         # them to the eviction-replay re-prefill path (the README failure
@@ -439,6 +573,10 @@ class ServingEngine:
             raise TypeError("speculative must be None, an int draft "
                             "length, or a generation.SpecConfig")
         self.spec: Optional[SpecConfig] = speculative
+        if self.spec is not None:
+            self._refuse_with_state(
+                "speculative", "a rejected draft would have to roll the "
+                "recurrent state back")
         self._spec_width = 1 + (self.spec.k if self.spec else 0)
         self._adapt = AdaptiveK(self.spec.k, self.spec.adaptive,
                                 decay=self.spec.ema_decay) \
@@ -468,6 +606,12 @@ class ServingEngine:
         self._defer_lookahead = _env_int(
             "PADDLE_TPU_SERVE_DEFER_LOOKAHEAD", 4)
         self._defer_max = _env_int("PADDLE_TPU_SERVE_DEFER_MAX", 8)
+
+    def _refuse_with_state(self, feature: str, why: str) -> None:
+        """What cannot be right yet for a model with state layers is
+        refused by name, never run wrong."""
+        if self.state is not None:
+            raise StateLayersUnsupported(feature, why)
 
     # -- public API --------------------------------------------------------
     def submit(self, prompt, max_new_tokens: int = 64,
@@ -569,6 +713,9 @@ class ServingEngine:
         would: crash replay re-prefills locally — deterministic greedy
         makes that token-exact even when the frames are long gone, and the
         delivered high-water mark keeps emission exactly-once."""
+        self._refuse_with_state(
+            "submit_prefilled", "the frames carry K/V pages and no "
+            "recurrent state")
         frames = list(kv_frames)
         p = np.asarray(prompt, np.int32).reshape(-1)
         need = self.pool.pages_for(len(p))
@@ -697,6 +844,21 @@ class ServingEngine:
         """Ask a ``forever`` loop to return once it drains to idle."""
         self._stop_flag = True
         self._work.set()
+
+    def row_state(self, rid: int) -> Dict[str, np.ndarray]:
+        """Host copy of a RUNNING request's fixed-size state: per name one
+        array ``[state layers, *shape]``, as the last program left it (what
+        ``last_decode_logits`` is to the logits: the tolerance harness
+        holds the recurrent state itself to the reference, because a state
+        kept in too few bits hides in the logits under the activations' own
+        rounding)."""
+        row = next((row for row, r in self._active.items()
+                    if r.rid == rid), None)
+        if self.state is None or row is None:
+            raise KeyError(f"request {rid} holds no row state")
+        return {name: np.stack([np.asarray(a[row])
+                                for a in self._arenas[name]])
+                for name in self.state.names}
 
     def step(self) -> None:
         """One scheduler iteration: shed what cannot meet its deadline,
@@ -845,6 +1007,10 @@ class ServingEngine:
         r.row = rows.pop(0)
         r.state = RUNNING
         self._active[r.row] = r
+        if self.state is not None:
+            # a state slot is the row; the prefill program zeroes it when
+            # the request's first page runs
+            self.meter.set_state_slots(len(self._active) / self.max_batch)
         self.meter.admit(r.rid, queue_depth=len(self._queue), pages=need)
         self.meter.set_occupancy(self.pool.occupancy())
 
@@ -881,8 +1047,7 @@ class ServingEngine:
         the client already saw are NOT re-delivered — ``delivered`` is the
         high-water mark)."""
         freed = self.pool.free(victim.rid)
-        del self._active[victim.row]
-        victim.row = None
+        self._release_row(victim)
         victim.state = QUEUED
         victim.generated = []        # replayed from the prompt on re-admit
         victim.cached_tokens = 0     # pages went back (trie-pinned ones
@@ -894,6 +1059,13 @@ class ServingEngine:
         self._queue.appendleft(victim)
         self.meter.evict(victim.rid, reason="pool_pressure",
                          pages_freed=freed)
+
+    def _release_row(self, r: Request) -> None:
+        """``r`` leaves its decode row (retired or evicted).  Its state slot
+        goes with the row and is not cleared: the next request's prefill
+        zeroes it, and a replay recomputes from the prompt."""
+        del self._active[r.row]
+        r.row = None
 
     def _preempt(self, victim: Request) -> None:
         """Route a pool-pressure preemption: with a host-RAM offload tier
@@ -1047,8 +1219,7 @@ class ServingEngine:
         if not r.done():
             return
         freed = self.pool.free(r.rid)
-        del self._active[r.row]
-        r.row = None
+        self._release_row(r)
         r.state = FINISHED
         self._results[r.rid] = np.asarray(r.generated, np.int32)
         if self.journal is not None:
@@ -1065,12 +1236,14 @@ class ServingEngine:
         t[:len(pages)] = pages
         return t
 
-    def _prefill_chunks(self, prompt, table, c0: int = 0):
+    def _prefill_chunks(self, prompt, table, c0: int = 0, row: int = 0):
         """Drive the compiled prefill program over ``prompt``'s
         page-sized chunks starting at chunk ``c0``; returns the
         last-prompt-token logits.  Shared by scheduled prefills
         (:meth:`_prefill`, where ``c0`` skips prefix-cached pages) and
-        the standalone :meth:`prefill_export` path."""
+        the standalone :meth:`prefill_export` path.  ``row``: the decode
+        row whose state slot the state layers carry the prompt through;
+        each launch is told how many tokens of its page are real."""
         import jax.numpy as jnp
 
         P = self.page_tokens
@@ -1083,7 +1256,8 @@ class ServingEngine:
             take = (len(prompt) - 1 - c * P) if c == n_chunks - 1 else 0
             logits = self._run_prefill(
                 jnp.asarray(chunk), jnp.int32(c * P), table,
-                jnp.int32(max(take, 0)))
+                jnp.int32(max(take, 0)), jnp.int32(row),
+                jnp.int32(len(part)))
         return logits
 
     def _prefill(self, r: Request) -> None:
@@ -1109,7 +1283,7 @@ class ServingEngine:
                 logits = self._cp_prefill_run(prompt, self.pool.table(r.rid))
             else:
                 table = jnp.asarray(self._padded_table(r.rid)[None])
-                logits = self._prefill_chunks(prompt, table, c0)
+                logits = self._prefill_chunks(prompt, table, c0, r.row)
         with _span("serve.prefill.to_host"):
             logits = np.asarray(logits)
         with _span("serve.prefill.sample"):
@@ -1170,7 +1344,10 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        out = arena.at[idx].set(jnp.asarray(vals).astype(arena.dtype))
+        # frames are ``[pages, P, kv, d]`` whatever this engine's arenas
+        # keep a token as (:meth:`_export_page`)
+        vals = jnp.asarray(vals).reshape((-1,) + arena.shape[1:])
+        out = arena.at[idx].set(vals.astype(arena.dtype))
         if self._mesh is not None:
             out = jax.device_put(out, arena.sharding)
         return out
@@ -1187,6 +1364,9 @@ class ServingEngine:
         scheduled on this engine."""
         import jax.numpy as jnp
 
+        self._refuse_with_state(
+            "prefill_export", "the exported frames carry K/V pages and no "
+            "recurrent state")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -1213,9 +1393,15 @@ class ServingEngine:
             self.pool.free(key)
 
     def _export_page(self, pid: int) -> dict:
-        """Host copy of one physical page across every layer and plane."""
-        return {key: np.stack([np.asarray(a[pid]) for a in arrs])
-                for key, arrs in self._arenas.items()}
+        """Host copy of one physical page across every layer and plane.
+        ONE wire format: a K / V frame is ``[layers, P, kv, d]`` also where
+        the arena keeps a token's heads merged, so frames move between
+        engines of either layout (a tp=1 prefill tier into a TP decode
+        tier, a journal read back under another tp)."""
+        return {key: np.stack([
+            np.asarray(a[pid]).reshape(self._page_shape)
+            if key in ("k", "v") else np.asarray(a[pid]) for a in arrs])
+            for key, arrs in self._arenas.items()}
 
     def _decode_step(self) -> None:
         """One verify-wide decode step.  Serial mode (spec off) is the
@@ -1236,7 +1422,8 @@ class ServingEngine:
             with _span("serve.decode.prep"):
                 batch = self._decode_prep()
             if batch is None:
-                sp.note(rows=0, n_tok=0, live_pages=0, table_pages=0)
+                sp.note(rows=0, n_tok=0, live_pages=0, table_pages=0,
+                        state_rows=0)
                 for r in list(self._active.values()):
                     self._retire_if_done(r)
                 return
@@ -1246,8 +1433,11 @@ class ServingEngine:
             # tables hold
             live = np.where(n_tok > 0, -(-(positions + n_tok)
                                          // self.page_tokens), 0)
+            # state_rows: rows whose recurrent state the step updates
             sp.note(rows=len(stepped), n_tok=int(n_tok.sum()),
-                    live_pages=int(live.sum()), table_pages=tables.size)
+                    live_pages=int(live.sum()), table_pages=tables.size,
+                    state_rows=len(stepped) if self.state is not None
+                    else 0)
             _faults.fire("serve_decode", f"step{self.steps_total}")
             _faults.fire("slow_serve", f"{self.fault_scope}/decode")
             with _span("serve.decode.dispatch"):
@@ -1487,12 +1677,14 @@ class ServingEngine:
         return self._arenas["v"]
 
     def _paged_attention(self, q, k_new, v_new, arenas, li, tables,
-                         positions, n_tok, walk=None):
+                         positions, n_tok, walk=None, scale=None):
         """Scatter this step's k/v into layer ``li``'s page arenas and
         attend each row over its pages: gathered by the whole padded table
         for the einsum below (prefill; decode where no kernel runs), or,
         with ``walk`` (:meth:`_page_walk`: the decode program on a TPU),
-        only the live ones, read in place by ``paged_decode_attention``.  ``n_tok`` [R] is the
+        only the live ones, read in place by ``paged_decode_attention``.
+        ``scale`` multiplies the scores (None: ``1 / sqrt(d)``; the kernel
+        knows only that one).  ``n_tok`` [R] is the
         per-row count of VALID tokens in the s-window (speculative verify
         rows carry 1 + k_r; idle rows 0) — invalid slots scatter to the
         trash page.  Mirrors ``generation.cached_attention``'s grouped
@@ -1512,6 +1704,10 @@ class ServingEngine:
         kp, vp = arenas["k"][li], arenas["v"][li]
         quant = self.kv_dtype == "int8"
         fp8 = self.kv_dtype == "fp8"
+
+        def rows(x):        # [R, s, kv, d] as the arena keeps a token
+            return x.reshape(R, s, kv * d) if self._flat_pages else x
+
         pos_js = positions[:, None] + jnp.arange(s)[None, :]      # [R, s]
         valid = jnp.arange(s)[None, :] < n_tok[:, None]           # [R, s]
         page = jnp.take_along_axis(tables,
@@ -1521,25 +1717,27 @@ class ServingEngine:
         if quant:
             kq, ksc = quantize_kv(k_new)        # [R,s,kv] scales
             vq, vsc = quantize_kv(v_new)
-            kp = kp.at[page, slot].set(kq)
-            vp = vp.at[page, slot].set(vq)
+            kp = kp.at[page, slot].set(rows(kq))
+            vp = vp.at[page, slot].set(rows(vq))
             ksp = arenas["ks"][li].at[page, slot].set(ksc)
             vsp = arenas["vs"][li].at[page, slot].set(vsc)
         elif fp8:
             # static scale: quantize on the scatter, no scale planes
             kp = kp.at[page, slot].set(
-                quantize_kv_fp8(k_new, self._fp8_scale))
+                rows(quantize_kv_fp8(k_new, self._fp8_scale)))
             vp = vp.at[page, slot].set(
-                quantize_kv_fp8(v_new, self._fp8_scale))
+                rows(quantize_kv_fp8(v_new, self._fp8_scale)))
         else:
-            kp = kp.at[page, slot].set(k_new.astype(kp.dtype))
-            vp = vp.at[page, slot].set(v_new.astype(vp.dtype))
+            kp = kp.at[page, slot].set(rows(k_new).astype(kp.dtype))
+            vp = vp.at[page, slot].set(rows(v_new).astype(vp.dtype))
         if walk is not None:
             from ..ops.pallas.paged_decode_attention import \
                 paged_decode_attention
 
-            out = paged_decode_attention(q, kp, vp, tables, positions, n_tok,
-                                         **walk)
+            shape = (kp.shape[0], P, kv, d)     # the kernel's view of it
+            out = paged_decode_attention(q, kp.reshape(shape),
+                                         vp.reshape(shape), tables,
+                                         positions, n_tok, **walk)
             return out, {"k": kp, "v": vp}
         C = MP * P
         if quant:
@@ -1560,8 +1758,9 @@ class ServingEngine:
         g = h // kv
         q5 = q.reshape(R, s, kv, g, d).astype(kk.dtype)
         scores = jnp.einsum("bskgd,bckd->bkgsc", q5, kk,
-                            preferred_element_type=jnp.float32) \
-            / jnp.sqrt(float(d))
+                            preferred_element_type=jnp.float32)
+        scores = scores / jnp.sqrt(float(d)) if scale is None \
+            else scores * float(scale)
         col = jnp.arange(C)[None, None, None, None, :]
         row_pos = pos_js[:, None, None, :, None]
         scores = jnp.where(col <= row_pos, scores,
@@ -1576,56 +1775,33 @@ class ServingEngine:
         return out, new
 
     def _forward(self, param_arrays, buffer_arrays, arenas, tokens,
-                 positions, tables, n_tok, walk=None):
-        """Shared transformer step for both programs.  ``tokens`` [R, s]
+                 positions, tables, n_tok, *, n_valid=None, row=None,
+                 fresh=None):
+        """Shared model step for both programs: ONE walk over the model's
+        layers, each given its :class:`_LayerIO`.  ``tokens`` [R, s]
         (decode/verify: s=spec width; prefill: R=1, s=page_tokens);
         ``positions`` [R] absolute position of each row's first token;
-        ``n_tok`` [R] valid tokens per row (rest scatter to trash);
-        ``walk``: :meth:`_paged_attention`'s."""
-        import jax.numpy as jnp
-
+        ``n_tok`` [R] tokens per row whose K/V are kept (rest scatter to
+        trash); ``n_valid`` [R] tokens per row that are real (None:
+        ``n_tok``) — what a state layer may let into its state; ``row``
+        (prefill): the decode row whose state slot the one prompt row uses,
+        zeroed first where ``fresh``; None (decode): row r is slot r."""
         from ..autograd import no_grad
         from ..jit import _StateSwap
-        from ..models.llama import rotate_half_apply
-        from ..nn import functional as F
-        from ..tensor.manipulation import reshape
         from ..tensor.tensor import Tensor
 
         model = self.model
+        new_arenas = {key: list(arrs) for key, arrs in arenas.items()}
         with _StateSwap(self._params, param_arrays), \
                 _StateSwap(self._buffers, buffer_arrays), no_grad():
-            base = model.llama
-            R, s = tokens.shape
-            cfg = model.config
-            h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                         cfg.head_dim)
-            cos = base.rope_cos._value
-            sin = base.rope_sin._value
-            pos_ids = jnp.clip(positions[:, None] + jnp.arange(s)[None, :],
-                               0, cos.shape[0] - 1)          # [R, s]
-            cos_s = jnp.take(cos, pos_ids, axis=0)[:, :, None, :]
-            sin_s = jnp.take(sin, pos_ids, axis=0)[:, :, None, :]
-            x = base.embed_tokens(Tensor(tokens))
-            new_arenas = {key: [] for key in arenas}
-            for li, layer in enumerate(base.layers):
-                xin = layer.input_layernorm(x)
-                q = reshape(layer.self_attn.q_proj(xin), [R, s, h, d])
-                k = reshape(layer.self_attn.k_proj(xin), [R, s, kvh, d])
-                v = reshape(layer.self_attn.v_proj(xin), [R, s, kvh, d])
-                qv, kv_ = rotate_half_apply(q._value, k._value, cos_s, sin_s)
-                out_v, new = self._paged_attention(
-                    qv, kv_, v._value, arenas, li, tables, positions,
-                    n_tok, walk)
-                for key in new:
-                    new_arenas[key].append(new[key])
-                x = x + layer.self_attn.o_proj(
-                    Tensor(out_v.reshape(R, s, h * d)))
-                x = x + layer.mlp(layer.post_attention_layernorm(x))
-            hidden = base.norm(x)
-            if model.lm_head is not None:
-                logits = model.lm_head(hidden)
-            else:
-                logits = F.linear(hidden, base.embed_tokens.weight.T)
+            x, shared = model.serve_begin(Tensor(tokens), positions)
+            for li, spec in enumerate(self._layers):
+                io = _LayerIO(self, spec, new_arenas,
+                              self._family_index[li], tables, positions,
+                              n_tok, n_tok if n_valid is None else n_valid,
+                              row, fresh)
+                x = model.serve_layer(li, x, shared, io)
+            logits = model.serve_end(x)
             return logits._value, new_arenas
 
     def _decode_fn(self, param_arrays, buffer_arrays, arenas, tokens,
@@ -1634,18 +1810,17 @@ class ServingEngine:
         fixed speculative width (1 + k_max; 1 when speculation is off) and
         ``n_tok`` carries each row's live width — adapting k never
         recompiles.  Returns logits [R, S, V]."""
-        logits, arenas = self._forward(param_arrays, buffer_arrays, arenas,
-                                       tokens, positions, tables, n_tok,
-                                       self._page_walk(*tokens.shape))
-        return logits, arenas
+        return self._forward(param_arrays, buffer_arrays, arenas, tokens,
+                             positions, tables, n_tok)
 
-    def _page_walk(self, rows: int, width: int):
-        """How the decode program attends, decided at trace time by what
-        the engine can observe: the keyword arguments of
+    def _page_walk(self, rows: int, width: int, layer: AttentionLayer):
+        """How one attention layer of the decode program attends, decided at
+        trace time by what the engine can observe: the keyword arguments of
         ``paged_decode_attention`` where the Pallas dispatch is local (a
-        TPU, or the interpreter), the pages hold the compute dtype and the
-        kernel's gate takes the shape; else None, the gather + einsum — with
-        a ``kernel_fallback`` event wherever a kernel could have run.  Many
+        TPU, or the interpreter), the pages hold the compute dtype, the
+        kernel's gate takes the shape and the layer scales its scores as
+        the kernel does; else None, the gather + einsum — with a
+        ``kernel_fallback`` event wherever a kernel could have run.  Many
         short rows against the prefill's one row of ``page_tokens``
         queries: the two programs share the mask rule and nothing else, so
         prefill keeps the einsum."""
@@ -1657,17 +1832,21 @@ class ServingEngine:
         if mode is None:
             return None
         kind, _, interpret = mode
-        cfg = self.model.config
         pages = self._arenas["k"][0]
+        page_shape = (self.num_pages, self.page_tokens, layer.kv_heads,
+                      layer.head_dim)
         if kind != "local":
             reason = "hybrid_mesh"
         elif self.kv_dtype != "bf16":
             reason = "kv_dtype"
         else:
             reason = paged_decode_attention_refusal(
-                (rows, width, cfg.num_attention_heads, cfg.head_dim),
-                pages.shape, (rows, self.max_pages_per_seq), pages.dtype,
+                (rows, width, layer.heads, layer.head_dim),
+                page_shape, (rows, self.max_pages_per_seq), pages.dtype,
                 interpret=interpret)
+            if reason is None and layer.scale is not None and \
+                    layer.scale != layer.head_dim ** -0.5:
+                reason = "scale"
         if reason is None:
             return {"interpret": interpret}
         from ..telemetry import kernel_fallback
@@ -1677,13 +1856,21 @@ class ServingEngine:
         return None
 
     def _prefill_fn(self, param_arrays, buffer_arrays, arenas, tokens,
-                    chunk_start, tables, take_idx):
+                    chunk_start, tables, take_idx, row=None, n_valid=None):
+        """ONE compiled prefill signature: one page of one prompt.  ``row``
+        / ``n_valid``: the request's decode row and the page's real tokens,
+        read by state layers only (the K/V of the last page's junk tail is
+        harmless: decode overwrites it before the position mask exposes
+        it; a recurrence would swallow it into the state)."""
         import jax.numpy as jnp
 
         positions = chunk_start[None]                 # [1]
         n_tok = jnp.full((1,), tokens.shape[1], jnp.int32)  # full chunk
-        logits, arenas = self._forward(param_arrays, buffer_arrays, arenas,
-                                       tokens, positions, tables, n_tok)
+        logits, arenas = self._forward(
+            param_arrays, buffer_arrays, arenas, tokens, positions, tables,
+            n_tok, n_valid=None if n_valid is None else n_valid[None],
+            row=jnp.int32(0) if row is None else row,
+            fresh=chunk_start == 0)
         return jnp.take(logits[0], take_idx, axis=0), arenas
 
     def _param_arrays(self):
@@ -1733,15 +1920,17 @@ class ServingEngine:
             if self._lint:
                 self.lint_report = check_decode_donation(
                     self._decode_exec, self._arena_bytes,
-                    scale_bytes=self._scale_bytes, shards=self.tp)
+                    scale_bytes=self._scale_bytes, shards=self.tp,
+                    state_bytes=self.state.nbytes if self.state else 0)
         logits, self._arenas = self._decode_exec(*args)
         return logits
 
-    def _run_prefill(self, tokens, chunk_start, tables, take_idx):
+    def _run_prefill(self, tokens, chunk_start, tables, take_idx, row,
+                     n_valid):
         pa, ba = self._param_arrays()
         args = (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(chunk_start), self._repl(tables),
-                self._repl(take_idx))
+                self._repl(take_idx), self._repl(row), self._repl(n_valid))
         if self._prefill_exec is None:
             self._prefill_exec = self._compile(self._prefill_fn, args,
                                                 PREFILL_PROGRAM)
@@ -1822,6 +2011,11 @@ class ServingEngine:
             page_idx = jnp.take(tables[0], pos // P)              # [s]
             slot = pos % P
             new_arenas = {key: [] for key in arenas}
+
+            def page_rows(x):   # the one row's [s, kv, d] as the arena
+                return x[0].reshape(s, kvh * d) if self._flat_pages \
+                    else x[0]   # keeps a token (see __init__)
+
             for li, layer in enumerate(base.layers):
                 xin = layer.input_layernorm(x)
                 q = reshape(layer.self_attn.q_proj(xin), [R, s, h, d])
@@ -1838,8 +2032,8 @@ class ServingEngine:
                 if self.kv_dtype == "int8":
                     kq, ksc = quantize_kv(kv_)
                     vq, vsc = quantize_kv(vv)
-                    kp = kp.at[page_idx, slot].set(kq[0])
-                    vp = vp.at[page_idx, slot].set(vq[0])
+                    kp = kp.at[page_idx, slot].set(page_rows(kq))
+                    vp = vp.at[page_idx, slot].set(page_rows(vq))
                     new_arenas["ks"].append(
                         arenas["ks"][li].at[page_idx, slot].set(ksc[0]))
                     new_arenas["vs"].append(
@@ -1849,15 +2043,17 @@ class ServingEngine:
                 elif self.kv_dtype == "fp8":
                     kq = quantize_kv_fp8(kv_, self._fp8_scale)
                     vq = quantize_kv_fp8(vv, self._fp8_scale)
-                    kp = kp.at[page_idx, slot].set(kq[0])
-                    vp = vp.at[page_idx, slot].set(vq[0])
+                    kp = kp.at[page_idx, slot].set(page_rows(kq))
+                    vp = vp.at[page_idx, slot].set(page_rows(vq))
                     k_att = dequantize_kv_fp8(
                         kq, self._fp8_scale).astype(self._cdt)
                     v_att = dequantize_kv_fp8(
                         vq, self._fp8_scale).astype(self._cdt)
                 else:
-                    kp = kp.at[page_idx, slot].set(kv_[0].astype(kp.dtype))
-                    vp = vp.at[page_idx, slot].set(vv[0].astype(vp.dtype))
+                    kp = kp.at[page_idx, slot].set(
+                        page_rows(kv_).astype(kp.dtype))
+                    vp = vp.at[page_idx, slot].set(
+                        page_rows(vv).astype(vp.dtype))
                     k_att = kv_.astype(kp.dtype)
                     v_att = vv.astype(vp.dtype)
                 new_arenas["k"].append(kp)

@@ -113,6 +113,8 @@ class SLOMeter:
         self._t_first_submit: Optional[float] = None
         self._t_last_finish: Optional[float] = None
         self.occupancy_peak = 0.0
+        self.state_slots_peak = None     # share of max_batch; None: the
+        # model has no state layers
         self.finished_total = 0
         self.evictions_total = 0
         self.shed_total = 0
@@ -402,6 +404,13 @@ class SLOMeter:
         self.occupancy_peak = max(self.occupancy_peak, float(frac))
         set_gauge("serving.kv_pool_occupancy", float(frac))
 
+    def set_state_slots(self, frac: float) -> None:
+        """Share of the row-state slots (one a decode row, ``RowStatePool``)
+        in use: the engine's active rows over ``max_batch``."""
+        self.state_slots_peak = max(self.state_slots_peak or 0.0,
+                                    float(frac))
+        set_gauge("serving.state_slots_occupancy", float(frac))
+
     def set_kv_bytes_per_token(self, b: float) -> None:
         """HBM bytes one KV token slot costs (arena + scales, all layers)
         — the denominator the int8-page halving shows up in."""
@@ -476,6 +485,8 @@ class SLOMeter:
             "deadline_miss_rate": round(self.deadline_miss_rate(), 4),
             "evictions": self.evictions_total,
             "kv_pool_occupancy_peak": round(self.occupancy_peak, 4),
+            "state_slots_peak": (None if self.state_slots_peak is None
+                                 else round(self.state_slots_peak, 4)),
             "spec_acceptance": (round(self.spec_acceptance(), 4)
                                 if self.spec_verify_steps else None),
             "effective_tokens_per_step": (

@@ -463,6 +463,39 @@ class TestDisaggE2E:
         dec.pool.check_leaks()
         pre.pool.check_leaks()
 
+    @pytest.mark.parametrize("pre_tp,dec_tp", [(1, 2), (2, 1)])
+    def test_frames_cross_arena_layouts(self, pre_tp, dec_tp):
+        """One wire format: at tp=1 the arenas keep a token's 64-wide heads
+        merged ``[N, P, kv * d]``, under the TP mesh the kv-head axis stays
+        an axis (it is sharded).  Frames exported by an engine of one
+        layout are imported by one of the other, token-exact."""
+        import jax
+
+        if len(jax.devices()) < 2:
+            pytest.skip("needs >= 2 (virtual) devices")
+
+        def build():    # TP shards the params in place: a model an engine
+            paddle.seed(5)
+            m = LlamaForCausalLM(llama_tiny(
+                hidden_size=256, vocab_size=96, max_position_embeddings=128))
+            m.eval()
+            return m
+
+        assert build().config.head_dim == 64
+        pre = ServingEngine(build(), tp=pre_tp, **KW)
+        dec = ServingEngine(build(), tp=dec_tp, **KW)
+        assert pre._arenas["k"][0].ndim != dec._arenas["k"][0].ndim
+        prompt = np.asarray(
+            np.random.default_rng(2).integers(1, 96, 21), np.int32)
+        first, frames = pre.prefill_export(prompt)
+        assert frames[0]["k"].shape == (2, KW["page_tokens"], 2, 64)
+        rid = dec.submit_prefilled(prompt, first, frames, max_new_tokens=5)
+        outs = dec.run()
+        np.testing.assert_array_equal(outs[rid],
+                                      _expect(build(), prompt, 5))
+        dec.pool.check_leaks()
+        pre.pool.check_leaks()
+
     def test_short_prompts_never_pay_the_network_leg(self, model, depot):
         rng = np.random.default_rng(4)
         pre = ServingEngine(model, **KW)

@@ -49,12 +49,33 @@ def tpu_text(fn, *args, **jit_kw) -> str:
 def kernels_in(text: str) -> dict:
     names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rms_norm_fwd",
              "rms_norm_bwd", "fused_rope", "decode_attention",
-             "paged_decode_attention")
+             "paged_decode_attention", "ssm_state_update")
     return {n: text.count(f'kernel_name = "{n}"') for n in names}
 
 
 def sds(*shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _compile_uncached(jitted, *args):
+    """Compile for the described chip with the persistent cache off (what
+    is compiled here for a TPU cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jitted.lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+def _copies_of(text: str, shape: str):
+    return [line.strip()[:160] for line in text.splitlines()
+            if shape in line.split(" = ", 1)[-1][:len(shape) + 2]
+            and (" copy(" in line or " copy-start(" in line)]
 
 
 # the page-walking decode kernel at the benchmark's serving cells (Mistral:
@@ -102,6 +123,13 @@ def on_tpu(monkeypatch):
     from paddle_tpu.distributed import topology
 
     monkeypatch.setattr(topology, "_hcg", None)
+    # an earlier file of this worker may have left the interpreter on
+    # (tests/test_analysis.py sets it and does not give it back): an
+    # interpreted kernel is no Mosaic call, and nothing here would find it
+    interpreted = paddle.get_flags("pallas_interpret")
+    paddle.set_flags({"pallas_interpret": False})
+    yield
+    paddle.set_flags(interpreted)
 
 
 class TestKernelsLower:
@@ -188,23 +216,39 @@ class TestKernelsLower:
         the cross-lowering above does not see.  The arenas' ``[N, P*kv,
         d]`` view must stay a bitcast: a copy of the pool a layer would
         cost more than the gather the kernel replaced."""
-        from jax.experimental.compilation_cache import compilation_cache
-
         args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
                 for a in paged_args(rows, width, heads, slots, pages)]
-        cache_was = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()
-        try:
-            text = jax.jit(paged_decode_attention).lower(*args).compile() \
-                .as_text()
-        finally:
-            jax.config.update("jax_enable_compilation_cache", cache_was)
-            compilation_cache.reset_cache()
+        text = _compile_uncached(jax.jit(paged_decode_attention),
+                                 *args).as_text()
         assert text.count('custom_call_target="tpu_custom_call"') == 1
         pool = f"bf16[{pages},128,"
         assert not [line for line in text.splitlines()
                     if pool in line and " copy(" in line], "the pool is copied"
+
+    def test_ssm_state_update_compiles_for_a_v5e(self, one_chip):
+        """The decode state update at granite-4.0-h-micro's shapes (64 rows
+        of ``[64, 64, 128]`` float32), through Mosaic and XLA's TPU
+        compiler for a described chip: one kernel, the arena aliased to
+        its output and never copied."""
+        from paddle_tpu.ops.pallas.ssm_state_update import (
+            ssm_state_update, ssm_state_update_refusal)
+
+        R, H, P, N = 64, 64, 64, 128
+        shapes = [((R, H, P, N), jnp.float32), ((R,), jnp.bool_),
+                  ((R, H, P), BF16), ((R, H), jnp.float32), ((H,), BF16),
+                  ((R, 1, N), BF16), ((R, 1, N), BF16), ((H,), BF16)]
+        assert ssm_state_update_refusal(shapes[0][0], jnp.float32,
+                                        (R, 1, N)) is None
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        compiled = _compile_uncached(
+            jax.jit(lambda *a: ssm_state_update(*a), donate_argnums=(0,)),
+            *args)
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        arena = R * H * P * N * 4
+        assert compiled.memory_analysis().alias_size_in_bytes >= arena
+        assert not _copies_of(text, f"f32[{R},{H},{P},{N}]")
 
     def test_rms_norm_and_rope(self):
         x, w = sds(4, 2048, 2048), sds(2048, dtype=jnp.float32)
@@ -325,3 +369,80 @@ class TestProgramsLower:
                         [b.value for b in buffers],
                         jnp.zeros((8, 1), jnp.int32), cache, jnp.int32(128))
         assert kernels_in(text)["decode_attention"] == cfg.num_hidden_layers
+
+
+@pytest.mark.usefixtures("on_tpu")
+class TestStateLayerProgramsLower:
+    """The serving decode program of a model with state layers (Granite-4.0-H
+    widths, one Mamba-2 / attention / Mamba-2 stretch, a small vocabulary),
+    compiled for a described v5e."""
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        from paddle_tpu.models import (GraniteHybridConfig,
+                                       GraniteHybridForCausalLM)
+        from paddle_tpu.serving import ServingEngine
+
+        paddle.seed(0)
+        model = GraniteHybridForCausalLM(GraniteHybridConfig(
+            vocab_size=1024, num_hidden_layers=3,
+            layer_types=("mamba", "attention", "mamba"),
+            max_position_embeddings=4096))
+        model.eval()
+        model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+        return ServingEngine(model, max_batch=64, page_tokens=128,
+                             num_pages=65, max_pages_per_seq=4)
+
+    def test_decode_updates_the_state_arenas_in_place(self, engine, one_chip):
+        """Pages and row state are aliased (donated in, returned), the
+        state update is the kernel, and no ``[64, 64, 64, 128]`` float32
+        arena is ever copied: a copy would cost a whole arena's traffic a
+        layer a step, what the live-rows kernel exists to avoid."""
+        from paddle_tpu import telemetry
+        from paddle_tpu.jit import named_program
+        from paddle_tpu.serving.engine import DECODE_PROGRAM
+
+        eng = engine
+        pa, ba = eng._param_arrays()
+        R, MP = eng.max_batch, eng.max_pages_per_seq
+        args = (pa, ba, eng._arenas, jnp.zeros((R, 1), jnp.int32),
+                jnp.zeros((R,), jnp.int32), jnp.zeros((R, MP), jnp.int32),
+                jnp.ones((R,), jnp.int32))
+        args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), args)
+        before = telemetry.counters().get(
+            "kernel_fallback.paged_decode_attention.head_dim", 0)
+        compiled = _compile_uncached(
+            jax.jit(named_program(eng._decode_fn, DECODE_PROGRAM),
+                    donate_argnums=(2,)), *args)
+        text = compiled.as_text()
+        assert eng.state.nbytes == 2 * 64 * (64 * 64 * 128 * 4
+                                             + 3 * 4352 * 2)
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            eng._arena_bytes + eng.state.nbytes
+        assert not _copies_of(text, "f32[64,64,64,128]")
+        # the state update is a Mosaic call a state layer; the 64-wide
+        # heads gather, counted once an attention layer
+        assert text.count("ssm_state_update") >= 2
+        assert telemetry.counters()[
+            "kernel_fallback.paged_decode_attention.head_dim"] == before + 1
+
+    def test_both_programs_lower_with_the_kernel_in_decode_only(self, engine):
+        eng = engine
+        pa, ba = eng._param_arrays()
+        R, MP, P = eng.max_batch, eng.max_pages_per_seq, eng.page_tokens
+        tables = jnp.zeros((R, MP), jnp.int32)
+        decode = tpu_text(
+            eng._decode_fn, pa, ba, eng._arenas,
+            jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
+            tables, jnp.ones((R,), jnp.int32), donate_argnums=(2,))
+        assert kernels_in(decode)["ssm_state_update"] == 1   # lowered once
+        assert decode.count("call @ssm_state_update") == 2   # called a layer
+        assert kernels_in(decode)["paged_decode_attention"] == 0
+        prefill = tpu_text(
+            eng._prefill_fn, pa, ba, eng._arenas,
+            jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
+            jnp.int32(P - 1), jnp.int32(3), jnp.int32(P - 7),
+            donate_argnums=(2,))
+        assert kernels_in(prefill)["ssm_state_update"] == 0
+        assert kernels_in(prefill)["rms_norm_fwd"] >= 2
