@@ -26,6 +26,7 @@ a time in decode, where the state update is the ``ssm_state_update`` kernel.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
@@ -157,22 +158,27 @@ class _InverseSoftplusLogUniform(I.Initializer):
         return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
-def _mamba_mix(cfg: GraniteHybridConfig, zxbcdt, conv_w, conv_b, A_log,
-               dt_bias, D, norm_w, tail0, state0, n_valid, chunk, live):
+@functools.partial(jax.jit, static_argnames=("dims", "chunk"))
+def _mamba_mix(dims, zxbcdt, conv_w, conv_b, A_log, dt_bias, D, norm_w,
+               tail0, state0, n_valid, chunk, live):
     """Everything between the two projections of a Mamba-2 mixer, on arrays.
+    ``dims``: the configuration's ``(H, P, G, N, d_inner, conv_dim, eps)``;
     ``zxbcdt [b, T, 2 * d_inner + 2 * G * N + H]``; ``tail0 [b, K - 1,
     conv_dim]``, ``state0 [b, H, P, N]`` carried in; ``n_valid [b]``.  With
     ``live`` ([b] bool: the decode step, T == 1) the recurrence is one
     token through :func:`~paddle_tpu.ops.ssm.ssm_decode_update`, else the
-    chunked scan.  Returns ``(gated y [b, T, d_inner], tail, state)``."""
+    chunked scan.  Returns ``(gated y [b, T, d_inner], tail, state)``.
+
+    A ``jit`` of its own: inside a program's trace the layers after the
+    first reuse the first one's trace and its lowering (one function, called
+    36 times), which a program compiled at several widths pays for each."""
     from ..ops import ssm
 
     b, T, _ = zxbcdt.shape
-    H, P = cfg.mamba_n_heads, cfg.mamba_d_head
-    G, N, di = cfg.mamba_n_groups, cfg.mamba_d_state, cfg.mamba_d_inner
+    H, P, G, N, di, conv_dim, eps = dims
     z = zxbcdt[..., :di]
-    xBC = zxbcdt[..., di:di + cfg.mamba_conv_dim]
-    dt = zxbcdt[..., di + cfg.mamba_conv_dim:]
+    xBC = zxbcdt[..., di:di + conv_dim]
+    dt = zxbcdt[..., di + conv_dim:]
     xBC, tail = ssm.causal_conv1d(xBC, conv_w, conv_b, tail0, n_valid)
     xBC = jax.nn.silu(xBC.astype(jnp.float32)).astype(xBC.dtype)
     x = xBC[..., :di].reshape(b, T, H, P)
@@ -192,7 +198,7 @@ def _mamba_mix(cfg: GraniteHybridConfig, zxbcdt, conv_w, conv_b, A_log,
     g = y.reshape(b, T, di).astype(jnp.float32) \
         * jax.nn.silu(z.astype(jnp.float32))
     g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True)
-                          + cfg.rms_norm_eps) * norm_w.astype(jnp.float32)
+                          + eps) * norm_w.astype(jnp.float32)
     return g.astype(zxbcdt.dtype), tail, state
 
 
@@ -251,8 +257,12 @@ class GraniteMambaMixer(nn.Layer):
         if self.conv_bias is not None:
             params.append(self.conv_bias)
 
+        dims = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
+                cfg.mamba_d_state, cfg.mamba_d_inner, cfg.mamba_conv_dim,
+                cfg.rms_norm_eps)
+
         def fn(zx_v, w, a_log, dt_b, d, nw, cb=None):
-            return _mamba_mix(cfg, zx_v, w, cb, a_log, dt_b, d, nw, tail0,
+            return _mamba_mix(dims, zx_v, w, cb, a_log, dt_b, d, nw, tail0,
                               state0, n_valid, chunk, live)
 
         y, tail, state = apply_op("mamba2_mix", fn, (zx, *params),
@@ -410,10 +420,13 @@ class GraniteHybridForCausalLM(nn.Layer):
             mixed = layer.self_attn.o_proj(Tensor(out.reshape(
                 R, s, cfg.num_attention_heads * cfg.head_dim)))
         else:
-            # one token a row is the decode step: the in-place state update
+            # one token a row is the decode step: the in-place state
+            # update; a prefill launch of several pages scans in chunks of
+            # the published size, so the intra-chunk work stays what it is
             mixed, tail, state = layer.mamba(
                 xin, io.read_state("conv"), io.read_state("ssm"),
-                io.n_valid, chunk=x.shape[1],
+                io.n_valid,
+                chunk=min(x.shape[1], self.config.mamba_chunk_size),
                 live=io.live if x.shape[1] == 1 else None)
             # a row with no valid token got its tail and state back as
             # they were

@@ -5,7 +5,8 @@ Reference capability: the serving half of the fusion set —
 the Pallas decode kernel / grouped einsum), the paged
 `block_multi_head_attention_kernel.cu` cache (here the page arenas +
 :class:`PagedKVPool` tables) and the `fused_multi_transformer` serving loop
-(here TWO compiled XLA programs reused across the whole request stream).
+(here a decode and a prefill XLA program, compiled once each — the prefill
+at a few fixed widths — and reused across the whole request stream).
 
 Design (TPU-first: *nothing* recompiles as traffic changes shape):
 
@@ -21,18 +22,21 @@ Design (TPU-first: *nothing* recompiles as traffic changes shape):
   per state layer an arena ``[max_batch, ...]`` indexed by the decode row
   (:class:`~paddle_tpu.serving.state_pool.RowStatePool`), donated and
   returned by both programs with the page arenas.  The prefill program
-  zeroes a request's slot when its first page runs and carries the state
-  from page to page; decode updates the live rows' slots in place.
+  zeroes a request's slot when its first launch runs and carries the state
+  from launch to launch; decode updates the live rows' slots in place.
 - **One decode program** per ``(max_batch, pages_per_seq)`` signature:
   every active request is a row; a row's block table gathers its pages
   into a ``[rows, pages_per_seq * page_tokens, kv, d]`` view, masked by
   the row's position.  Idle rows point at the reserved trash page, so
   admit/finish/evict never changes the compiled shape.
-- **One prefill program**: prompts stream through in fixed
-  ``page_tokens``-sized chunks (each chunk fills exactly one page), so
-  ragged prompt lengths share a single compiled signature instead of one
-  per length; junk tail slots of the last chunk are overwritten by the
-  first decode steps before the position mask ever exposes them.
+- **One prefill program at a few fixed widths** (``PREFILL_WIDTHS``
+  pages a launch): a prompt's uncached pages stream through in launches
+  of those widths (:func:`prefill_plan`), so ragged prompt lengths share a
+  handful of compiled signatures instead of one per length, and a launch
+  of several pages reads the weights once for all of them.  Junk tail slots of the
+  prompt's last page are overwritten by the first decode steps before
+  the position mask ever exposes them; what a wide launch holds past
+  that page scatters to the trash page.
 - **Scheduler** — FIFO admission gated on free page count, eviction under
   pool pressure (youngest-admitted victim, or the most-slack victim when
   deadlines are attached; the evictee requeues at the front and recomputes
@@ -110,6 +114,30 @@ QUEUED, RUNNING, FINISHED, SHED = "queued", "running", "finished", "shed"
 DECODE_PROGRAM = "serve_decode_fn"
 PREFILL_PROGRAM = "serve_prefill_fn"
 CP_PREFILL_PROGRAM = "serve_cp_prefill_fn"
+# Pages a launch of the prefill program may hold: one executable a width,
+# every one the module ``jit_serve_prefill_fn``.  A launch of one page is
+# bound by the weight read like a decode step; wider ones read the weights
+# once for all their pages (the sweep on the chip: PERF.md section 6).
+PREFILL_WIDTHS = (1, 4)
+
+
+def prefill_plan(pages: int, widths=PREFILL_WIDTHS) -> List[int]:
+    """The widths of the launches that cover ``pages`` pages: the fewest
+    launches the ladder allows in which every launch is more than half full.
+    The widest width while more than it holds is left; then the narrowest
+    that covers the rest, unless half of it or more would be junk (a launch
+    costs what its width costs: the sweep in PERF.md section 6), where full
+    narrower launches take the rest.  Only a prompt's last launch can run
+    past its pages."""
+    out = []
+    while pages > 0:
+        w = next((w for w in widths if w >= pages), None)
+        if w is None or (2 * pages <= w and w != widths[0]):
+            w = max((w for w in widths if w <= pages), default=widths[0])
+        out.append(w)
+        pages -= w
+    return out
+
 
 # Tracing a program swaps tracers into the model's param Tensors
 # (``_StateSwap`` in ``_forward``), so two engines sharing one model object
@@ -251,9 +279,11 @@ class _LayerIO:
         eng, spec = self._eng, self._spec
         walk = eng._page_walk(q.shape[0], q.shape[1], spec) \
             if self._row is None else None      # the decode program
-        out, new = eng._paged_attention(
-            q, k, v, self._arenas, self._index, self._tables,
-            self._positions, self._n_tok, walk, spec.scale)
+        pages = {key: self._arenas[key][self._index] for key in eng._planes}
+        out, new = eng._attend(
+            q, k, v, pages, self._tables, self._positions, self._n_tok,
+            walk=None if walk is None else tuple(walk.items()),
+            scale=spec.scale)
         for key, arena in new.items():
             self._arenas[key][self._index] = arena
         return out
@@ -261,7 +291,7 @@ class _LayerIO:
     def read_state(self, name: str):
         """The rows' slots of state array ``name``, ``[R, *shape]``: in
         decode the arena itself (row r is slot r); in prefill the
-        request's slot, zero where its first page is running."""
+        request's slot, zero where its first launch is running."""
         import jax
         import jax.numpy as jnp
 
@@ -308,6 +338,7 @@ class ServingEngine:
                  kv_dtype: Optional[str] = None, speculative=None,
                  tp: Optional[int] = None, prefix_cache=None,
                  cp: Optional[int] = None, offload=None):
+        import jax as _jax
         import jax.numpy as jnp
 
         from ..generation.speculative import AdaptiveK, SpecConfig
@@ -416,7 +447,6 @@ class ServingEngine:
             self._refuse_with_state(
                 "cp > 1", "the ring prefill carries no recurrent state "
                 "between its shards")
-            import jax as _jax
             from jax.sharding import Mesh as _Mesh
 
             if self.tp > 1:
@@ -496,7 +526,6 @@ class ServingEngine:
         elif self._mesh is not None:
             # cp mesh: arenas replicate — each device aliases its full
             # copy, so the donation lint floors are unchanged (shards=1)
-            import jax as _jax
             from jax.sharding import NamedSharding, PartitionSpec
 
             rep = NamedSharding(self._mesh, PartitionSpec())
@@ -504,6 +533,10 @@ class ServingEngine:
                       for key, arrs in arenas.items()}
         self._arena_bytes = 2 * n_layers * int(np.prod(self._arena_shape)) \
             * arenas["k"][0].dtype.itemsize
+        self._planes = tuple(arenas)    # what an attention layer's page
+        # holds: k, v and an int8 pool's scales
+        self._attend = _jax.jit(self._paged_attention,
+                                static_argnames=("walk", "scale"))
         if self.state is not None:
             arenas.update(self.state.zeros())
         self._arenas = arenas
@@ -587,7 +620,11 @@ class ServingEngine:
         self._results: Dict[int, np.ndarray] = {}
         self.shed: Dict[int, str] = {}                 # rid -> reason
         self._decode_exec = None
-        self._prefill_exec = None
+        # a launch wider than a row's table could never be full of pages
+        self._prefill_widths = tuple(
+            w for w in PREFILL_WIDTHS
+            if w <= max(self.max_pages_per_seq, PREFILL_WIDTHS[0]))
+        self._prefill_exec: Dict[int, object] = {}     # width -> program
         self._decode_compiles = 0
         self.lint_report = None
         self.last_decode_logits = None   # host copy of the latest verify
@@ -910,9 +947,9 @@ class ServingEngine:
         for r in [r for r in self._active.values() if not r.generated]:
             with _span("serve.prefill", rid=r.rid, trace=r.trace_id or "",
                        prompt_tokens=len(r.prompt),
-                       chunks=-(-len(r.prompt) // self.page_tokens),
-                       cached_tokens=r.cached_tokens):
-                self._prefill(r)
+                       cached_tokens=r.cached_tokens) as sp:
+                chunks, launches = self._prefill(r)
+                sp.note(chunks=chunks, launches=launches)
             did_work = True
             self._retire_if_done(r)
         if self._active:
@@ -1237,30 +1274,35 @@ class ServingEngine:
         return t
 
     def _prefill_chunks(self, prompt, table, c0: int = 0, row: int = 0):
-        """Drive the compiled prefill program over ``prompt``'s
-        page-sized chunks starting at chunk ``c0``; returns the
-        last-prompt-token logits.  Shared by scheduled prefills
-        (:meth:`_prefill`, where ``c0`` skips prefix-cached pages) and
-        the standalone :meth:`prefill_export` path.  ``row``: the decode
-        row whose state slot the state layers carry the prompt through;
-        each launch is told how many tokens of its page are real."""
+        """Drive the compiled prefill program over ``prompt``'s pages
+        ``[c0, n_chunks)`` in the launches of :func:`prefill_plan`.
+        Returns the last-prompt-token logits and the number of launches.
+        Shared by scheduled prefills (:meth:`_prefill`, where ``c0`` skips
+        prefix-cached pages) and the standalone :meth:`prefill_export` path.  ``row``: the decode row
+        whose state slot the state layers carry the prompt through; each
+        launch is told how many of its tokens are real."""
         import jax.numpy as jnp
 
         P = self.page_tokens
         n_chunks = -(-len(prompt) // P)
-        logits = None
-        for c in range(c0, n_chunks):
-            chunk = np.zeros((1, P), np.int32)
-            part = prompt[c * P:(c + 1) * P]
+        widths = prefill_plan(n_chunks - c0, self._prefill_widths)
+        logits, c = None, c0
+        for w in widths:
+            part = prompt[c * P:(c + w) * P]
+            chunk = np.zeros((1, w * P), np.int32)
             chunk[0, :len(part)] = part
-            take = (len(prompt) - 1 - c * P) if c == n_chunks - 1 else 0
+            # the prompt's last token lies in its last launch
+            take = len(prompt) - 1 - c * P if c + w >= n_chunks else 0
             logits = self._run_prefill(
                 jnp.asarray(chunk), jnp.int32(c * P), table,
-                jnp.int32(max(take, 0)), jnp.int32(row),
-                jnp.int32(len(part)))
-        return logits
+                jnp.int32(take), jnp.int32(row), jnp.int32(len(part)))
+            c += w
+        return logits, len(widths)
 
-    def _prefill(self, r: Request) -> None:
+    def _prefill(self, r: Request):
+        """Fill ``r``'s pages and deliver its first token.  Returns the
+        pages run and the program launches that took (both 0 where the
+        pages were imported)."""
         import jax.numpy as jnp
 
         if r.kv_import is not None:
@@ -1269,7 +1311,7 @@ class ServingEngine:
                 kernel_fallback("serving_cp_prefill", "kv_import",
                                 rid=str(r.rid))
             self._import_kv(r)
-            return
+            return 0, 0
         _faults.fire("serve_prefill", f"rid{r.rid}")
         prompt = r.prompt
         n_chunks = -(-len(prompt) // self.page_tokens)
@@ -1281,9 +1323,12 @@ class ServingEngine:
         with _span("serve.prefill.dispatch"):
             if self._cp_accepts(len(prompt), cached_tokens=r.cached_tokens):
                 logits = self._cp_prefill_run(prompt, self.pool.table(r.rid))
+                launches = 1
             else:
                 table = jnp.asarray(self._padded_table(r.rid)[None])
-                logits = self._prefill_chunks(prompt, table, c0, r.row)
+                logits, launches = self._prefill_chunks(prompt, table, c0,
+                                                        r.row)
+            self.meter.prefill_launched(launches)
         with _span("serve.prefill.to_host"):
             logits = np.asarray(logits)
         with _span("serve.prefill.sample"):
@@ -1303,6 +1348,7 @@ class ServingEngine:
                 r.drafter = self.spec.make_drafter()
                 r.drafter.begin([int(t) for t in r.prompt])
                 r.drafter.observe([tok])
+        return n_chunks - c0, launches
 
     def _import_kv(self, r: Request) -> None:
         """Disaggregated admission (ISSUE 19 leg 2): instead of running
@@ -1383,9 +1429,11 @@ class ServingEngine:
             pages = self.pool.table(key)
             t[:len(pages)] = pages
             if self._cp_accepts(len(prompt)):
-                logits = self._cp_prefill_run(prompt, pages)
+                logits, launches = self._cp_prefill_run(prompt, pages), 1
             else:
-                logits = self._prefill_chunks(prompt, jnp.asarray(t[None]))
+                logits, launches = self._prefill_chunks(
+                    prompt, jnp.asarray(t[None]))
+            self.meter.prefill_launched(launches)
             first = int(np.argmax(np.asarray(logits)))
             frames = [self._export_page(p) for p in pages]
             return first, frames
@@ -1676,13 +1724,18 @@ class ServingEngine:
     def _vs(self):
         return self._arenas["v"]
 
-    def _paged_attention(self, q, k_new, v_new, arenas, li, tables,
+    def _paged_attention(self, q, k_new, v_new, arenas, tables,
                          positions, n_tok, walk=None, scale=None):
-        """Scatter this step's k/v into layer ``li``'s page arenas and
-        attend each row over its pages: gathered by the whole padded table
+        """Scatter this step's k/v into one layer's page arenas
+        (``arenas``: that layer's array by plane, ``k`` / ``v`` and an int8
+        pool's ``ks`` / ``vs``) and attend each row over its pages: gathered
+        by the whole padded table
         for the einsum below (prefill; decode where no kernel runs), or,
-        with ``walk`` (:meth:`_page_walk`: the decode program on a TPU),
-        only the live ones, read in place by ``paged_decode_attention``.
+        with ``walk`` (:meth:`_page_walk`'s items: the decode program on a
+        TPU), only the live ones, read in place by
+        ``paged_decode_attention``.  Called through ``self._attend``, a
+        ``jit`` of it: the layers of a program that share a shape share
+        one trace and one lowering of this body.
         ``scale`` multiplies the scores (None: ``1 / sqrt(d)``; the kernel
         knows only that one).  ``n_tok`` [R] is the
         per-row count of VALID tokens in the s-window (speculative verify
@@ -1701,7 +1754,7 @@ class ServingEngine:
         kv = k_new.shape[2]
         P = self.page_tokens
         MP = tables.shape[1]
-        kp, vp = arenas["k"][li], arenas["v"][li]
+        kp, vp = arenas["k"], arenas["v"]
         quant = self.kv_dtype == "int8"
         fp8 = self.kv_dtype == "fp8"
 
@@ -1719,8 +1772,8 @@ class ServingEngine:
             vq, vsc = quantize_kv(v_new)
             kp = kp.at[page, slot].set(rows(kq))
             vp = vp.at[page, slot].set(rows(vq))
-            ksp = arenas["ks"][li].at[page, slot].set(ksc)
-            vsp = arenas["vs"][li].at[page, slot].set(vsc)
+            ksp = arenas["ks"].at[page, slot].set(ksc)
+            vsp = arenas["vs"].at[page, slot].set(vsc)
         elif fp8:
             # static scale: quantize on the scatter, no scale planes
             kp = kp.at[page, slot].set(
@@ -1737,7 +1790,7 @@ class ServingEngine:
             shape = (kp.shape[0], P, kv, d)     # the kernel's view of it
             out = paged_decode_attention(q, kp.reshape(shape),
                                          vp.reshape(shape), tables,
-                                         positions, n_tok, **walk)
+                                         positions, n_tok, **dict(walk))
             return out, {"k": kp, "v": vp}
         C = MP * P
         if quant:
@@ -1757,17 +1810,32 @@ class ServingEngine:
             vv = vp[tables].reshape(R, C, kv, d)
         g = h // kv
         q5 = q.reshape(R, s, kv, g, d).astype(kk.dtype)
-        scores = jnp.einsum("bskgd,bckd->bkgsc", q5, kk,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(float(d)) if scale is None \
-            else scores * float(scale)
-        col = jnp.arange(C)[None, None, None, None, :]
-        row_pos = pos_js[:, None, None, :, None]
-        scores = jnp.where(col <= row_pos, scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bkgsc,bckd->bskgd", probs.astype(vv.dtype), vv,
-                         preferred_element_type=jnp.float32)
+
+        def attend(q5, pos_js):     # queries [R, b, kv, g, d] at pos_js
+            scores = jnp.einsum("bskgd,bckd->bkgsc", q5, kk,
+                                preferred_element_type=jnp.float32)
+            scores = scores / jnp.sqrt(float(d)) if scale is None \
+                else scores * float(scale)
+            col = jnp.arange(C)[None, None, None, None, :]
+            row_pos = pos_js[:, None, None, :, None]
+            scores = jnp.where(col <= row_pos, scores,
+                               jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(scores, axis=-1)
+            return jnp.einsum("bkgsc,bckd->bskgd", probs.astype(vv.dtype),
+                              vv, preferred_element_type=jnp.float32)
+
+        if s > P and s % P == 0:
+            # a launch of several pages attends a page of queries at a
+            # time, so the float32 scores stay the size one page's are
+            def blocks(x):      # [R, s, ...] -> [s / P, R, P, ...]
+                return jnp.moveaxis(
+                    x.reshape((R, s // P, P) + x.shape[2:]), 1, 0)
+
+            out = jax.lax.map(lambda b: attend(*b),
+                              (blocks(q5), blocks(pos_js)))
+            out = jnp.moveaxis(out, 0, 1)
+        else:
+            out = attend(q5, pos_js)
         out = out.reshape(R, s, h, d).astype(q.dtype)
         new = {"k": kp, "v": vp}
         if quant:
@@ -1779,7 +1847,8 @@ class ServingEngine:
                  fresh=None):
         """Shared model step for both programs: ONE walk over the model's
         layers, each given its :class:`_LayerIO`.  ``tokens`` [R, s]
-        (decode/verify: s=spec width; prefill: R=1, s=page_tokens);
+        (decode/verify: s=spec width; prefill: R=1, s=a width of
+        ``PREFILL_WIDTHS`` x page_tokens);
         ``positions`` [R] absolute position of each row's first token;
         ``n_tok`` [R] tokens per row whose K/V are kept (rest scatter to
         trash); ``n_valid`` [R] tokens per row that are real (None:
@@ -1821,9 +1890,9 @@ class ServingEngine:
         kernel's gate takes the shape and the layer scales its scores as
         the kernel does; else None, the gather + einsum — with a
         ``kernel_fallback`` event wherever a kernel could have run.  Many
-        short rows against the prefill's one row of ``page_tokens``
+        short rows against the prefill's one row of one to a few pages of
         queries: the two programs share the mask rule and nothing else, so
-        prefill keeps the einsum."""
+        prefill keeps the einsum, a page of queries at a time."""
         from ..ops import pallas_mode
         from ..ops.pallas.paged_decode_attention import (
             KERNEL_NAME, paged_decode_attention_refusal)
@@ -1857,15 +1926,23 @@ class ServingEngine:
 
     def _prefill_fn(self, param_arrays, buffer_arrays, arenas, tokens,
                     chunk_start, tables, take_idx, row=None, n_valid=None):
-        """ONE compiled prefill signature: one page of one prompt.  ``row``
-        / ``n_valid``: the request's decode row and the page's real tokens,
-        read by state layers only (the K/V of the last page's junk tail is
-        harmless: decode overwrites it before the position mask exposes
-        it; a recurrence would swallow it into the state)."""
+        """The prefill signature, compiled once a width of
+        ``PREFILL_WIDTHS``: ``tokens`` [1, w * page_tokens] of one prompt
+        from position ``chunk_start`` on.  ``row`` / ``n_valid``: the
+        request's decode row and the launch's real tokens.  K/V are kept up
+        to the end of the page that holds the last real token (its junk
+        tail is harmless: decode overwrites it before the position mask
+        exposes it); whole junk pages behind it, which only a launch wider
+        than the prompt's rest has, scatter to the trash page.  State
+        layers let only the real tokens into the state: a recurrence would
+        swallow the junk."""
         import jax.numpy as jnp
 
         positions = chunk_start[None]                 # [1]
-        n_tok = jnp.full((1,), tokens.shape[1], jnp.int32)  # full chunk
+        s, P = tokens.shape[1], self.page_tokens
+        # n_valid <= s, a whole number of pages
+        keep = s if n_valid is None else -(-n_valid // P) * P
+        n_tok = jnp.full((1,), keep, jnp.int32)
         logits, arenas = self._forward(
             param_arrays, buffer_arrays, arenas, tokens, positions, tables,
             n_tok, n_valid=None if n_valid is None else n_valid[None],
@@ -1927,14 +2004,24 @@ class ServingEngine:
 
     def _run_prefill(self, tokens, chunk_start, tables, take_idx, row,
                      n_valid):
+        """Launch the prefill program of ``tokens``' width.  The first
+        launch compiles every width of the ladder, so none compiles once
+        requests are being served."""
         pa, ba = self._param_arrays()
         args = (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(chunk_start), self._repl(tables),
                 self._repl(take_idx), self._repl(row), self._repl(n_valid))
-        if self._prefill_exec is None:
-            self._prefill_exec = self._compile(self._prefill_fn, args,
-                                                PREFILL_PROGRAM)
-        logits, self._arenas = self._prefill_exec(*args)
+        if not self._prefill_exec:
+            import jax.numpy as jnp
+
+            for w in self._prefill_widths:
+                wide = self._repl(jnp.zeros((1, w * self.page_tokens),
+                                            tokens.dtype))
+                self._prefill_exec[w] = self._compile(
+                    self._prefill_fn, args[:3] + (wide,) + args[4:],
+                    PREFILL_PROGRAM)
+        width = tokens.shape[1] // self.page_tokens
+        logits, self._arenas = self._prefill_exec[width](*args)
         return logits
 
     # -- context-parallel prefill (ISSUE 20 leg 1) -------------------------

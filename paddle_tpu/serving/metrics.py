@@ -116,6 +116,7 @@ class SLOMeter:
         self.state_slots_peak = None     # share of max_batch; None: the
         # model has no state layers
         self.finished_total = 0
+        self.prefill_launches_total = 0  # launches of a prefill program
         self.evictions_total = 0
         self.shed_total = 0
         self.shed_reasons: Dict[str, int] = {}
@@ -219,6 +220,10 @@ class SLOMeter:
         c.last_token_t = t
         c.n_tokens += 1
         self._count_token(c)
+
+    def prefill_launched(self, launches: int) -> None:
+        """One prompt's prefill took ``launches`` program launches."""
+        self.prefill_launches_total += int(launches)
 
     def token(self, rid) -> None:
         c = self._clocks[rid]
@@ -484,6 +489,7 @@ class SLOMeter:
             "latency_ms_p99": _r(_pct(lat, 99)),
             "deadline_miss_rate": round(self.deadline_miss_rate(), 4),
             "evictions": self.evictions_total,
+            "prefill_launches": self.prefill_launches_total,
             "kv_pool_occupancy_peak": round(self.occupancy_peak, 4),
             "state_slots_peak": (None if self.state_slots_peak is None
                                  else round(self.state_slots_peak, 4)),

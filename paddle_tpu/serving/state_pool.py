@@ -9,9 +9,9 @@ row it was admitted to, so there is no second allocator: admission,
 retirement, eviction and slot reuse copy nothing, and the slots in use are
 the engine's active rows.  The arenas ride the engine's two compiled
 programs exactly like the page arenas (donated in, updated in place,
-returned); the prefill program zeroes a request's state when its first page
-runs, carries it from page to page in the slot, and decode updates it in
-place.
+returned); the prefill program zeroes a request's state when its first
+launch runs, carries it from launch to launch in the slot, and decode updates
+it in place.
 """
 
 from __future__ import annotations

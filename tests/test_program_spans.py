@@ -29,7 +29,8 @@ SPANS = {
     "serve.shed_scan": ("serve.step", set()),
     "serve.admit": ("serve.step", {"admitted"}),
     "serve.prefill": ("serve.step", {"rid", "trace", "prompt_tokens",
-                                     "chunks", "cached_tokens"}),
+                                     "chunks", "launches",
+                                     "cached_tokens"}),
     "serve.prefill.dispatch": ("serve.prefill", set()),
     "serve.prefill.to_host": ("serve.prefill", set()),
     "serve.prefill.sample": ("serve.prefill", set()),
@@ -251,6 +252,12 @@ def test_prefill_span_carries_the_requests_trace_id(recorded):
     page = recorded["engine"].page_tokens
     assert all(s[2]["chunks"] == -(-s[2]["prompt_tokens"] // page)
                and s[2]["cached_tokens"] == 0 for s in prefills)
+    # the launches of the engine's plan; the meter counts what the spans say
+    launches = [s[2]["launches"] for s in prefills]
+    assert launches == [len(serving_engine.prefill_plan(s[2]["chunks"]))
+                        for s in prefills]
+    assert recorded["engine"].meter.summary()["prefill_launches"] == \
+        sum(launches)
     for child in ("dispatch", "to_host", "sample"):
         assert len(recorded["spans"][f"serve.prefill.{child}"]) == \
             len(PROMPTS)
@@ -258,8 +265,9 @@ def test_prefill_span_carries_the_requests_trace_id(recorded):
 
 def test_compile_span_says_which_program_compiled(recorded):
     programs = [s[2]["program"] for s in recorded["spans"]["serve.compile"]]
-    assert sorted(programs) == [serving_engine.DECODE_PROGRAM,
-                                serving_engine.PREFILL_PROGRAM]
+    # the prefill program once a width, all before the decode program
+    assert programs == [serving_engine.PREFILL_PROGRAM] * len(
+        serving_engine.PREFILL_WIDTHS) + [serving_engine.DECODE_PROGRAM]
 
 
 def test_train_span_counts_and_program(recorded):
@@ -342,8 +350,8 @@ def test_the_engine_compiles_under_those_names(engine_model, monkeypatch):
                         num_pages=16, max_pages_per_seq=4)
     eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
     eng.run()
-    assert seen == [serving_engine.PREFILL_PROGRAM,
-                    serving_engine.DECODE_PROGRAM]
+    assert seen == [serving_engine.PREFILL_PROGRAM] * len(
+        serving_engine.PREFILL_WIDTHS) + [serving_engine.DECODE_PROGRAM]
 
 
 @pytest.mark.parametrize("variant, name", [

@@ -94,8 +94,8 @@ def test_a_model_without_state_layers_reports_none(llama):
 @pytest.mark.parametrize("which", ["hybrid", "llama"])
 def test_both_programs_compile_once_under_their_names(which, request):
     eng, _, compiled = request.getfixturevalue(which)
-    assert sorted(compiled) == sorted([serving_engine.DECODE_PROGRAM,
-                                       serving_engine.PREFILL_PROGRAM])
+    assert compiled == [serving_engine.PREFILL_PROGRAM] * len(
+        serving_engine.PREFILL_WIDTHS) + [serving_engine.DECODE_PROGRAM]
     assert (serving_engine.DECODE_PROGRAM, serving_engine.PREFILL_PROGRAM) \
         == ("serve_decode_fn", "serve_prefill_fn")
     assert eng._decode_compiles == 1

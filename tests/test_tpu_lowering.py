@@ -334,10 +334,11 @@ class TestProgramsLower:
             jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
             tables, jnp.ones((R,), jnp.int32), donate_argnums=(2,))
         assert kernels_in(decode)["rms_norm_fwd"] >= 2
-        # decode walks live pages (one kernel, lowered once and called by
-        # every layer); prefill gathers
+        # decode walks live pages (one kernel, lowered once inside the one
+        # attention function that every layer calls); prefill gathers
         assert kernels_in(decode)["paged_decode_attention"] == 1
-        assert decode.count("call @paged_decode_attention") == \
+        assert decode.count("call @paged_decode_attention") == 1
+        assert decode.count("call @_paged_attention") == \
             WIDTHS["num_hidden_layers"]
         prefill = tpu_text(
             eng._prefill_fn, pa, ba, eng._arenas,
@@ -436,8 +437,10 @@ class TestStateLayerProgramsLower:
             eng._decode_fn, pa, ba, eng._arenas,
             jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
             tables, jnp.ones((R,), jnp.int32), donate_argnums=(2,))
-        assert kernels_in(decode)["ssm_state_update"] == 1   # lowered once
-        assert decode.count("call @ssm_state_update") == 2   # called a layer
+        # lowered once, inside the one mixer function every state layer calls
+        assert kernels_in(decode)["ssm_state_update"] == 1
+        assert decode.count("call @ssm_state_update") == 1
+        assert decode.count("call @_mamba_mix") == 2
         assert kernels_in(decode)["paged_decode_attention"] == 0
         prefill = tpu_text(
             eng._prefill_fn, pa, ba, eng._arenas,
