@@ -498,7 +498,10 @@ def train(sz: Sizes) -> dict:
     t_read = float(np.median(times["host_read"]))
     say("train", step_s_block_until_ready=round(t_block, 4),
         step_s_host_read=round(t_read, 4))
-    require(0.5 < t_read / t_block < 2.0,
+    # a statement about the device: held at the chip's sizes, where a step
+    # is a quarter of a second, not at the interpreted tiny size, where a
+    # 10 ms CPU step under the test workers' load is scheduler noise
+    require(not sz.mosaic or 0.5 < t_read / t_block < 2.0,
             f"host read ({t_read:.4f}s) and block_until_ready "
             f"({t_block:.4f}s) disagree about a step: one of them does not "
             "wait for the device")

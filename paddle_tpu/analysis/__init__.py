@@ -1,8 +1,7 @@
 """paddle_tpu.analysis — **shardlint**, the SPMD/HLO static linter.
 
-The repo inspected optimized HLO in three ad-hoc places (the bench
-``--tp-derate`` wire-byte walk, the hand-written ``ParallelCrossEntropy``
-no-``[B,V]``-all-gather assert, the compile-metrics cost crosscheck);
+The repo inspected optimized HLO in ad-hoc places (a wire-byte walk, the
+hand-written ``ParallelCrossEntropy`` no-``[B,V]``-all-gather assert);
 this subsystem promotes that pattern into a first-class tool: anything
 the ``compile/`` subsystem can lower — an
 :class:`~paddle_tpu.jit.TrainStep` /
@@ -28,9 +27,8 @@ Layers:
 - :mod:`.linter`       — :func:`lint`, the one entry point.
 
 Gates wired on top: ``__graft_entry__.dryrun_multichip`` fails loudly on
-unexempted involuntary-remat findings in every factorization, ``bench.py``
-reports ``lint_findings`` per point, and the tier-1 ``analysis`` pytest
-marker runs the fixture + clean-program suites.
+unexempted involuntary-remat findings in every factorization, and the
+tier-1 ``analysis`` pytest marker runs the fixture + clean-program suites.
 """
 
 from .annotations import host_sync_ok, is_host_sync_ok  # noqa: F401

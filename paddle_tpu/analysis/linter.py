@@ -1,8 +1,7 @@
 """The shardlint driver: collect artifacts → run rules → apply baseline.
 
 :func:`lint` is the one entry point every consumer calls — the dryrun
-gate, the bench ``lint_findings`` detail, the tier-1 ``analysis`` suite,
-and ad-hoc standalone use::
+gate, the tier-1 ``analysis`` suite, and ad-hoc standalone use::
 
     from paddle_tpu.analysis import lint
     report = lint(step, args=(ids, labels))     # a (Distributed)TrainStep

@@ -23,7 +23,7 @@ Pricing: the last-resort reshard replicates the tensor (ring all-gather:
 ``(n-1)/n × full_bytes`` per chip) and then slices locally (free), so
 each occurrence is priced at ``full_bytes × (n-1)/n`` wire bytes, with
 ``n`` the participant count read off the sharding's device assignment —
-the same ring-cost model ``bench.py --tp-derate`` uses.
+the ring-cost model of ``telemetry.ring_wire_bytes``.
 """
 
 from __future__ import annotations

@@ -36,13 +36,11 @@ Env: ``PADDLE_TPU_COMPILE_CACHE`` (explicit AOT root),
 from .aot import AOTFunction, fingerprint, resolve_cache  # noqa: F401
 from .cache import (ExecutableCache, PersistentCacheStats,  # noqa: F401
                     cache_dir, default_root, enable_persistent_cache)
-from .metrics import (compile_begin, compile_end,  # noqa: F401
-                      compile_info_detail, crosscheck_stepmeter, flops_of)
+from .metrics import compile_begin, compile_end, flops_of  # noqa: F401
 
 __all__ = [
     "AOTFunction", "fingerprint", "resolve_cache",
     "ExecutableCache", "default_root", "cache_dir",
     "enable_persistent_cache", "PersistentCacheStats",
-    "flops_of", "compile_begin", "compile_end", "crosscheck_stepmeter",
-    "compile_info_detail",
+    "flops_of", "compile_begin", "compile_end",
 ]

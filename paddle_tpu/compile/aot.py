@@ -3,7 +3,7 @@ persistent executable cache.
 
 The per-process jit cache (:class:`paddle_tpu.jit._CompileCache`) dies with
 the process, so every supervisor relaunch (exit 101 → restart) and every
-cold ``bench.py`` run re-pays the XLA compile of the fused train step —
+cold run re-pays the XLA compile of the fused train step —
 minutes at 7B scale. This module makes that wall-clock a one-time cost:
 
 1. ``jitted.lower(*args)`` produces the StableHLO module **without**

@@ -1,5 +1,5 @@
 """Measured compile telemetry: flight-recorder events + runtime gauges for
-every AOT compile, and the ``cost_analysis()`` FLOP cross-check.
+every AOT compile, and the ``cost_analysis()`` FLOP count.
 
 Event protocol (the flight recorder narrates compile time the same way it
 narrates checkpoints):
@@ -17,19 +17,15 @@ Gauges/counters exported through ``telemetry.prometheus_text()``:
 amortize), ``compile_cost_flops_last``.
 
 :func:`flops_of` pulls XLA's own executed-FLOP estimate off a compiled
-executable; :func:`crosscheck_stepmeter` compares it against a
-:class:`~paddle_tpu.telemetry.StepMeter`'s analytic ``flops_per_step``
-model (6·N·tokens) so a drifting MFU model is visible as a ratio gauge
-instead of a silently wrong headline number.
+executable.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Optional
 
-__all__ = ["flops_of", "compile_begin", "compile_end",
-           "crosscheck_stepmeter", "bump_counter", "cache_event",
-           "remat_diagnostics"]
+__all__ = ["flops_of", "compile_begin", "compile_end", "bump_counter",
+           "cache_event", "remat_diagnostics"]
 
 
 def flops_of(compiled) -> Optional[float]:
@@ -110,36 +106,3 @@ def remat_diagnostics(name: str, fingerprint: str, count: int) -> None:
         t.set_gauge("compile_partitioner_remats_last", count)
     except Exception:
         pass
-
-
-def crosscheck_stepmeter(meter, flops_per_step: Optional[float]) -> Optional[float]:
-    """Ratio of XLA's cost-analysis FLOPs/step to the meter's analytic
-    ``flops_per_step`` model (1.0 = the MFU accounting matches what XLA
-    says it executes). Returns None — and exports no gauge — when either
-    side is unknown; otherwise exports the ratio as the
-    ``compile_flops_model_ratio`` gauge and records a crosscheck event."""
-    model = getattr(meter, "flops_per_step", None)
-    if not flops_per_step or not model:
-        return None
-    ratio = float(flops_per_step) / float(model)
-    try:
-        t = _telemetry()
-        t.set_gauge("compile_flops_model_ratio", ratio)
-        t.record_event("compile_flops_crosscheck", getattr(meter, "name", "?"),
-                       cost_flops=flops_per_step, model_flops=model,
-                       ratio=round(ratio, 4))
-    except Exception:
-        pass
-    return ratio
-
-
-def compile_info_detail(info: Optional[Dict[str, Any]]) -> Dict[str, Any]:
-    """Flatten an AOT compile-info dict into bench/telemetry detail fields
-    (empty when no compile has happened, e.g. a pre-warmed process)."""
-    if not info:
-        return {}
-    out = {"compile_mode": info.get("mode"),
-           "compile_time_s": round(float(info.get("seconds", 0.0)), 4)}
-    if info.get("flops"):
-        out["cost_flops_per_step"] = info["flops"]
-    return out

@@ -272,7 +272,7 @@ class SDCMonitor:
 
     ``domain`` is a :class:`~..fleet.fault_domain.FaultDomain` (or None
     for solo mode: no vote partner, fingerprints still anchor checkpoint
-    integrity and the bench overhead measurement). ``replay_fn(step) ->
+    integrity). ``replay_fn(step) ->
     digest-hex`` re-executes the step's batch and returns the voted
     fingerprint digest; ``None`` means confirmation cannot run and a named
     minority is conservatively treated as sticky. ``ledger`` receives the

@@ -7,8 +7,8 @@ Two measurement paths, in order of fidelity:
   collective intervals with the compute intervals, and report the
   fraction of collective wall-time that ran UNDER compute. This is the
   literal "collective time ∧ compute time" estimator.
-- :func:`hidden_comm_seconds` — the analytic bound used by ``bench.py``
-  when only HLO byte counts and a measured step time exist (CPU virtual
+- :func:`hidden_comm_seconds` — the analytic bound for when only HLO
+  byte counts and a measured step time exist (CPU virtual
   meshes can't produce a truthful device trace): ring-decomposed bytes
   are overlappable by construction, hidden up to the compute time
   actually available.

@@ -179,10 +179,9 @@ define_flag("use_fused_rope", True,
             "(reference: fused_rotary_position_embedding.py surface).")
 define_flag("flash_block_q", 512,
             "Pallas flash attention query-block rows; the dispatcher uses "
-            "the largest power-of-two fraction that divides the sequence. "
-            "512 measured +15% over 256 on the llama-670M seq-2048 train "
-            "step on v5e (31958 vs 27717 tok/s); bench_llama_longctx "
-            "sweeps higher values at 8K.")
+            "the largest power-of-two fraction that divides the sequence "
+            "(512 against 256: not measured on the current tree, see "
+            "PERF.md).")
 define_flag("flash_block_k", 512,
             "Pallas flash attention key-block rows (see flash_block_q).")
 define_flag("use_decode_attention", True,

@@ -67,7 +67,7 @@ def cached_attention(q, k_new, v_new, cache_k, cache_v, pos, pad_lens=None):
         # DECODE fast path: the fused Pallas kernel appends k/v via an
         # input_output-ALIASED single-block write, so the compiled scan
         # keeps the cache in place instead of copying all C slots every
-        # step (the 0.576-MBU-at-8K ceiling, BENCH_r05).
+        # step (not measured on the current tree; see PERF.md).
         mode = pallas_mode("use_decode_attention")
         if mode is not None:
             kind, _mesh, interp = mode

@@ -2,7 +2,7 @@
 target-model forward, emit every token the target agrees with.
 
 A decode step is bandwidth-bound — it reads every weight byte to emit one
-token (the 0.576-MBU-at-8K wall, BENCH_r05).  Speculation amortizes that
+token.  Speculation amortizes that
 weight read: a cheap drafter proposes ``k`` tokens, the target model runs
 ONCE over ``[t_last, d_1..d_k]`` (positions ``p..p+k``), and the longest
 prefix of drafts matching the target's own greedy argmax is accepted plus

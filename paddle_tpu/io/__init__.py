@@ -420,7 +420,7 @@ def _to_transport(obj, use_shm: bool):
     """Worker→parent encoding: Tensors/ndarrays become ndarrays (big ones
     parked in shared memory) with the original type recorded, so the parent
     reconstructs exactly what the sync loader would have yielded."""
-    from multiprocessing import shared_memory
+    from multiprocessing import resource_tracker, shared_memory
 
     was_tensor = isinstance(obj, Tensor)
     if was_tensor:
@@ -432,6 +432,13 @@ def _to_transport(obj, use_shm: bool):
             np.copyto(view, obj)
             desc = _ShmArray(shm.name, obj.shape, obj.dtype, was_tensor)
             shm.close()
+            # the segment is the parent's from here: it attaches, copies
+            # and unlinks (_from_transport / _release_transport).  Left
+            # registered, this worker's resource tracker unlinks it when
+            # the worker exits, which can be before a busy parent has read
+            # it (the tracker has no public hand-over; `_name` is what
+            # SharedMemory itself registers and unregisters)
+            resource_tracker.unregister(shm._name, "shared_memory")
             return desc
         return _TensorArray(obj) if was_tensor else obj
     if isinstance(obj, (list, tuple)):

@@ -28,12 +28,7 @@ def sds_like(shape, dtype, like):
 from .flash_attention import (flash_attention, flash_attention_supported,
                               flash_attention_varlen,
                               flash_attention_varlen_supported)
-from .decode_attention import (decode_attention, decode_attention_fp8,
-                               decode_attention_fp8_supported,
-                               decode_attention_int8,
-                               decode_attention_int8_supported,
-                               decode_attention_sharded_supported,
-                               decode_attention_supported)
+from .decode_attention import decode_attention, decode_attention_supported
 from .paged_decode_attention import (paged_decode_attention,
                                      paged_decode_attention_refusal)
 from .ssm_state_update import ssm_state_update, ssm_state_update_refusal
@@ -43,9 +38,6 @@ from .rope import fused_rope
 __all__ = ["flash_attention", "flash_attention_supported",
            "flash_attention_varlen", "flash_attention_varlen_supported",
            "decode_attention", "decode_attention_supported",
-           "decode_attention_fp8", "decode_attention_fp8_supported",
-           "decode_attention_int8", "decode_attention_int8_supported",
-           "decode_attention_sharded_supported",
            "paged_decode_attention", "paged_decode_attention_refusal",
            "ssm_state_update", "ssm_state_update_refusal",
            "fused_rms_norm", "fused_rope"]

@@ -6,8 +6,8 @@ Capability parity target: the reference's serving hot kernel
 fused (cache write + masked single-token attention) per decode step.  The
 XLA einsum path (`generation.cached_attention`) is numerically fine but its
 `dynamic_update_slice` inside the decode scan materializes a full copy of
-the cache every step (measured ~1.6 ms at 8K context on v5e — the 0.576 MBU
-ceiling in BENCH_r05).  Here the cache arrays are passed through
+the cache every step (its cost is not measured on the current tree; see
+PERF.md).  Here the cache arrays are passed through
 ``input_output_aliases``: the kernel writes exactly the new token's rows
 back and the rest of the aliased HBM buffer is never touched, so the
 compiled scan keeps the cache resident in place.
@@ -40,10 +40,6 @@ is stale and masked with ``col < pos``), and the append writes just the
 new token's ``(kv, d)`` row group through the aliased output.
 ``pos``/``pad_lens`` ride scalar prefetch so the output block index map can
 target the append rows dynamically.
-
-The int8 / fp8 variants below keep the older one-head-per-program block
-``(1, block_k, 1, d)``, which the TPU lowering refuses for ``kv > 1``; they
-have no product caller (ROADMAP D2) and run in interpret mode only.
 
 No VJP: decode runs under ``no_grad`` by construction.
 """
@@ -293,509 +289,3 @@ def decode_attention(q, k_new, v_new, cache_k, cache_v, pos,
 
     return (out[:, :h].reshape(b, 1, h, d),
             ck_out.reshape(cache_k.shape), cv_out.reshape(cache_v.shape))
-
-
-# ---------------------------------------------------------------------------
-# int8 quantized-cache variant (ISSUE 13)
-# ---------------------------------------------------------------------------
-
-_QMAX = 127.0
-_SCALE_EPS = 1e-8
-
-
-def decode_attention_int8_supported(q_shape, cache_shape, *,
-                                    block_k: int = DEFAULT_BLOCK_K,
-                                    emit_fallback: bool = False) -> bool:
-    """Shapes the int8 decode kernel handles.  The extra constraint over
-    the bf16 kernel is lane alignment of the per-token scale vectors
-    (``block_k`` must fill whole lane registers).  With ``emit_fallback``
-    every gate rejection lands a ``kernel_fallback`` telemetry event so an
-    int8 deployment silently falling back to the einsum path is visible."""
-    def _reject(reason: str, **detail) -> bool:
-        if emit_fallback:
-            from ...telemetry import kernel_fallback
-
-            kernel_fallback("decode_attention_int8", reason, **detail)
-        return False
-
-    if len(q_shape) != 4 or len(cache_shape) != 4:
-        return _reject("rank", q_rank=len(q_shape))
-    b, s, h, d = q_shape
-    _, C, kv, dc = cache_shape
-    if not _decode_shape_ok(q_shape, cache_shape, block_k):
-        return _reject("shape", q_shape=list(q_shape), cache_len=C,
-                       block_k=block_k)
-    if block_k % _LANES != 0:
-        return _reject("scale_lane_alignment", block_k=block_k)
-    return True
-
-
-def _decode_kernel_int8(pos_ref, pad_ref, q_ref, kn_ref, vn_ref, ck_ref,
-                        cv_ref, ks_ref, vs_ref, o_ref, cko_ref, cvo_ref,
-                        kso_ref, vso_ref, acc_ref, m_ref, l_ref, *,
-                        scale: float, block_k: int):
-    """Same online-softmax structure as :func:`_decode_kernel`, but the
-    cache blocks are int8 with per-token f32 scales riding a ``[b, kv, C]``
-    scale plane.  Dequant is FUSED into the block math without a transpose:
-    ``q . (k*s) == (q . k) * s`` scales the score columns, and
-    ``p @ diag(s) @ v == (p*s) @ v`` scales the probability columns — the
-    softmax denominator keeps the UNSCALED p.  The append quantizes the new
-    token in-kernel and writes its int8 row + scale through the aliased
-    buffers."""
-    ib, ik = pl.program_id(0), pl.program_id(2)
-    nk = pl.num_programs(2)
-    pos = pos_ref[0]
-    pad = pad_ref[ib]
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    def _bcast(col):
-        return jnp.broadcast_to(col, (col.shape[0], _LANES))
-
-    def _online(s_col, v_rows, p_scale=None):
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s_col, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_ok = jnp.where(m_new == _NEG_INF, 0.0, m_new)
-        p = jnp.exp(s_col - m_ok)
-        alpha = jnp.exp(m_prev - m_ok)
-        l_ref[:] = _bcast(l_prev * alpha + jnp.sum(p, axis=1, keepdims=True))
-        m_ref[:] = _bcast(m_new)
-        pv = p if p_scale is None else p * p_scale
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pv.astype(v_rows.dtype), v_rows, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when((ik * block_k < pos) & ((ik + 1) * block_k > pad))
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32)            # (g, d)
-        k = ck_ref[0, :, 0, :].astype(jnp.float32)     # (block_k, d) int8
-        ksc = ks_ref[0]                                # (1, block_k) f32
-        vsc = vs_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * ksc * scale                            # fused k dequant
-        col = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where((col < pos) & (col >= pad), s, _NEG_INF)
-        _online(s, cv_ref[0, :, 0, :].astype(jnp.float32), p_scale=vsc)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        # the new token folds in EXACT (pre-quantization k/v): its cache
-        # row is quantized by _append below, but this step's reader sees
-        # the true values — one step later the quantized row is what the
-        # einsum oracle reads too
-        q = q_ref[0, 0].astype(jnp.float32)
-        kn = kn_ref[0, 0].astype(jnp.float32)          # (1, d)
-        s_new = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) \
-            * scale
-        _online(s_new, vn_ref[0, 0].astype(jnp.float32))
-        l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-    @pl.when(ik == pos // block_k)
-    def _append():
-        row = pos % block_k
-        kn = kn_ref[0, 0].astype(jnp.float32)          # (1, d)
-        vn = vn_ref[0, 0].astype(jnp.float32)
-        ks_new = jnp.maximum(jnp.max(jnp.abs(kn)), _SCALE_EPS) / _QMAX
-        vs_new = jnp.maximum(jnp.max(jnp.abs(vn)), _SCALE_EPS) / _QMAX
-        cko_ref[0, :, 0, :] = ck_ref[0, :, 0, :]
-        cvo_ref[0, :, 0, :] = cv_ref[0, :, 0, :]
-        kso_ref[0, :] = ks_ref[0, :]
-        vso_ref[0, :] = vs_ref[0, :]
-        cko_ref[0, pl.ds(row, 1), 0, :] = jnp.clip(
-            jnp.round(kn / ks_new), -_QMAX, _QMAX).astype(jnp.int8)
-        cvo_ref[0, pl.ds(row, 1), 0, :] = jnp.clip(
-            jnp.round(vn / vs_new), -_QMAX, _QMAX).astype(jnp.int8)
-        kso_ref[0, 0, pl.ds(row, 1)] = jnp.full((1,), ks_new, jnp.float32)
-        vso_ref[0, 0, pl.ds(row, 1)] = jnp.full((1,), vs_new, jnp.float32)
-
-
-def decode_attention_int8(q, k_new, v_new, cache_k, cache_v, k_scale,
-                          v_scale, pos, pad_lens=None, *,
-                          scale: Optional[float] = None,
-                          block_k: int = DEFAULT_BLOCK_K,
-                          interpret: bool = False):
-    """Fused int8-cache decode step: dequantize the k/v block loads in
-    place (score- and probability-column scaling — no dequantized cache
-    copy ever exists), quantize+append the new token at ``pos``, and
-    attend ``q`` over cols ``[pad_lens, pos]``.
-
-    - cache_k/cache_v — int8 ``[b, C, kv, d]``, aliased in place
-    - k_scale/v_scale — f32 ``[b, kv, C]`` per-token scales, aliased too
-      (lane-major over C so a ``block_k`` slice is lane-aligned)
-
-    Returns ``(out, new_ck, new_cv, new_ks, new_vs)``."""
-    b, s, h, d = q.shape
-    _, C, kv, _ = cache_k.shape
-    assert s == 1, "decode kernel is single-query (s == 1)"
-    assert cache_k.dtype == jnp.int8 and cache_v.dtype == jnp.int8
-    g = h // kv
-    gp = max(g, _MIN_SUBLANES)
-    sc = scale if scale is not None else 1.0 / (d ** 0.5)
-
-    q4 = q.reshape(b, kv, g, d)
-    if gp != g:
-        q4 = jnp.concatenate(
-            [q4, jnp.zeros((b, kv, gp - g, d), q4.dtype)], axis=2)
-    kn3 = jnp.transpose(k_new, (0, 2, 1, 3))           # [b, kv, 1, d]
-    vn3 = jnp.transpose(v_new, (0, 2, 1, 3))
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
-    pad_arr = (jnp.zeros((b,), jnp.int32) if pad_lens is None
-               else jnp.asarray(pad_lens, jnp.int32).reshape(b))
-
-    nk = C // block_k
-    kernel = functools.partial(_decode_kernel_int8, scale=sc,
-                               block_k=block_k)
-    grid = (b, kv, nk)
-
-    out, ck_out, cv_out, ks_out, vs_out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, gp, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, 1, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, 1, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ik, ikv, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ik, ikv, 0)),
-                pl.BlockSpec((1, 1, block_k),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, ik)),
-                pl.BlockSpec((1, 1, block_k),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, ik)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, gp, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, pos_r[0] // block_k, ikv, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, pos_r[0] // block_k, ikv, 0)),
-                pl.BlockSpec((1, 1, block_k),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, pos_r[0] // block_k)),
-                pl.BlockSpec((1, 1, block_k),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, pos_r[0] // block_k)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((gp, d), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, gp, d), q.dtype),
-            jax.ShapeDtypeStruct(cache_k.shape, jnp.int8),
-            jax.ShapeDtypeStruct(cache_v.shape, jnp.int8),
-            jax.ShapeDtypeStruct(k_scale.shape, jnp.float32),
-            jax.ShapeDtypeStruct(v_scale.shape, jnp.float32),
-        ],
-        # operand indices count the scalar-prefetch args: pos=0, pad=1,
-        # q=2, k_new=3, v_new=4, ck=5, cv=6, ks=7, vs=8 — the int8 arenas
-        # AND their scale planes all update in place
-        input_output_aliases={5: 1, 6: 2, 7: 3, 8: 4},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * C * d,
-            bytes_accessed=(2 * b * C * kv * (d + 4)    # int8 rows + f32 scales
-                            + 2 * block_k * kv * (d + 4)
-                            + b * h * d * q.dtype.itemsize),
-            transcendentals=b * h * C),
-        interpret=interpret,
-    )(pos_arr, pad_arr, q4, kn3, vn3, cache_k, cache_v, k_scale, v_scale)
-
-    out = out[:, :, :g, :].reshape(b, 1, h, d)
-    return out, ck_out, cv_out, ks_out, vs_out
-
-
-# ---------------------------------------------------------------------------
-# fp8 (f8e4m3fn) static-scale cache variant (long-context ladder)
-# ---------------------------------------------------------------------------
-
-_FP8_MAX = 448.0        # f8e4m3fn finite max (e4m3fn encodes no inf)
-_FP8_MIN_ROWS = 32      # fp8 min VMEM tile is (32, 128) sublanes x lanes
-
-
-def decode_attention_fp8_supported(q_shape, cache_shape, *,
-                                   block_k: int = DEFAULT_BLOCK_K,
-                                   emit_fallback: bool = False) -> bool:
-    """Shapes the fp8 decode kernel handles.  The fp8 cache needs the
-    same lane-aligned ``block_k`` as int8 plus fp8's larger minimum VMEM
-    tile (32 sublanes): a cache block slice is ``(block_k, d)`` fp8 rows.
-    With ``emit_fallback`` every gate rejection lands a
-    ``kernel_fallback`` event so an fp8 deployment silently serving the
-    einsum path is visible."""
-    def _reject(reason: str, **detail) -> bool:
-        if emit_fallback:
-            from ...telemetry import kernel_fallback
-
-            kernel_fallback("decode_attention_fp8", reason, **detail)
-        return False
-
-    if len(q_shape) != 4 or len(cache_shape) != 4:
-        return _reject("rank", q_rank=len(q_shape))
-    b, s, h, d = q_shape
-    _, C, kv, dc = cache_shape
-    if not _decode_shape_ok(q_shape, cache_shape, block_k):
-        return _reject("shape", q_shape=list(q_shape), cache_len=C,
-                       block_k=block_k)
-    if block_k % _LANES != 0 or block_k % _FP8_MIN_ROWS != 0:
-        return _reject("fp8_tile_alignment", block_k=block_k)
-    return True
-
-
-def _decode_kernel_fp8(pos_ref, pad_ref, q_ref, kn_ref, vn_ref, ck_ref,
-                       cv_ref, o_ref, cko_ref, cvo_ref, acc_ref, m_ref,
-                       l_ref, *, scale: float, kv_scale: float,
-                       block_k: int):
-    """Same online-softmax structure as :func:`_decode_kernel`, but the
-    cache blocks are f8e4m3fn under ONE static scale baked into the
-    program as a compile-time constant — no scale planes, no scale
-    loads.  Dequant fuses into the block math: the k factor folds into
-    the score scale (``q . (k*c) == (q . k) * c``) and the v factor is a
-    scalar VPU multiply on the block load.  The append clips to ±448
-    (e4m3fn saturates instead of producing inf) and writes the fp8 row
-    through the aliased buffer."""
-    ib, ik = pl.program_id(0), pl.program_id(2)
-    nk = pl.num_programs(2)
-    pos = pos_ref[0]
-    pad = pad_ref[ib]
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    def _bcast(col):
-        return jnp.broadcast_to(col, (col.shape[0], _LANES))
-
-    def _online(s_col, v_rows):
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s_col, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_ok = jnp.where(m_new == _NEG_INF, 0.0, m_new)
-        p = jnp.exp(s_col - m_ok)
-        alpha = jnp.exp(m_prev - m_ok)
-        l_ref[:] = _bcast(l_prev * alpha + jnp.sum(p, axis=1, keepdims=True))
-        m_ref[:] = _bcast(m_new)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v_rows.dtype), v_rows, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when((ik * block_k < pos) & ((ik + 1) * block_k > pad))
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32)            # (g, d)
-        k = ck_ref[0, :, 0, :].astype(jnp.float32)     # (block_k, d) fp8
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * (scale * kv_scale)                     # fused k dequant
-        col = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where((col < pos) & (col >= pad), s, _NEG_INF)
-        _online(s, cv_ref[0, :, 0, :].astype(jnp.float32) * kv_scale)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        # the new token folds in EXACT (pre-quantization k/v), same
-        # contract as the int8 kernel: next step's readers see the fp8
-        # row _append writes, exactly like the einsum oracle
-        q = q_ref[0, 0].astype(jnp.float32)
-        kn = kn_ref[0, 0].astype(jnp.float32)          # (1, d)
-        s_new = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) \
-            * scale
-        _online(s_new, vn_ref[0, 0].astype(jnp.float32))
-        l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-    @pl.when(ik == pos // block_k)
-    def _append():
-        row = pos % block_k
-        kn = kn_ref[0, 0].astype(jnp.float32)          # (1, d)
-        vn = vn_ref[0, 0].astype(jnp.float32)
-        cko_ref[0, :, 0, :] = ck_ref[0, :, 0, :]
-        cvo_ref[0, :, 0, :] = cv_ref[0, :, 0, :]
-        cko_ref[0, pl.ds(row, 1), 0, :] = jnp.clip(
-            kn / kv_scale, -_FP8_MAX, _FP8_MAX).astype(cko_ref.dtype)
-        cvo_ref[0, pl.ds(row, 1), 0, :] = jnp.clip(
-            vn / kv_scale, -_FP8_MAX, _FP8_MAX).astype(cvo_ref.dtype)
-
-
-def decode_attention_fp8(q, k_new, v_new, cache_k, cache_v, pos,
-                         pad_lens=None, *, kv_scale: float = 1.0,
-                         scale: Optional[float] = None,
-                         block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = False):
-    """Fused fp8-cache decode step: dequantize the f8e4m3fn k/v block
-    loads in place under the STATIC ``kv_scale`` (a compile-time scalar —
-    half of int8's per-page bytes because no scale planes exist),
-    clip+quantize+append the new token at ``pos``, and attend ``q`` over
-    cols ``[pad_lens, pos]``.
-
-    Returns ``(out, new_ck, new_cv)`` with the caches aliased in place."""
-    b, s, h, d = q.shape
-    _, C, kv, _ = cache_k.shape
-    assert s == 1, "decode kernel is single-query (s == 1)"
-    assert cache_k.dtype == jnp.float8_e4m3fn \
-        and cache_v.dtype == jnp.float8_e4m3fn
-    g = h // kv
-    gp = max(g, _MIN_SUBLANES)
-    sc = scale if scale is not None else 1.0 / (d ** 0.5)
-
-    q4 = q.reshape(b, kv, g, d)
-    if gp != g:
-        q4 = jnp.concatenate(
-            [q4, jnp.zeros((b, kv, gp - g, d), q4.dtype)], axis=2)
-    kn3 = jnp.transpose(k_new, (0, 2, 1, 3))           # [b, kv, 1, d]
-    vn3 = jnp.transpose(v_new, (0, 2, 1, 3))
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
-    pad_arr = (jnp.zeros((b,), jnp.int32) if pad_lens is None
-               else jnp.asarray(pad_lens, jnp.int32).reshape(b))
-
-    nk = C // block_k
-    kernel = functools.partial(_decode_kernel_fp8, scale=sc,
-                               kv_scale=float(kv_scale), block_k=block_k)
-    grid = (b, kv, nk)
-
-    out, ck_out, cv_out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, gp, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, 1, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, 1, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ik, ikv, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ik, ikv, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, gp, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, ikv, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, pos_r[0] // block_k, ikv, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda ib, ikv, ik, pos_r, pad_r:
-                             (ib, pos_r[0] // block_k, ikv, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((gp, d), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, kv, gp, d), q.dtype),
-            jax.ShapeDtypeStruct(cache_k.shape, jnp.float8_e4m3fn),
-            jax.ShapeDtypeStruct(cache_v.shape, jnp.float8_e4m3fn),
-        ],
-        # operand indices count the scalar-prefetch args: pos=0, pad=1,
-        # q=2, k_new=3, v_new=4, cache_k=5, cache_v=6
-        input_output_aliases={5: 1, 6: 2},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * h * C * d,
-            bytes_accessed=(2 * b * C * kv * d        # fp8 rows, 1 byte
-                            + 2 * block_k * kv * d
-                            + b * h * d * q.dtype.itemsize),
-            transcendentals=b * h * C),
-        interpret=interpret,
-    )(pos_arr, pad_arr, q4, kn3, vn3, cache_k, cache_v)
-
-    out = out[:, :, :g, :].reshape(b, 1, h, d)
-    return out, ck_out, cv_out
-
-
-# ---------------------------------------------------------------------------
-# TP-sharded dispatch gate (ISSUE 19)
-# ---------------------------------------------------------------------------
-
-def decode_attention_sharded_supported(q_shape, cache_shape, *, tp: int = 1,
-                                       block_k: int = DEFAULT_BLOCK_K,
-                                       int8: bool = False,
-                                       fp8: bool = False,
-                                       emit_fallback: bool = False) -> bool:
-    """Can the decode kernel run per-shard under a ``model``-axis mesh of
-    size ``tp``?  GSPMD partitions the kv-head axis (arena sharding
-    ``P(None, None, "model", None)``), so each shard sees
-    ``kv // tp`` cache heads and ``h // tp`` query heads — the kernel
-    itself is unchanged; this gate answers whether the PER-SHARD shapes
-    still satisfy the (int8-)kernel constraints.  Heads must divide
-    evenly: a ragged shard would silently change the q-group geometry.
-    ``tp == 1`` degrades to the unsharded gates."""
-    def _reject(reason: str, **detail) -> bool:
-        if emit_fallback:
-            from ...telemetry import kernel_fallback
-
-            kernel_fallback("decode_attention_sharded", reason, tp=tp,
-                            **detail)
-        return False
-
-    if tp < 1:
-        return _reject("bad_tp")
-    if len(q_shape) != 4 or len(cache_shape) != 4:
-        return _reject("rank", q_rank=len(q_shape))
-    b, s, h, d = q_shape
-    bc, C, kv, dc = cache_shape
-    if h % tp != 0 or kv % tp != 0:
-        return _reject("ragged_heads", h=h, kv=kv)
-    q_shard = (b, s, h // tp, d)
-    cache_shard = (bc, C, kv // tp, dc)
-    if int8 and fp8:
-        return _reject("conflicting_cache_dtypes")
-    if int8:
-        ok = decode_attention_int8_supported(q_shard, cache_shard,
-                                             block_k=block_k,
-                                             emit_fallback=emit_fallback)
-    elif fp8:
-        ok = decode_attention_fp8_supported(q_shard, cache_shard,
-                                            block_k=block_k,
-                                            emit_fallback=emit_fallback)
-    else:
-        ok = decode_attention_supported(q_shard, cache_shard,
-                                        block_k=block_k)
-        if not ok:
-            return _reject("shard_shape", q_shard=list(q_shard),
-                           cache_len=C, block_k=block_k)
-    return bool(ok)

@@ -510,19 +510,8 @@ class ServingEngine:
             self._scale_bytes = 2 * n_layers * int(np.prod(sshape)) * 4
         if self._mesh is not None and self.tp > 1:
             from .disagg import shard_arenas
-            from ..ops.pallas.decode_attention import \
-                decode_attention_sharded_supported
 
             arenas = shard_arenas(arenas, self._mesh)
-            # pure telemetry: would the Pallas decode kernel still take
-            # the per-shard shapes on accel?  (CPU tier-1 always uses the
-            # einsum path; a silent per-shard fallback must be visible.)
-            decode_attention_sharded_supported(
-                (self.max_batch, 1, att[0].heads, head_dim),
-                (self.max_batch, MP * P, kv_heads, head_dim),
-                tp=self.tp, int8=self.kv_dtype == "int8",
-                fp8=self.kv_dtype == "fp8",
-                emit_fallback=True)
         elif self._mesh is not None:
             # cp mesh: arenas replicate — each device aliases its full
             # copy, so the donation lint floors are unchanged (shards=1)

@@ -110,12 +110,12 @@ def _serve_tier() -> str:
     return os.environ.get("PADDLE_TPU_SERVE_TIER", "decode") or "decode"
 
 
-# -- in-memory KV (single-process fleets: bench, unit tests) -----------------
+# -- in-memory KV (single-process fleets: unit tests) ------------------------
 
 class LocalKV:
     """A put/touch/age/keys/delete KV in process memory, with an
-    injectable clock — the fake-clock lease-expiry tests and the bench's
-    in-process fleet use this where a real deployment uses the launcher's
+    injectable clock — the fake-clock lease-expiry tests and in-process
+    fleets use this where a real deployment uses the launcher's
     ``TCPStore``."""
 
     def __init__(self, now: Callable[[], float] = time.monotonic):

@@ -1,9 +1,10 @@
 """Quantized KV-cache pages: dtype resolution, per-token int8 scales,
 and DTYPE_BYTES-priced page accounting.
 
-The serving MBU wall is raw bytes-per-token (BENCH_r05: 0.576 MBU at 8K
-context); int8 pages halve the cache bytes behind that ceiling AND double
-how many concurrent users a fixed pool holds.  Scheme:
+Decode is bound by the bytes read a token (what a quantized pool gains on
+the chip is not measured on the current tree; see PERF.md); int8 pages
+halve the cache bytes AND double how many concurrent users a fixed pool
+holds.  Scheme:
 
 - **Storage** — the page arenas become ``int8`` (exactly half the bf16
   itemsize) and a per-(token-slot, kv-head) ``float32`` scale rides in a
@@ -13,9 +14,9 @@ how many concurrent users a fixed pool holds.  Scheme:
   so per-token scales need no calibration pass and are exact for the
   token they cover (a per-page scale would need the whole page up front).
 - **Dequant at the load** — the gather that builds a row's paged view
-  multiplies the int8 block by its scale column in the same fused program
-  (and the Pallas decode kernel does the multiply on its k/v block loads),
-  so no dequantized copy of the cache ever materializes in HBM.
+  multiplies the int8 block by its scale column in the same program
+  (``ServingEngine._paged_attention``), so no dequantized copy of the
+  ARENAS is ever kept.
 - **Calibration seam** — :func:`observe_kv_absmax` runs the PTQ
   :class:`~paddle_tpu.quantization.AbsmaxObserver` over sample KV tensors;
   the per-tensor scale it yields is what a static-scale format (the fp8

@@ -234,7 +234,7 @@ class SLOMeter:
     def _count_token(self, c: RequestClock) -> None:
         """Recomputing an already-produced token after an eviction is
         replay WORK, not new output — count the two separately so the
-        bench's token totals match what the stream actually delivered."""
+        token totals match what the stream actually delivered."""
         if c.n_tokens <= c.replay_watermark:
             bump("serving.tokens_replayed")
         else:

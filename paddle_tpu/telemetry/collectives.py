@@ -13,8 +13,7 @@ Two recording modes, matching how collectives actually reach the hardware:
   per-step collective profile — and bump its execution counter per call, so
   executed bytes stay accurate without re-tracing.
 
-Wire cost uses the standard ring formulas (the same accounting bench.py's
-HLO walker applies): all-reduce moves ``2(n-1)/n * S`` bytes per chip,
+Wire cost uses the standard ring formulas: all-reduce moves ``2(n-1)/n * S`` bytes per chip,
 gather/scatter ``(n-1)/n * S``, permute ``S``; the time estimate prices
 those bytes at the chip's public one-way ICI bandwidth.
 """
@@ -32,7 +31,7 @@ __all__ = ["record_collective", "collective_stats", "ici_cost_estimate",
            "PEAK_TFLOPS", "ICI_GBPS_ONEWAY", "PEAK_HBM_GBPS", "chip_lookup"]
 
 # ---------------------------------------------------------------------------
-# chip tables (single home — bench.py and chip_smoke.py price against these)
+# chip tables (single home — chip_smoke.py and StepMeter price against these)
 #
 # Keyed by substrings of ``device.device_kind`` ("TPU v5 lite", "TPU v5e",
 # "TPU v5p", "TPU v4", "TPU v6 lite" / "TPU v6e").  Sources:

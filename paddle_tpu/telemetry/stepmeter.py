@@ -3,7 +3,7 @@ FLOP/byte model, loss/grad-norm, HBM watermarks, per-step collective bytes,
 and training-health columns (``skipped`` / ``steps_skipped``; an optional
 ``health_guard`` feeds the spike detector from the same values).
 
-Driven by the training loop (and bench.py)::
+Driven by the training loop::
 
     meter = StepMeter("llama", tokens_per_step=batch*seq, model_params=N,
                       jsonl_path="telemetry/steps.jsonl")

@@ -41,6 +41,13 @@ GRAD_TOLERANCES: Dict[str, Dict[str, float]] = {
 }
 
 
+def sum_order_atol(ref: np.ndarray, k: int) -> float:
+    """How far two float32 evaluations of the same ``k``-term dots may
+    differ when they add the products in different orders: each of the
+    ``k`` additions rounds by at most half an ulp of the running sum."""
+    return k * np.finfo(np.float32).eps * float(np.abs(ref).max())
+
+
 def _run_op(op: Callable, arrays: Sequence[np.ndarray], dtype: str,
             stop_gradient: bool = True):
     tensors = [paddle.to_tensor(a.astype(np.float32)).astype(dtype)

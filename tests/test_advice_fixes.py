@@ -1,4 +1,4 @@
-"""Regression tests for advisor findings (ADVICE.md). Round 1: batch_norm
+"""Regression tests for advisor findings. Round 1: batch_norm
 eager gradients, pool ceil_mode/return_mask, AmpScaler.minimize contract,
 interpolate align_corners, AdamW lr_ratio. Round 3: rpc frame auth, ASP
 masks registered after TrainStep compilation, DataLoader unpicklable custom

@@ -28,6 +28,18 @@ from paddle_tpu.analysis import (Baseline, Finding, Severity, analyze_perm,
 pytestmark = pytest.mark.analysis
 
 
+@pytest.fixture(scope="class")
+def interpreted_kernels():
+    """Pallas kernels through the interpreter while a class's programs are
+    traced (class scope: ``hybrid_step`` is traced lazily by later tests);
+    the flag goes back afterwards, so no later test of the worker
+    (``test_tpu_lowering.py`` lowers the real kernels) inherits it."""
+    prior = paddle.get_flags(["pallas_interpret"])
+    paddle.set_flags({"pallas_interpret": True})
+    yield
+    paddle.set_flags(prior)
+
+
 def _mesh(axes):
     names = tuple(axes)
     sizes = tuple(axes[a] for a in names)
@@ -109,7 +121,7 @@ class TestInvoluntaryRematFixture:
     and TestBaseline on synthetic diagnostics.)"""
 
     @pytest.fixture(scope="class")
-    def hybrid_step(self):
+    def hybrid_step(self, interpreted_kernels):
         strategy = dist.fleet.DistributedStrategy()
         strategy.hybrid_configs = {
             "dp_degree": 2, "mp_degree": 1, "pp_degree": 2,
@@ -122,7 +134,6 @@ class TestInvoluntaryRematFixture:
 
         cfg = llama_tiny(num_hidden_layers=4, num_attention_heads=4,
                          num_key_value_heads=2)
-        paddle.set_flags({"pallas_interpret": True})
         model = LlamaForCausalLMHybrid(cfg, hcg)
         opt = paddle.optimizer.AdamW(1e-3, parameters=model.parameters())
         step = dist.DistributedTrainStep(
@@ -476,7 +487,7 @@ class TestCleanPrograms:
         report = lint(step, args=(ids, ids), baseline=False)
         assert report.findings == [], report.format()
 
-    def test_tp_hybrid_step_lints_clean(self):
+    def test_tp_hybrid_step_lints_clean(self, interpreted_kernels):
         """mp2×pp2×dp2 (dryrun factorization 1): the TP slice — scanned
         pipe stack and GSPMD TP layers included — produces ZERO findings;
         the remat debt is specific to the ZeRO-3 × pipe layout mix."""
@@ -492,7 +503,6 @@ class TestCleanPrograms:
 
         cfg = llama_tiny(num_hidden_layers=4, num_attention_heads=4,
                          num_key_value_heads=2)
-        paddle.set_flags({"pallas_interpret": True})
         model = LlamaForCausalLMHybrid(cfg, hcg)
         opt = paddle.optimizer.AdamW(1e-3, parameters=model.parameters())
         step = dist.DistributedTrainStep(

@@ -18,8 +18,6 @@ from paddle_tpu.distributed.checkpoint.replicator import (FencedEpoch,
                                                           SnapshotClient,
                                                           SnapshotStore)
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny
-from paddle_tpu.ops.pallas.decode_attention import \
-    decode_attention_sharded_supported
 from paddle_tpu.serving import (PagedKVPool, PrefixCache, ServingEngine,
                                 TRASH_PAGE)
 from paddle_tpu.serving.disagg import (DisaggCoordinator, PrefillWorker,
@@ -304,17 +302,6 @@ class TestPrefixEngine:
 # -- TP-sharded decode -------------------------------------------------------
 
 class TestTPDecode:
-    def test_sharded_dispatch_gate(self):
-        ok = decode_attention_sharded_supported
-        # the bf16 kernel needs whole (16, 128) tiles of kv heads per shard
-        assert ok((4, 1, 32, 64), (4, 256, 32, 64), tp=2)
-        assert ok((4, 1, 32, 64), (4, 256, 32, 64), tp=1)
-        assert not ok((4, 1, 8, 64), (4, 256, 4, 64), tp=2)   # kv/shard = 2
-        assert ok((4, 1, 8, 64), (4, 256, 4, 64), tp=4, int8=True)
-        assert not ok((4, 1, 8, 64), (4, 256, 4, 64), tp=3)   # ragged
-        assert not ok((4, 1, 32, 64), (4, 128, 32, 64), tp=2)  # C < block_k
-        assert not ok((4, 1, 8), (4, 256, 4, 64), tp=2)       # rank
-
     def test_ragged_tp_raises_at_construction(self, tp_model):
         with pytest.raises(ValueError, match="must divide"):
             ServingEngine(tp_model, tp=3, **KW)

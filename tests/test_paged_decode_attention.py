@@ -230,3 +230,44 @@ def test_speculative_width_walks_pages(model, interpreted):
     _, plain = _serve(model, speculative=2)
     assert got == plain and eng._decode_compiles == 1
     assert not _fallbacks()
+
+
+def test_mesh_engine_programs_refuse_kernels_by_name(monkeypatch):
+    """A TP / CP engine's programs are traced inside ``gspmd_program()``
+    (``ServingEngine._compile``): GSPMD cannot partition a Mosaic call, so
+    on a TPU every kernel dispatch in them is refused and counted as
+    ``no_mesh``.  A one-device engine's programs get their kernels and
+    count nothing.  (The platform check is steered here, in the test: the
+    CPU answers "not a TPU" before the scope is ever asked.)"""
+    import paddle_tpu.ops as ops
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs >= 2 (virtual) devices")
+
+    def build():        # TP shards the params in place: a model an engine
+        paddle.seed(5)
+        m = LlamaForCausalLM(llama_tiny(num_hidden_layers=2, vocab_size=96,
+                                        max_position_embeddings=128))
+        m.eval()
+        return m
+
+    kw = dict(max_batch=3, page_tokens=8, num_pages=24, max_pages_per_seq=6)
+    seen = []
+
+    def probe(params, buffers, arenas):
+        seen.append(ops.pallas_eligible("use_decode_attention"))
+        return arenas
+
+    args = (jnp.zeros(()), jnp.zeros(()), jnp.zeros((2,)))
+    key = "kernel_fallback.use_decode_attention.no_mesh"
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    prior = paddle.get_flags(["pallas_interpret"])
+    paddle.set_flags({"pallas_interpret": False})
+    telemetry.reset()
+    try:
+        ServingEngine(build(), **kw)._compile(probe, args, "probe")
+        assert seen == [True] and key not in telemetry.counters()
+        ServingEngine(build(), tp=2, **kw)._compile(probe, args, "probe")
+        assert seen == [True, False] and telemetry.counters()[key] == 1
+    finally:
+        paddle.set_flags(prior)

@@ -8,7 +8,8 @@ kernels on default-on product paths shipped with such specs because every
 test ran them in interpret mode, which checks nothing of the kind:
 ``decode_attention`` blocked one kv head out of ``[b, C, kv, d]`` and
 ``flash_attention_varlen`` blocked one element out of a rank-1 ``(b,)``.
-This file is the test that would have caught both, at the bench's shapes:
+This file is the test that would have caught both, at ``chip_smoke.py``'s
+shapes:
 
 - every Pallas kernel a default-on flag dispatches to;
 - every shape a ``*_supported`` gate accepts must lower;
@@ -35,7 +36,7 @@ from paddle_tpu.ops.pallas import (decode_attention,
                                    paged_decode_attention_refusal)
 
 BF16 = jnp.bfloat16
-# Llama-670M widths (bench.py, chip_smoke.py); depth cut to two layers
+# Llama-670M widths (chip_smoke.py); depth cut to two layers
 WIDTHS = dict(vocab_size=32000, hidden_size=2048, intermediate_size=8192,
               num_hidden_layers=2, num_attention_heads=16,
               num_key_value_heads=16)
@@ -167,7 +168,7 @@ class TestKernelsLower:
 
     @pytest.mark.parametrize("b,C,h,kv,d,blk,dtype", [
         (8, 160, 16, 16, 128, 160, BF16),     # chip_smoke generate, 2K
-        (8, 256, 16, 16, 128, 256, BF16),     # bench_llama_decode
+        (8, 256, 16, 16, 128, 256, BF16),     # a whole 256-row block
         (4, 8192, 16, 16, 128, 256, BF16),    # the 8K point (ROADMAP S4)
         (2, 512, 32, 16, 64, 256, BF16),      # GQA, head_dim 64
         (2, 512, 32, 32, 128, 128, BF16),     # 32 MHA heads
