@@ -684,7 +684,7 @@ def serve(sz: Sizes) -> dict:
                 f"{calls} paged_decode_attention calls in the decode "
                 f"program, expected {sz.layers}")
     # the engine raises on a non-finite live row every step; the last
-    # step's logits are its host copy
+    # step's logits stayed on the device and are fetched here
     logits = eng.last_decode_logits
     require(logits is not None and logits.shape[-1] == sz.vocab
             and bool(np.isfinite(logits).any()),

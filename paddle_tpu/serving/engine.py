@@ -139,6 +139,23 @@ def prefill_plan(pages: int, widths=PREFILL_WIDTHS) -> List[int]:
     return out
 
 
+# What the decode program writes for a position whose logits hold a NaN or an
+# infinity, in place of a token id.
+NON_FINITE = -1
+
+
+def greedy_choice(logits):
+    """The greedy token of every position of ``logits`` [..., V], chosen on
+    the device from the values as they are (no cast): int32 [...], the first
+    index of the maximum as ``np.argmax`` picks it, or :data:`NON_FINITE`
+    where the position holds a NaN or an infinity."""
+    import jax.numpy as jnp
+
+    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.where(jnp.all(jnp.isfinite(logits), axis=-1), tok,
+                     jnp.int32(NON_FINITE))
+
+
 # Tracing a program swaps tracers into the model's param Tensors
 # (``_StateSwap`` in ``_forward``), so two engines sharing one model object
 # — an in-process fleet scaling out while the incumbent serves — must never
@@ -616,8 +633,8 @@ class ServingEngine:
         self._prefill_exec: Dict[int, object] = {}     # width -> program
         self._decode_compiles = 0
         self.lint_report = None
-        self.last_decode_logits = None   # host copy of the latest verify
-        # logits [R, S, V] — the int8-vs-bf16 tolerance harness reads it
+        self._decode_logits = None       # the latest step's logits
+        # [R, S, V], left on the device (:attr:`last_decode_logits`)
         self.steps_total = 0
         self.first_step_wall: Optional[float] = None   # WARMING until set:
         # a replica advertises warming=True on its lease until its first
@@ -870,6 +887,19 @@ class ServingEngine:
         """Ask a ``forever`` loop to return once it drains to idle."""
         self._stop_flag = True
         self._work.set()
+
+    @property
+    def last_decode_logits(self) -> Optional[np.ndarray]:
+        """Host copy of the latest decode step's logits ``[R, S, V]`` in the
+        program's own dtype, None before the first step.  FETCHED ON
+        REQUEST: the array stays on the device and a step pulls only its
+        token ids, so every read costs a transfer of the whole array and is
+        counted (``SLOMeter.summary()["decode_logits_fetches"]``).  The
+        tolerance harnesses read it; the serving loop never does."""
+        if self._decode_logits is None:
+            return None
+        self.meter.decode_logits_fetched()
+        return np.asarray(self._decode_logits)
 
     def row_state(self, rid: int) -> Dict[str, np.ndarray]:
         """Host copy of a RUNNING request's fixed-size state: per name one
@@ -1478,16 +1508,16 @@ class ServingEngine:
             _faults.fire("serve_decode", f"step{self.steps_total}")
             _faults.fire("slow_serve", f"{self.fault_scope}/decode")
             with _span("serve.decode.dispatch"):
-                logits = self._run_decode(jnp.asarray(tokens),
+                choice = self._run_decode(jnp.asarray(tokens),
                                           jnp.asarray(positions),
                                           jnp.asarray(tables),
                                           jnp.asarray(n_tok))
+            # the step's one sync point: the chosen ids, not the logits
             with _span("serve.decode.to_host") as to_host:
-                logits = np.asarray(logits)               # [R, S, V]
-                to_host.note(bytes=logits.nbytes)
-            self.last_decode_logits = logits
+                choice = np.asarray(choice)               # [R, S] int32
+                to_host.note(bytes=choice.nbytes)
             with _span("serve.decode.sample"):
-                self._decode_sample(stepped, logits, n_tok, drafts)
+                self._decode_sample(stepped, choice, n_tok, drafts)
 
     def _decode_prep(self):
         """Host-side inputs of one decode step: ``(stepped rows, tokens,
@@ -1529,12 +1559,18 @@ class ServingEngine:
             return None
         return stepped, tokens, n_tok, positions, tables, drafts
 
-    def _decode_sample(self, stepped, logits, n_tok, drafts) -> None:
+    def _decode_sample(self, stepped, choice, n_tok, drafts) -> None:
+        """Book one step's tokens.  ``choice`` [R, S]: what the program
+        chose at each position (:func:`greedy_choice`).  Only a stepped
+        row's live positions ``[:n_tok[row]]`` are looked at: idle rows and
+        the junk past a row's drafts hold whatever the program made of
+        them."""
         proposed_total = accepted_total = emitted_total = 0
+        choice, n_tok = choice.tolist(), n_tok.tolist()
         for r in stepped:
-            nv = int(n_tok[r.row])
-            row_logits = logits[r.row, :nv]
-            if not np.all(np.isfinite(row_logits)):
+            nv = n_tok[r.row]
+            chosen = choice[r.row][:nv]
+            if min(chosen) == NON_FINITE:
                 # a corrupted int8 scale (or any cache poisoning) surfaces
                 # as NaN/inf logits — fail LOUDLY instead of emitting junk
                 raise RuntimeError(
@@ -1544,7 +1580,7 @@ class ServingEngine:
             d = drafts.get(r.rid, [])
             emitted: List[int] = []
             for i in range(nv):
-                tok = int(np.argmax(row_logits[i]))
+                tok = chosen[i]
                 r.generated.append(tok)
                 self.meter.token(r.rid)
                 self._deliver(r, tok)
@@ -1867,9 +1903,12 @@ class ServingEngine:
         """ONE compiled decode signature: ``tokens`` [R, S] where S is the
         fixed speculative width (1 + k_max; 1 when speculation is off) and
         ``n_tok`` carries each row's live width — adapting k never
-        recompiles.  Returns logits [R, S, V]."""
-        return self._forward(param_arrays, buffer_arrays, arenas, tokens,
-                             positions, tables, n_tok)
+        recompiles.  Returns the greedy choice [R, S]
+        (:func:`greedy_choice`: all the host fetches of a step), the logits
+        [R, S, V] it was made from, and the arenas."""
+        logits, arenas = self._forward(param_arrays, buffer_arrays, arenas,
+                                       tokens, positions, tables, n_tok)
+        return greedy_choice(logits), logits, arenas
 
     def _page_walk(self, rows: int, width: int, layer: AttentionLayer):
         """How one attention layer of the decode program attends, decided at
@@ -1988,8 +2027,10 @@ class ServingEngine:
                     self._decode_exec, self._arena_bytes,
                     scale_bytes=self._scale_bytes, shards=self.tp,
                     state_bytes=self.state.nbytes if self.state else 0)
-        logits, self._arenas = self._decode_exec(*args)
-        return logits
+        # the previous step's logits are dropped here, never fetched unless
+        # someone asked (:attr:`last_decode_logits`)
+        choice, self._decode_logits, self._arenas = self._decode_exec(*args)
+        return choice
 
     def _run_prefill(self, tokens, chunk_start, tables, take_idx, row,
                      n_valid):

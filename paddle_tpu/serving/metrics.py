@@ -117,6 +117,8 @@ class SLOMeter:
         # model has no state layers
         self.finished_total = 0
         self.prefill_launches_total = 0  # launches of a prefill program
+        self.decode_logits_fetches_total = 0   # whole [R, S, V] arrays
+        # pulled to the host (``ServingEngine.last_decode_logits``)
         self.evictions_total = 0
         self.shed_total = 0
         self.shed_reasons: Dict[str, int] = {}
@@ -224,6 +226,11 @@ class SLOMeter:
     def prefill_launched(self, launches: int) -> None:
         """One prompt's prefill took ``launches`` program launches."""
         self.prefill_launches_total += int(launches)
+
+    def decode_logits_fetched(self) -> None:
+        """Someone read the decode logits: one whole array came to the
+        host."""
+        self.decode_logits_fetches_total += 1
 
     def token(self, rid) -> None:
         c = self._clocks[rid]
@@ -490,6 +497,7 @@ class SLOMeter:
             "deadline_miss_rate": round(self.deadline_miss_rate(), 4),
             "evictions": self.evictions_total,
             "prefill_launches": self.prefill_launches_total,
+            "decode_logits_fetches": self.decode_logits_fetches_total,
             "kv_pool_occupancy_peak": round(self.occupancy_peak, 4),
             "state_slots_peak": (None if self.state_slots_peak is None
                                  else round(self.state_slots_peak, 4)),

@@ -226,9 +226,10 @@ def test_serving_span_counts_match_steps_and_requests(recorded):
                  "serve.decode.sample"):
         assert len(spans[name]) == len(ran), name
     assert len(spans["serve.decode.prep"]) == len(decodes)
-    vocab = 96
+    # what a step fetches: one int32 a position [R, S], never the logits
     assert {s[2]["bytes"] for s in spans["serve.decode.to_host"]} == \
-        {eng.max_batch * 1 * vocab * 4}
+        {eng.max_batch * 1 * 4}
+    assert eng.meter.summary()["decode_logits_fetches"] == 0
     assert sum(s[2]["tokens"] for s in spans["serve.deliver"]) == \
         len(PROMPTS) * NEW_TOKENS
 

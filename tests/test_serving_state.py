@@ -47,11 +47,12 @@ def spy_on_decode(eng):
     filled as the engine steps."""
     seen, sample = {}, eng._decode_sample
 
-    def spy(stepped, logits, n_tok, drafts):
+    def spy(stepped, choice, n_tok, drafts):
+        logits = eng.last_decode_logits
         for r in stepped:
             seen.setdefault(r.rid, []).append(
                 (r.row, np.asarray(logits[r.row, 0], np.float32)))
-        return sample(stepped, logits, n_tok, drafts)
+        return sample(stepped, choice, n_tok, drafts)
 
     eng._decode_sample = spy
     return seen
