@@ -21,10 +21,19 @@ Two layer classes:
   ``nn.LayerList`` experts, gate configurable by dict or Gate instance. The
   expert loop is unrolled (E static sub-graphs); fine for eager parity +
   moderate E.
-- :class:`ExpertParallelMLP` — the flagship path: stacked expert weights
+- :class:`ExpertParallelMLP` — the GShard path: stacked expert weights
   ``[E, d, h]`` applied with one batched einsum, expert axis shardable over
-  mesh axes (``expert_axes``) under the engine/pjit. This is what an MoE
-  transformer should use on TPU.
+  mesh axes (``expert_axes``) under the engine/pjit.  ``LlamaForCausalLM``'s
+  MoE variants train through it.
+
+Which layer a transformer should use now: a model whose experts are routed
+without a capacity (no token dropped), whose router is sigmoid / group-limited,
+or which holds only its chip's share of the experts takes
+:class:`paddle_tpu.nn.layer.moe.RoutedExperts` — sort-based dropless dispatch
+over a grouped matmul, told ``experts_held`` (``DeepseekV3ForCausalLM`` does).
+The two classes here keep GShard's capacity semantics (overflow tokens are
+dropped) and their ``[N, E, C]`` dispatch tensors; they are not given a third
+dispatch.
 """
 
 from __future__ import annotations

@@ -1,22 +1,32 @@
 """How a causal LM tells ``ServingEngine`` what each of its layers keeps.
 
-A served model describes itself layer by layer (``serve_layers()``): an
-:class:`AttentionLayer` keeps K/V that grow by a token a step, in pages of
-the engine's ``PagedKVPool``; a :class:`StateLayer` (a state-space /
-recurrent mixer) keeps arrays of a FIXED shape per request — for Mamba-2 the
-last ``d_conv - 1`` convolution inputs and the ``[H, P, N]`` recurrent state
-— however long the request is (``serving/state_pool.py`` holds those).
+A served model describes itself layer by layer (``serve_layers()``), and a
+layer is one of three kinds: an :class:`AttentionLayer` keeps K/V that grow
+by a token a step, in pages of the engine's ``PagedKVPool``; a
+:class:`LatentAttentionLayer` (multi-head latent attention) keeps ONE latent
+row a token in the same pages, ``[c_kv | k_rope]``, shared by every head and
+never expanded to per-head K/V in the cache; a :class:`StateLayer` (a
+state-space / recurrent mixer) keeps arrays of a FIXED shape per request —
+for Mamba-2 the last ``d_conv - 1`` convolution inputs and the ``[H, P, N]``
+recurrent state — however long the request is (``serving/state_pool.py``
+holds those).
 
 The engine walks ``serve_layers()`` once per program and owns pages, tables
 and state slots; the model owns its block math::
 
-    model.serve_layers()                       -> [AttentionLayer | StateLayer]
+    model.serve_layers()     -> [AttentionLayer | LatentAttentionLayer | StateLayer]
     model.serve_begin(tokens, positions)       -> (x, shared)
     model.serve_layer(i, x, shared, io)        -> x
     model.serve_end(x)                         -> logits
 
 ``io`` is the engine's side of layer ``i``: ``io.attend(q, k, v)`` scatters
 this step's K/V into the layer's pages and attends each row over its pages;
+``io.attend_latent(q_nope, q_rope, c_kv, k_rope, w_uk, w_uv)`` does the same
+for a latent layer's rows, in the absorbed form in the decode program and
+the expanded form in the prefill program; ``io.note(name, value)`` hands the
+engine a count made inside the program (it rides the step's one fetch);
+``io.keep(name, value)`` leaves an array on the device beside the decode
+step's logits, for a tolerance harness to fetch (no step does);
 ``io.read_state(name)`` / ``io.write_state(name, value)`` read and write the
 rows' slots of one state array; ``io.n_valid [R]`` says how many of a row's
 tokens are real and ``io.live [R]`` which rows step at all.
@@ -31,7 +41,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-__all__ = ["AttentionLayer", "StateLayer"]
+__all__ = ["AttentionLayer", "LatentAttentionLayer", "StateLayer"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +52,31 @@ class AttentionLayer:
     kv_heads: int
     head_dim: int
     scale: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionLayer:
+    """A layer whose cache is one latent row a token (MLA, DeepSeek-V2):
+    ``latent_dim`` lanes of the normalised compressed K/V ``c_kv`` — the
+    value too — then ``rope_dim`` lanes of the one rotated key part all
+    heads share.  Head ``i``'s key is ``[W_UK,i c_kv | k_rope]``
+    (``nope_dim + rope_dim`` wide), its value ``W_UV,i c_kv`` (``v_dim``);
+    ``scale`` multiplies the scores."""
+    heads: int
+    latent_dim: int
+    rope_dim: int
+    nope_dim: int
+    v_dim: int
+    scale: float
+
+    @property
+    def row_width(self) -> int:
+        """Lanes of a cached row: ``latent_dim + rope_dim`` rounded up to
+        whole 128-lane registers.  The chip tiles the minor axis by 128 in
+        HBM whatever is asked (576 lanes occupy 640), and Mosaic refuses a
+        copy of a 576-wide slice, so the padding is stated, zero, and costs
+        nothing more."""
+        return -(-(self.latent_dim + self.rope_dim) // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)

@@ -31,6 +31,9 @@ from .flash_attention import (flash_attention, flash_attention_supported,
 from .decode_attention import decode_attention, decode_attention_supported
 from .paged_decode_attention import (paged_decode_attention,
                                      paged_decode_attention_refusal)
+from .mla_paged_decode_attention import (mla_paged_decode_attention,
+                                         mla_paged_decode_attention_refusal)
+from .grouped_matmul import grouped_matmul, grouped_matmul_refusal
 from .ssm_state_update import ssm_state_update, ssm_state_update_refusal
 from .fused_norm import fused_rms_norm
 from .rope import fused_rope
@@ -39,5 +42,8 @@ __all__ = ["flash_attention", "flash_attention_supported",
            "flash_attention_varlen", "flash_attention_varlen_supported",
            "decode_attention", "decode_attention_supported",
            "paged_decode_attention", "paged_decode_attention_refusal",
+           "mla_paged_decode_attention",
+           "mla_paged_decode_attention_refusal",
+           "grouped_matmul", "grouped_matmul_refusal",
            "ssm_state_update", "ssm_state_update_refusal",
            "fused_rms_norm", "fused_rope"]

@@ -53,14 +53,15 @@ downgrade to the eviction-replay re-prefill — the "offload stall" row),
 and ``kv_dtype="fp8"`` stores f8e4m3fn pages under one static scale at
 exactly half the bf16 page bytes."""
 
-from .kv_pool import (OffloadPool, PagedKVPool, PoolExhausted,  # noqa: F401
-                      TRASH_PAGE, default_offload_pages,
-                      default_page_tokens)
+from .kv_pool import (LatentLayersUnsupported, OffloadPool,  # noqa: F401
+                      PagedKVPool, PoolExhausted, TRASH_PAGE,
+                      default_offload_pages, default_page_tokens)
 from .kv_quant import (FP8_MAX, KV_DTYPES, default_fp8_scale,  # noqa: F401
                        dequantize_kv, dequantize_kv_fp8, kv_cache_dtype,
-                       kv_page_bytes, kv_scale_page_bytes,
+                       kv_page_bytes, kv_scale_page_bytes, layer_page_bytes,
                        observe_kv_absmax, quantize_kv, quantize_kv_fp8)
-from ..models.serve_protocol import AttentionLayer, StateLayer  # noqa: F401
+from ..models.serve_protocol import (AttentionLayer,  # noqa: F401
+                                     LatentAttentionLayer, StateLayer)
 from .state_pool import RowStatePool, StateLayersUnsupported  # noqa: F401
 from .metrics import FleetMeter, RequestClock, SLOMeter  # noqa: F401
 from .admission import (AdmissionController, CircuitBreaker, Deadline,  # noqa: F401
@@ -82,11 +83,12 @@ from .disagg import (DisaggCoordinator, PrefillWorker,  # noqa: F401
 __all__ = [
     "PagedKVPool", "PoolExhausted", "TRASH_PAGE", "default_page_tokens",
     "OffloadPool", "default_offload_pages",
-    "AttentionLayer", "StateLayer", "RowStatePool", "StateLayersUnsupported",
+    "AttentionLayer", "LatentAttentionLayer", "StateLayer", "RowStatePool",
+    "StateLayersUnsupported", "LatentLayersUnsupported",
     "KV_DTYPES", "kv_cache_dtype", "quantize_kv", "dequantize_kv",
     "quantize_kv_fp8", "dequantize_kv_fp8", "default_fp8_scale", "FP8_MAX",
     "observe_kv_absmax", "kv_page_bytes", "kv_scale_page_bytes",
-    "RequestClock", "SLOMeter", "FleetMeter",
+    "layer_page_bytes", "RequestClock", "SLOMeter", "FleetMeter",
     "AdmissionController", "CircuitBreaker", "Deadline", "Overloaded",
     "JournalState", "ServingJournal", "TokenSink",
     "Request", "ServingEngine", "check_decode_donation",

@@ -13,7 +13,9 @@ Design (TPU-first: *nothing* recompiles as traffic changes shape):
 - **Physical cache** — per layer, ``k_pages``/``v_pages`` arenas of shape
   ``[num_pages, page_tokens, kv_heads, head_dim]`` (a token's heads merged,
   ``[num_pages, page_tokens, kv_heads * head_dim]``, where a head is
-  narrower than the chip's 128 lanes).  Both compiled
+  narrower than the chip's 128 lanes); for a model with latent-attention
+  layers (MLA) ONE arena a layer, ``[num_pages, page_tokens, row_width]``:
+  a latent row a token and nothing per head.  Both compiled
   programs take the arenas DONATED, update them with scatter-writes, and
   return them; XLA aliases the buffers so the cache never copies (the
   donation lint below enforces exactly this).  A model with state-space
@@ -94,14 +96,15 @@ from ..telemetry import tracing
 from ..telemetry.runtime import bump as _bump
 from .admission import AdmissionController, Deadline, Overloaded
 from .journal import ServingJournal
-from .kv_pool import OffloadPool, PagedKVPool, PoolExhausted, TRASH_PAGE, \
-    default_page_tokens
+from .kv_pool import LatentLayersUnsupported, OffloadPool, PagedKVPool, \
+    PoolExhausted, TRASH_PAGE, default_page_tokens
 from .kv_quant import (default_fp8_scale, dequantize_kv, dequantize_kv_fp8,
-                       kv_cache_dtype, kv_page_bytes, kv_scale_page_bytes,
+                       kv_cache_dtype, kv_scale_page_bytes, layer_page_bytes,
                        quantize_kv, quantize_kv_fp8)
 from .metrics import SLOMeter
 from .prefix_cache import PrefixCache
-from ..models.serve_protocol import AttentionLayer, StateLayer
+from ..models.serve_protocol import AttentionLayer, LatentAttentionLayer, \
+    StateLayer
 from .state_pool import RowStatePool, StateLayersUnsupported
 
 __all__ = ["Request", "ServingEngine", "check_decode_donation"]
@@ -231,6 +234,9 @@ def check_decode_donation(compiled, arena_bytes: int,
     buffers ride the same donation: an unaliased scale arena silently
     copies ``2 * layers * pages * page_tokens * kv_heads`` floats per
     step, so the gate requires ``arena_bytes + scale_bytes`` aliased.
+    ``arena_bytes`` is the page planes as the engine allocated them, whatever
+    a page's row holds: K and V of every kv head, or a latent layer's one
+    latent row a token.
     ``state_bytes``: the row-state arenas of a model with state layers
     (:class:`RowStatePool`), which must be aliased all the same — an
     unaliased one copies every row's recurrent state every step.
@@ -282,9 +288,9 @@ class _LayerIO:
     step at all).  The updated arenas land in the program's result."""
 
     def __init__(self, engine, spec, arenas, index, tables, positions,
-                 n_tok, n_valid, row, fresh):
+                 n_tok, n_valid, row, fresh, notes, kept):
         self._eng, self._spec, self._arenas = engine, spec, arenas
-        self._index = index
+        self._index, self._notes, self._kept = index, notes, kept
         self._tables, self._positions, self._n_tok = tables, positions, n_tok
         self._row, self._fresh = row, fresh
         self.n_valid = n_valid
@@ -304,6 +310,48 @@ class _LayerIO:
         for key, arena in new.items():
             self._arenas[key][self._index] = arena
         return out
+
+    def attend_latent(self, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv):
+        """Scatter this step's latent rows (``c_kv`` [R, s, latent],
+        ``k_rope`` [R, s, rope], already rotated) into the layer's pages
+        and attend ``q_nope`` [R, s, h, nope] / ``q_rope`` [R, s, h, rope]
+        over each row's pages.  ``w_uk`` [latent, h, nope] and ``w_uv``
+        [latent, h, v] are the layer's up-projections: the decode program
+        absorbs them into the query and the output and walks the latent
+        rows as they lie; the prefill program expands the row's pages to
+        per-head K/V inside the program.  Returns [R, s, h, v]."""
+        eng, spec = self._eng, self._spec
+        decode = self._row is None
+        walk = eng._latent_walk(q_nope.shape[0], q_nope.shape[1], spec) \
+            if decode else None
+        out, pages = eng._attend_latent(
+            q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
+            self._arenas["c"][self._index], self._tables, self._positions,
+            self._n_tok, spec=spec, absorbed=decode,
+            walk=None if walk is None else tuple(walk.items()))
+        self._arenas["c"][self._index] = pages
+        return out
+
+    def note(self, name: str, value, reduce: str = "sum") -> None:
+        """A count made inside the program, for the step's span: summed
+        (``reduce="max"``: the largest taken) over the layers that note it
+        and over a prompt's launches, and fetched with the step's token
+        ids."""
+        import jax.numpy as jnp
+
+        value = jnp.asarray(value, jnp.int32)
+        if name in self._notes:
+            prev = self._notes[name][0]
+            value = prev + value if reduce == "sum" \
+                else jnp.maximum(prev, value)
+        self._notes[name] = (value, reduce)
+
+    def keep(self, name: str, value) -> None:
+        """An array ``[R, s, ...]`` this layer leaves ON THE DEVICE beside
+        the program's logits, stacked over the layers that keep it
+        (:attr:`ServingEngine.last_decode_kept`, ``last_prefill_kept``):
+        fetched on request, by a tolerance harness; no step fetches it."""
+        self._kept.setdefault(name, []).append(value)
 
     def read_state(self, name: str):
         """The rows' slots of state array ``name``, ``[R, *shape]``: in
@@ -336,11 +384,14 @@ class ServingEngine:
     The model owns its block math; the engine owns pages, tables, state
     slots and the two things a layer may ask of it: attend over this
     layer's pages, read and write this row's state.  ``LlamaForCausalLM``
-    (attention layers only: every feature below) and
+    (attention layers only: every feature below),
     ``GraniteHybridForCausalLM`` (Mamba-2 state layers beside attention
     layers: what would need the state snapshotted, moved or rolled back —
     prefix cache, offload, speculation, TP / CP meshes, quantized pages,
-    disaggregated prefill — raises :class:`StateLayersUnsupported`) are
+    disaggregated prefill — raises :class:`StateLayersUnsupported`) and
+    ``DeepseekV3ForCausalLM`` (latent-attention layers: the prefix cache,
+    speculation and fp8 pages work; int8 pages, TP / CP meshes, offload
+    and disaggregated prefill raise :class:`LatentLayersUnsupported`) are
     served.  Greedy decoding — determinism is what makes eviction-replay
     byte-exact."""
 
@@ -366,26 +417,37 @@ class ServingEngine:
                 "ServingEngine serves causal LMs that describe their layers "
                 "to it (serve_layers / serve_begin / serve_layer / "
                 "serve_end, see models/serve_protocol.py: LlamaForCausalLM, "
-                "GraniteHybridForCausalLM); got " + type(model).__name__)
+                "GraniteHybridForCausalLM, DeepseekV3ForCausalLM); got "
+                + type(model).__name__)
         self.model = model
         # the model's layers as the engine sees them, and each layer's
         # index within its own family of arenas
         self._layers = list(model.serve_layers())
-        att = [sp for sp in self._layers if isinstance(sp, AttentionLayer)]
+        # the layers that keep pages: K/V rows or latent rows, one kind a
+        # model (one page arena shape serves them all)
+        paged = [sp for sp in self._layers
+                 if isinstance(sp, (AttentionLayer, LatentAttentionLayer))]
         stl = [sp for sp in self._layers if isinstance(sp, StateLayer)]
-        if len(att) + len(stl) != len(self._layers) or not att:
+        if len(paged) + len(stl) != len(self._layers) or not paged:
             raise TypeError(
-                "serve_layers() must name AttentionLayer / StateLayer "
-                "entries, at least one of them an AttentionLayer")
-        if len({(a.kv_heads, a.head_dim) for a in att}) != 1:
-            raise ValueError("every attention layer must keep K/V of one "
-                             "shape (kv_heads, head_dim): one page arena "
-                             "shape serves them all")
-        counts = {AttentionLayer: 0, StateLayer: 0}
+                "serve_layers() must name AttentionLayer / "
+                "LatentAttentionLayer / StateLayer entries, at least one of "
+                "them a layer that keeps pages")
+        if len({(a.kv_heads, a.head_dim) if isinstance(a, AttentionLayer)
+                else a for a in paged}) != 1:
+            raise ValueError("every layer that keeps pages must keep rows "
+                             "of one shape ((kv_heads, head_dim), or one "
+                             "latent layer's): one page arena shape serves "
+                             "them all")
+        self._latent: Optional[LatentAttentionLayer] = \
+            paged[0] if isinstance(paged[0], LatentAttentionLayer) else None
+        att = paged
+        counts = {StateLayer: 0, "paged": 0}
         self._family_index = []
         for sp in self._layers:
-            self._family_index.append(counts[type(sp)])
-            counts[type(sp)] += 1
+            family = StateLayer if isinstance(sp, StateLayer) else "paged"
+            self._family_index.append(counts[family])
+            counts[family] += 1
         self.max_batch = max_batch if max_batch is not None else \
             _env_int("PADDLE_TPU_SERVE_MAX_BATCH", 4)
         P = page_tokens if page_tokens is not None else default_page_tokens()
@@ -422,8 +484,11 @@ class ServingEngine:
                     if jnp.issubdtype(p._value.dtype, jnp.floating)),
                    jnp.float32)
         self._cdt = cdt
-        n_layers, kv_heads, head_dim = \
-            len(att), att[0].kv_heads, att[0].head_dim
+        n_layers = len(att)
+        # a latent row is one "head" as wide as the padded row
+        kv_heads, head_dim = (1, self._latent.row_width) \
+            if self._latent is not None \
+            else (att[0].kv_heads, att[0].head_dim)
         # fixed-size state per decode row, for the model's state layers
         self.state: Optional[RowStatePool] = \
             RowStatePool(self.max_batch, stl) if stl else None
@@ -441,6 +506,9 @@ class ServingEngine:
             self._refuse_with_state(
                 "tp > 1", "the state layers' heads are not sharded over a "
                 "model mesh yet")
+            self._refuse_with_latent(
+                "tp > 1", "every head reads the one latent row: the heads "
+                "and the experts need a mesh of their own (two axes)")
             from .disagg import decode_mesh, shard_llama_params
 
             h_att = att[0].heads
@@ -464,6 +532,9 @@ class ServingEngine:
             self._refuse_with_state(
                 "cp > 1", "the ring prefill carries no recurrent state "
                 "between its shards")
+            self._refuse_with_latent(
+                "cp > 1", "the ring prefill unrolls the Llama block and "
+                "rotates per-head K/V")
             from jax.sharding import Mesh as _Mesh
 
             if self.tp > 1:
@@ -498,7 +569,9 @@ class ServingEngine:
         # around every scatter and gather the compiler re-laid a 64-wide
         # arena out, a copy of the whole pool each way (PERF.md, PR 26).
         # Under the TP mesh the kv-head axis stays an axis: it is sharded.
-        self._flat_pages = head_dim % 128 != 0 and self.tp == 1
+        # A latent layer's page is [P, row_width]: one row a token.
+        self._flat_pages = self._latent is not None or \
+            (head_dim % 128 != 0 and self.tp == 1)
         self._page_shape = (P, kv_heads, head_dim)
         self._arena_shape = (N, P, kv_heads * head_dim) if self._flat_pages \
             else (N,) + self._page_shape
@@ -507,16 +580,19 @@ class ServingEngine:
             self._refuse_with_state(
                 f"kv_dtype={self.kv_dtype!r}", "quantized pages beside a "
                 "float32 recurrent state have no measured tolerance yet")
+        if self.kv_dtype == "int8":
+            self._refuse_with_latent(
+                "kv_dtype='int8'", "the scales are one a kv head, and a "
+                "latent row mixes a normalised latent with a rotated key")
         self._fp8_scale = default_fp8_scale() \
             if self.kv_dtype == "fp8" else None
         adt = (jnp.int8 if self.kv_dtype == "int8"
                else jnp.float8_e4m3fn if self.kv_dtype == "fp8" else cdt)
-        arenas = {
-            "k": [jnp.zeros(self._arena_shape, adt)
-                  for _ in range(n_layers)],
-            "v": [jnp.zeros(self._arena_shape, adt)
-                  for _ in range(n_layers)],
-        }
+        # what a page holds, by plane: K and V rows, or latent rows ("c")
+        arenas = {plane: [jnp.zeros(self._arena_shape, adt)
+                          for _ in range(n_layers)]
+                  for plane in (("c",) if self._latent is not None
+                                else ("k", "v"))}
         self._scale_bytes = 0
         if self.kv_dtype == "int8":
             sshape = (N, P, kv_heads)
@@ -537,18 +613,24 @@ class ServingEngine:
             rep = NamedSharding(self._mesh, PartitionSpec())
             arenas = {key: [_jax.device_put(a, rep) for a in arrs]
                       for key, arrs in arenas.items()}
-        self._arena_bytes = 2 * n_layers * int(np.prod(self._arena_shape)) \
-            * arenas["k"][0].dtype.itemsize
-        self._planes = tuple(arenas)    # what an attention layer's page
-        # holds: k, v and an int8 pool's scales
+        # what the donation gate holds the decode program to: the page
+        # planes as they are allocated, whatever kind of row they keep
+        self._arena_bytes = sum(
+            int(np.prod(a.shape)) * a.dtype.itemsize
+            for plane in ("k", "v", "c") for a in arenas.get(plane, ()))
+        self._planes = tuple(arenas)    # what a layer's page holds: k, v
+        # and an int8 pool's scales, or latent rows
         self._attend = _jax.jit(self._paged_attention,
                                 static_argnames=("walk", "scale"))
+        self._attend_latent = _jax.jit(
+            self._latent_attention,
+            static_argnames=("spec", "walk", "absorbed"))
         if self.state is not None:
             arenas.update(self.state.zeros())
         self._arenas = arenas
+        # a page is priced by its layer's kind, over every layer
         self.pool.set_page_bytes(
-            kv_page_bytes(P, kv_heads, head_dim, self.kv_dtype,
-                          n_layers=n_layers),
+            sum(layer_page_bytes(sp, P, self.kv_dtype) for sp in att),
             kv_scale_page_bytes(P, kv_heads, self.kv_dtype,
                                 n_layers=n_layers),
             self.kv_dtype)
@@ -593,6 +675,9 @@ class ServingEngine:
             self._refuse_with_state(
                 "offload", "a swapped-out request's recurrent state is not "
                 "spilled with its pages yet")
+            self._refuse_with_latent(
+                "offload", "the host frames are K/V frames [layers, P, kv, "
+                "d]; latent rows have no frame format yet")
         self._offload_lost: set = set()   # parked rids whose host frames
         # were LRU-dropped: recall is impossible, re-admission downgrades
         # them to the eviction-replay re-prefill path (the README failure
@@ -633,8 +718,17 @@ class ServingEngine:
         self._prefill_exec: Dict[int, object] = {}     # width -> program
         self._decode_compiles = 0
         self.lint_report = None
+        self._note_reduce: Dict[str, str] = {}   # what the model's layers
+        # note (``io.note``) and how it adds up, set when a program is traced
+        self._prefill_notes: list = []   # a prompt's launches' vectors
         self._decode_logits = None       # the latest step's logits
         # [R, S, V], left on the device (:attr:`last_decode_logits`)
+        self._decode_kept: Dict[str, object] = {}   # and what its layers
+        # kept there (:attr:`last_decode_kept`)
+        self._decode_noted = None        # what they noted: fetched with the
+        # step's token ids
+        self._prefill_kept: list = []    # the same of the latest prompt's
+        # launches (:attr:`last_prefill_kept`)
         self.steps_total = 0
         self.first_step_wall: Optional[float] = None   # WARMING until set:
         # a replica advertises warming=True on its lease until its first
@@ -655,6 +749,11 @@ class ServingEngine:
         refused by name, never run wrong."""
         if self.state is not None:
             raise StateLayersUnsupported(feature, why)
+
+    def _refuse_with_latent(self, feature: str, why: str) -> None:
+        """The same for pages of latent rows."""
+        if self._latent is not None:
+            raise LatentLayersUnsupported(feature, why)
 
     # -- public API --------------------------------------------------------
     def submit(self, prompt, max_new_tokens: int = 64,
@@ -759,6 +858,9 @@ class ServingEngine:
         self._refuse_with_state(
             "submit_prefilled", "the frames carry K/V pages and no "
             "recurrent state")
+        self._refuse_with_latent(
+            "submit_prefilled", "the frames are K/V frames; latent rows "
+            "have no frame format yet")
         frames = list(kv_frames)
         p = np.asarray(prompt, np.int32).reshape(-1)
         need = self.pool.pages_for(len(p))
@@ -901,6 +1003,24 @@ class ServingEngine:
         self.meter.decode_logits_fetched()
         return np.asarray(self._decode_logits)
 
+    @property
+    def last_decode_kept(self) -> Dict[str, np.ndarray]:
+        """Host copies of what the latest decode step's layers kept on the
+        device (``io.keep``): per name one array ``[layers that kept it, R,
+        S, ...]``.  Fetched on request, as :attr:`last_decode_logits` is."""
+        return {name: np.asarray(a) for name, a in self._decode_kept.items()}
+
+    @property
+    def last_prefill_kept(self) -> Dict[str, np.ndarray]:
+        """The same of the latest prompt's prefill: per name one array
+        ``[layers that kept it, tokens, ...]`` over the tokens of the pages
+        its launches ran, padding included (a prefix-cached page ran in no
+        launch)."""
+        launches = self._prefill_kept
+        return {name: np.concatenate([np.asarray(k[name])[:, 0]
+                                      for k in launches], axis=1)
+                for name in (launches[0] if launches else ())}
+
     def row_state(self, rid: int) -> Dict[str, np.ndarray]:
         """Host copy of a RUNNING request's fixed-size state: per name one
         array ``[state layers, *shape]``, as the last program left it (what
@@ -967,8 +1087,8 @@ class ServingEngine:
             with _span("serve.prefill", rid=r.rid, trace=r.trace_id or "",
                        prompt_tokens=len(r.prompt),
                        cached_tokens=r.cached_tokens) as sp:
-                chunks, launches = self._prefill(r)
-                sp.note(chunks=chunks, launches=launches)
+                chunks, launches, noted = self._prefill(r)
+                sp.note(chunks=chunks, launches=launches, **noted)
             did_work = True
             self._retire_if_done(r)
         if self._active:
@@ -1306,6 +1426,7 @@ class ServingEngine:
         n_chunks = -(-len(prompt) // P)
         widths = prefill_plan(n_chunks - c0, self._prefill_widths)
         logits, c = None, c0
+        self._prefill_notes, self._prefill_kept = [], []
         for w in widths:
             part = prompt[c * P:(c + w) * P]
             chunk = np.zeros((1, w * P), np.int32)
@@ -1320,8 +1441,10 @@ class ServingEngine:
 
     def _prefill(self, r: Request):
         """Fill ``r``'s pages and deliver its first token.  Returns the
-        pages run and the program launches that took (both 0 where the
-        pages were imported)."""
+        pages run, the program launches that took (both 0 where the pages
+        were imported) and what the launches' layers noted, for the
+        span."""
+        import jax
         import jax.numpy as jnp
 
         if r.kv_import is not None:
@@ -1330,7 +1453,7 @@ class ServingEngine:
                 kernel_fallback("serving_cp_prefill", "kv_import",
                                 rid=str(r.rid))
             self._import_kv(r)
-            return 0, 0
+            return 0, 0, {}
         _faults.fire("serve_prefill", f"rid{r.rid}")
         prompt = r.prompt
         n_chunks = -(-len(prompt) // self.page_tokens)
@@ -1349,7 +1472,9 @@ class ServingEngine:
                                                         r.row)
             self.meter.prefill_launched(launches)
         with _span("serve.prefill.to_host"):
-            logits = np.asarray(logits)
+            # the launches' notes come with the logits: one sync point
+            logits, noted = jax.device_get((logits, self._prefill_notes))
+            self._prefill_notes = []
         with _span("serve.prefill.sample"):
             tok = int(np.argmax(logits))
             r.generated.append(tok)
@@ -1367,7 +1492,10 @@ class ServingEngine:
                 r.drafter = self.spec.make_drafter()
                 r.drafter.begin([int(t) for t in r.prompt])
                 r.drafter.observe([tok])
-        return n_chunks - c0, launches
+        # a launch reads the prompt's pages so far, its own included
+        pages = 0 if self._latent is None else int((c0 + np.cumsum(
+            prefill_plan(n_chunks - c0, self._prefill_widths))).sum())
+        return n_chunks - c0, launches, self._step_facts(noted, pages)
 
     def _import_kv(self, r: Request) -> None:
         """Disaggregated admission (ISSUE 19 leg 2): instead of running
@@ -1432,6 +1560,9 @@ class ServingEngine:
         self._refuse_with_state(
             "prefill_export", "the exported frames carry K/V pages and no "
             "recurrent state")
+        self._refuse_with_latent(
+            "prefill_export", "the frames are K/V frames; latent rows have "
+            "no frame format yet")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -1483,6 +1614,7 @@ class ServingEngine:
         drafts leave stale cache slots AT OR PAST the next write position;
         the next step's scatter overwrites them before its gather (same
         program), and the causal mask hides anything beyond its window."""
+        import jax
         import jax.numpy as jnp
 
         with _span("serve.decode") as sp:
@@ -1512,12 +1644,40 @@ class ServingEngine:
                                           jnp.asarray(positions),
                                           jnp.asarray(tables),
                                           jnp.asarray(n_tok))
-            # the step's one sync point: the chosen ids, not the logits
+            # the step's one sync point: the chosen ids [R, S] int32 and
+            # what the layers noted, not the logits
             with _span("serve.decode.to_host") as to_host:
-                choice = np.asarray(choice)               # [R, S] int32
-                to_host.note(bytes=choice.nbytes)
+                choice, noted = jax.device_get((choice, self._decode_noted))
+                to_host.note(bytes=choice.nbytes
+                             + (0 if noted is None else noted.nbytes))
+            if self._latent is not None or noted is not None:
+                sp.note(**self._step_facts(
+                    [] if noted is None else [noted], int(live.sum()),
+                    int(np.where(n_tok > 0, positions + n_tok, 0).sum())))
             with _span("serve.decode.sample"):
                 self._decode_sample(stepped, choice, n_tok, drafts)
+
+    def _step_facts(self, noted, pages: int, tokens: int = 0) -> dict:
+        """A ``serve.decode`` / ``serve.prefill`` span's facts from what
+        the layers of its launches noted (``noted``: one vector a launch,
+        in ``_note_reduce``'s order), and for a model with latent layers
+        ``latent_pages``: the pages of latent rows its programs read, over
+        every layer (``pages`` a layer: the live pages a decode step walks,
+        the prompt's pages so far at each prefill launch), and
+        ``latent_tokens``: the cached tokens a decode step's queries see,
+        over every layer."""
+        facts = {}
+        if self._latent is not None:
+            layers = sum(isinstance(sp, LatentAttentionLayer)
+                         for sp in self._layers)
+            facts.update(latent_pages=pages * layers,
+                         latent_tokens=tokens * layers)
+        if len(noted):
+            noted = np.asarray(noted, np.int64).reshape(len(noted), -1)
+            for i, (name, how) in enumerate(self._note_reduce.items()):
+                col = noted[:, i]
+                facts[name] = int(col.max() if how == "max" else col.sum())
+        return facts
 
     def _decode_prep(self):
         """Host-side inputs of one decode step: ``(stepped rows, tokens,
@@ -1867,6 +2027,158 @@ class ServingEngine:
             new["ks"], new["vs"] = ksp, vsp
         return out, new
 
+    def _latent_attention(self, q_nope, q_rope, c_new, r_new, w_uk, w_uv,
+                          pages, tables, positions, n_tok, *, spec,
+                          absorbed: bool, walk=None):
+        """Scatter this step's latent rows into one latent layer's page
+        arena ``pages`` [N, P, row_width] and attend each row over its
+        pages (:meth:`_LayerIO.attend_latent` has the arguments).  What is
+        stored is ``[c_kv | k_rope | 0]`` a token and nothing per head.
+
+        ``absorbed`` (the decode program): ``W_UK`` goes into the query and
+        ``W_UV`` onto the output, so every head attends over the latent
+        rows as they lie — read in place by ``mla_paged_decode_attention``
+        with ``walk`` (:meth:`_latent_walk`'s items), else gathered by the
+        whole padded table.  Expanded (the prefill program: one row, pages
+        of queries): the row's live pages are gathered a block at a time
+        and expanded to per-head K/V inside the program (a third of the
+        absorbed form's operations a score), under an online softmax.  fp8
+        pages quantize on the
+        scatter and dequantize at the gather.  Scores and softmax are
+        float32; junk columns mask to exact zeros.  Called through
+        ``self._attend_latent``, a ``jit`` of it."""
+        import jax
+        import jax.numpy as jnp
+
+        R, s, h, _ = q_nope.shape
+        L, rd, W = spec.latent_dim, spec.rope_dim, spec.row_width
+        P, MP = self.page_tokens, tables.shape[1]
+        fp8 = self.kv_dtype == "fp8"
+        cdt = self._cdt
+
+        def padded(*parts):     # [..., L | rd | zeros] to the row's width
+            parts = [x.astype(cdt) for x in parts]
+            return jnp.concatenate(
+                parts + [jnp.zeros(parts[0].shape[:-1] + (W - L - rd,),
+                                   cdt)], axis=-1)
+
+        pos_js = positions[:, None] + jnp.arange(s)[None, :]      # [R, s]
+        valid = jnp.arange(s)[None, :] < n_tok[:, None]
+        page = jnp.take_along_axis(tables,
+                                   jnp.clip(pos_js // P, 0, MP - 1), axis=1)
+        page = jnp.where(valid, page, TRASH_PAGE)
+        slot = jnp.where(valid, pos_js % P, 0)
+        rows = padded(c_new, r_new)
+        pages = pages.at[page, slot].set(
+            quantize_kv_fp8(rows, self._fp8_scale) if fp8 else rows)
+
+        def latent_rows(ids):       # pages ``ids`` [R, n] as [R, n * P, W]
+            ctx = pages[ids].reshape(R, -1, W)
+            return dequantize_kv_fp8(ctx, self._fp8_scale).astype(cdt) \
+                if fp8 else ctx
+
+        low = jnp.finfo(jnp.float32).min
+        if absorbed:
+            q_abs = jnp.einsum("rshn,lhn->rshl", q_nope.astype(cdt), w_uk,
+                               preferred_element_type=jnp.float32)
+            q_cat = padded(q_abs, q_rope)
+            if walk is not None:
+                from ..ops.pallas.mla_paged_decode_attention import \
+                    mla_paged_decode_attention
+
+                o_lat = mla_paged_decode_attention(
+                    q_cat, pages, tables, positions, n_tok, latent=L,
+                    scale=spec.scale, **dict(walk))
+            else:
+                ctx = latent_rows(tables)       # the whole padded tables
+                scores = jnp.einsum("rshw,rcw->rhsc", q_cat, ctx,
+                                    preferred_element_type=jnp.float32)
+                col = jnp.arange(MP * P)[None, None, None, :]
+                probs = jax.nn.softmax(jnp.where(
+                    col <= pos_js[:, None, :, None], scores * spec.scale,
+                    low), axis=-1).astype(cdt)
+                o_lat = jnp.einsum("rhsc,rcl->rshl", probs, ctx[..., :L],
+                                   preferred_element_type=jnp.float32)
+            out = jnp.einsum("rshl,lhv->rshv", o_lat.astype(cdt), w_uv,
+                             preferred_element_type=jnp.float32)
+            return out.astype(q_nope.dtype), pages
+
+        # the live context, a few pages at a time (flash-style): what a
+        # launch costs follows the tokens the prompt has so far, not the
+        # table's width, and the float32 scores stay a block's size.  (A
+        # softmax over the whole padded table cost 19 ms a layer a page of
+        # queries here, 120 x this loop: PERF.md section 6, PR 30.)
+        B = 2 if MP % 2 == 0 else 1             # pages a block
+        n_blocks = jnp.minimum(
+            (jnp.max(pos_js) + B * P) // (B * P), MP // B)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(cdt)
+
+        def block(i, carry):
+            m, l, acc = carry       # [R, h, s, 1] x 2, [R, h, s, v]
+            ctx = latent_rows(
+                jax.lax.dynamic_slice_in_dim(tables, i * B, B, axis=1))
+            c_ctx = ctx[..., :L]
+            k = jnp.concatenate([
+                jnp.einsum("rcl,lhn->rchn", c_ctx, w_uk,
+                           preferred_element_type=jnp.float32).astype(cdt),
+                jnp.broadcast_to(ctx[:, :, None, L:L + rd],
+                                 (R, B * P, h, rd))], axis=-1)
+            v = jnp.einsum("rcl,lhv->rchv", c_ctx, w_uv,
+                           preferred_element_type=jnp.float32).astype(cdt)
+            scores = jnp.einsum("rbhd,rchd->rhbc", q, k,
+                                preferred_element_type=jnp.float32)
+            col = i * B * P + jnp.arange(B * P)[None, None, None, :]
+            scores = jnp.where(col <= pos_js[:, None, :, None],
+                               scores * spec.scale, low)
+            m_new = jnp.maximum(m, jnp.max(scores, -1, keepdims=True))
+            # a query that has seen nothing yet keeps exact zeros
+            m_ok = jnp.where(m_new == low, 0.0, m_new)
+            p = jnp.exp(scores - m_ok)
+            alpha = jnp.exp(m - m_ok)
+            pv = jnp.einsum("rhbc,rchv->rhbv", p.astype(cdt), v,
+                            preferred_element_type=jnp.float32)
+            return (m_new, l * alpha + jnp.sum(p, -1, keepdims=True),
+                    acc * alpha + pv)
+
+        _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
+            jnp.full((R, h, s, 1), low, jnp.float32),
+            jnp.zeros((R, h, s, 1), jnp.float32),
+            jnp.zeros((R, h, s, spec.v_dim), jnp.float32)))
+        out = jnp.moveaxis(acc / jnp.where(l > 0.0, l, 1.0), 1, 2)
+        return out.astype(q_nope.dtype), pages
+
+    def _latent_walk(self, rows: int, width: int,
+                     layer: LatentAttentionLayer):
+        """:meth:`_page_walk` for a latent layer: the keyword arguments of
+        ``mla_paged_decode_attention`` where a kernel can run and its gate
+        takes the shapes, else None (the gather + einsum) with a counted
+        ``kernel_fallback``."""
+        from ..ops import pallas_mode
+        from ..ops.pallas.mla_paged_decode_attention import (
+            KERNEL_NAME, mla_paged_decode_attention_refusal)
+
+        mode = pallas_mode("use_decode_attention")
+        if mode is None:
+            return None
+        kind, _, interpret = mode
+        if kind != "local":
+            reason = "hybrid_mesh"
+        elif self.kv_dtype != "bf16":
+            reason = "kv_dtype"
+        else:
+            reason = mla_paged_decode_attention_refusal(
+                (rows, width, layer.heads, layer.row_width),
+                self._arena_shape, (rows, self.max_pages_per_seq),
+                self._arenas["c"][0].dtype, layer.latent_dim,
+                interpret=interpret)
+        if reason is None:
+            return {"interpret": interpret}
+        from ..telemetry import kernel_fallback
+
+        kernel_fallback(KERNEL_NAME, reason, kv_dtype=self.kv_dtype,
+                        rows=rows, width=width)
+        return None
+
     def _forward(self, param_arrays, buffer_arrays, arenas, tokens,
                  positions, tables, n_tok, *, n_valid=None, row=None,
                  fresh=None):
@@ -1880,12 +2192,16 @@ class ServingEngine:
         ``n_tok``) — what a state layer may let into its state; ``row``
         (prefill): the decode row whose state slot the one prompt row uses,
         zeroed first where ``fresh``; None (decode): row r is slot r."""
+        import jax.numpy as jnp
+
         from ..autograd import no_grad
         from ..jit import _StateSwap
         from ..tensor.tensor import Tensor
 
         model = self.model
         new_arenas = {key: list(arrs) for key, arrs in arenas.items()}
+        notes: Dict[str, object] = {}
+        kept: Dict[str, list] = {}
         with _StateSwap(self._params, param_arrays), \
                 _StateSwap(self._buffers, buffer_arrays), no_grad():
             x, shared = model.serve_begin(Tensor(tokens), positions)
@@ -1893,22 +2209,31 @@ class ServingEngine:
                 io = _LayerIO(self, spec, new_arenas,
                               self._family_index[li], tables, positions,
                               n_tok, n_tok if n_valid is None else n_valid,
-                              row, fresh)
+                              row, fresh, notes, kept)
                 x = model.serve_layer(li, x, shared, io)
             logits = model.serve_end(x)
-            return logits._value, new_arenas
+            self._note_reduce = {name: notes[name][1]
+                                 for name in sorted(notes)}
+            # what the layers noted: one small vector, in that order
+            noted = jnp.stack([notes[name][0] for name in self._note_reduce]) \
+                if notes else None
+            return logits._value, new_arenas, noted, \
+                {name: jnp.stack(vals) for name, vals in kept.items()}
 
     def _decode_fn(self, param_arrays, buffer_arrays, arenas, tokens,
                    positions, tables, n_tok):
         """ONE compiled decode signature: ``tokens`` [R, S] where S is the
         fixed speculative width (1 + k_max; 1 when speculation is off) and
         ``n_tok`` carries each row's live width — adapting k never
-        recompiles.  Returns the greedy choice [R, S]
-        (:func:`greedy_choice`: all the host fetches of a step), the logits
-        [R, S, V] it was made from, and the arenas."""
-        logits, arenas = self._forward(param_arrays, buffer_arrays, arenas,
-                                       tokens, positions, tables, n_tok)
-        return greedy_choice(logits), logits, arenas
+        recompiles.  Returns what a step fetches — the greedy choice [R, S]
+        (:func:`greedy_choice`) and the vector of what the layers noted
+        (``io.note``; None where none did) — then what stays on the device
+        — the logits [R, S, V] the choice was made from and what the layers
+        kept (``io.keep``) — and the arenas."""
+        logits, arenas, noted, kept = self._forward(
+            param_arrays, buffer_arrays, arenas, tokens, positions, tables,
+            n_tok)
+        return (greedy_choice(logits), noted), (logits, kept), arenas
 
     def _page_walk(self, rows: int, width: int, layer: AttentionLayer):
         """How one attention layer of the decode program attends, decided at
@@ -1971,12 +2296,14 @@ class ServingEngine:
         # n_valid <= s, a whole number of pages
         keep = s if n_valid is None else -(-n_valid // P) * P
         n_tok = jnp.full((1,), keep, jnp.int32)
-        logits, arenas = self._forward(
+        logits, arenas, noted, kept = self._forward(
             param_arrays, buffer_arrays, arenas, tokens, positions, tables,
             n_tok, n_valid=None if n_valid is None else n_valid[None],
             row=jnp.int32(0) if row is None else row,
             fresh=chunk_start == 0)
-        return jnp.take(logits[0], take_idx, axis=0), arenas
+        # what the layers noted rides beside the logits row, and what they
+        # kept stays on the device, as in decode
+        return (jnp.take(logits[0], take_idx, axis=0), noted), kept, arenas
 
     def _param_arrays(self):
         with _SWAP_LOCK:
@@ -2029,7 +2356,9 @@ class ServingEngine:
                     state_bytes=self.state.nbytes if self.state else 0)
         # the previous step's logits are dropped here, never fetched unless
         # someone asked (:attr:`last_decode_logits`)
-        choice, self._decode_logits, self._arenas = self._decode_exec(*args)
+        (choice, self._decode_noted), \
+            (self._decode_logits, self._decode_kept), self._arenas = \
+            self._decode_exec(*args)
         return choice
 
     def _run_prefill(self, tokens, chunk_start, tables, take_idx, row,
@@ -2051,7 +2380,12 @@ class ServingEngine:
                     self._prefill_fn, args[:3] + (wide,) + args[4:],
                     PREFILL_PROGRAM)
         width = tokens.shape[1] // self.page_tokens
-        logits, self._arenas = self._prefill_exec[width](*args)
+        (logits, noted), kept, self._arenas = \
+            self._prefill_exec[width](*args)
+        if noted is not None:
+            self._prefill_notes.append(noted)   # fetched with the logits
+        if kept:
+            self._prefill_kept.append(kept)
         return logits
 
     # -- context-parallel prefill (ISSUE 20 leg 1) -------------------------

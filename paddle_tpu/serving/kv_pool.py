@@ -8,8 +8,11 @@ a page-count check and eviction frees pages without moving anyone else's
 data.
 
 This module is pure accounting (no arrays): the :class:`ServingEngine`
-owns the physical ``[num_pages, page_tokens, kv_heads, head_dim]`` arenas
-and indexes them with the tables handed out here.  Page 0 is RESERVED as
+owns the physical arenas — per attention layer a K and a V arena
+``[num_pages, page_tokens, kv_heads, head_dim]``, per latent-attention layer
+ONE arena ``[num_pages, page_tokens, row_width]`` of latent rows — and
+indexes them with the tables handed out here: one table a request serves
+every layer, whatever a page's row holds.  Page 0 is RESERVED as
 the trash page — inactive batch rows in the compiled decode program write
 their (ignored) k/v there, so a row going idle never needs a reshape or a
 recompile.
@@ -35,7 +38,8 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["PagedKVPool", "OffloadPool", "PoolExhausted",
-           "default_page_tokens", "default_offload_pages", "TRASH_PAGE"]
+           "LatentLayersUnsupported", "default_page_tokens",
+           "default_offload_pages", "TRASH_PAGE"]
 
 # int8 paging (ISSUE 13) keeps the accounting here and the arrays in the
 # engine, same split as the bf16 pool: kv_quant.py prices a page through
@@ -58,6 +62,18 @@ def default_offload_pages() -> int:
 class PoolExhausted(RuntimeError):
     """No free pages: the caller must evict a request (or reject the
     admission) before retrying."""
+
+
+class LatentLayersUnsupported(NotImplementedError):
+    """A serving feature that is not built for pages of latent rows
+    (:class:`~paddle_tpu.models.serve_protocol.LatentAttentionLayer`) was
+    asked for: it is refused by name, never run wrong."""
+
+    def __init__(self, feature: str, why: str):
+        self.feature = feature
+        super().__init__(
+            f"{feature} is not supported for a model with latent-attention "
+            f"layers: {why}")
 
 
 class PagedKVPool:

@@ -44,7 +44,7 @@ from typing import Optional
 __all__ = ["KV_DTYPES", "kv_cache_dtype", "quantize_kv", "dequantize_kv",
            "quantize_kv_fp8", "dequantize_kv_fp8", "default_fp8_scale",
            "observe_kv_absmax", "kv_page_bytes", "kv_scale_page_bytes",
-           "FP8_MAX"]
+           "layer_page_bytes", "FP8_MAX"]
 
 KV_DTYPES = ("bf16", "int8", "fp8")
 _QMAX = 127.0
@@ -153,6 +153,21 @@ def kv_page_bytes(page_tokens: int, kv_heads: int, head_dim: int,
 
     per = DTYPE_BYTES[_dtype_code(kv_dtype)]
     return 2 * n_layers * page_tokens * kv_heads * head_dim * per
+
+
+def layer_page_bytes(layer, page_tokens: int, kv_dtype: str) -> int:
+    """HBM bytes of ONE pool page in ONE layer, by the layer's kind
+    (:mod:`~paddle_tpu.models.serve_protocol`): K and V of every kv head
+    for an ``AttentionLayer``, one padded latent row a token for a
+    ``LatentAttentionLayer``.  Excludes scale buffers."""
+    from ..analysis.program import DTYPE_BYTES
+    from ..models.serve_protocol import LatentAttentionLayer
+
+    if isinstance(layer, LatentAttentionLayer):
+        return page_tokens * layer.row_width \
+            * DTYPE_BYTES[_dtype_code(kv_dtype)]
+    return kv_page_bytes(page_tokens, layer.kv_heads, layer.head_dim,
+                         kv_dtype)
 
 
 def kv_scale_page_bytes(page_tokens: int, kv_heads: int, kv_dtype: str,

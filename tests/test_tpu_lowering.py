@@ -50,7 +50,8 @@ def tpu_text(fn, *args, **jit_kw) -> str:
 def kernels_in(text: str) -> dict:
     names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rms_norm_fwd",
              "rms_norm_bwd", "fused_rope", "decode_attention",
-             "paged_decode_attention", "ssm_state_update")
+             "paged_decode_attention", "ssm_state_update",
+             "mla_paged_decode_attention", "moe_grouped_matmul")
     return {n: text.count(f'kernel_name = "{n}"') for n in names}
 
 
@@ -251,6 +252,58 @@ class TestKernelsLower:
         assert compiled.memory_analysis().alias_size_in_bytes >= arena
         assert not _copies_of(text, f"f32[{R},{H},{P},{N}]")
 
+    @pytest.mark.parametrize("rows,width", [(128, 1), (16, 3)],
+                             ids=["reasoning", "speculative"])
+    def test_mla_paged_decode_attention_compiles_for_a_v5e(
+            self, one_chip, rows, width):
+        """The absorbed-MLA page walk at DeepSeek-V3's widths (128 heads
+        over latent rows of 512 + 64 lanes padded to 640, 128 rows x 40
+        slots of 3000 pages), through Mosaic and XLA's TPU compiler for a
+        described chip: one kernel, the arena read where it lies.  A row
+        of 576 lanes is what the gate refuses: Mosaic cannot copy a slice
+        that is not whole lane registers."""
+        from paddle_tpu.ops.pallas.mla_paged_decode_attention import (
+            mla_paged_decode_attention, mla_paged_decode_attention_refusal)
+
+        q, arena, tables = (rows, width, 128, 640), (3000, 128, 640), \
+            (rows, 40)
+        assert mla_paged_decode_attention_refusal(
+            q, arena, tables, BF16, 512) is None
+        assert mla_paged_decode_attention_refusal(
+            (rows, width, 128, 576), (3000, 128, 576), tables, BF16,
+            512) == "latent_width"
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in ((q, BF16), (arena, BF16),
+                                     (tables, jnp.int32),
+                                     ((rows,), jnp.int32),
+                                     ((rows,), jnp.int32))]
+        text = _compile_uncached(jax.jit(
+            lambda *a: mla_paged_decode_attention(
+                *a, latent=512, scale=0.135)), *args).as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert not [line for line in text.splitlines()
+                    if "bf16[3000,128," in line and " copy(" in line], \
+            "the pool is copied"
+
+    @pytest.mark.parametrize("m,k,n", [(1024, 7168, 2048),
+                                       (1024, 2048, 7168),
+                                       (4096, 7168, 2048)],
+                             ids=["decode-up", "decode-down", "prefill-up"])
+    def test_moe_grouped_matmul_compiles_for_a_v5e(self, one_chip, m, k, n):
+        """The grouped expert matmul at DeepSeek-V3's expert widths, 16
+        experts held, the pairs of 128 decode rows and of a 512-token
+        prefill launch: one kernel, and no copy of the experts' weights."""
+        from paddle_tpu.ops.pallas.grouped_matmul import (
+            grouped_matmul, grouped_matmul_refusal)
+
+        assert grouped_matmul_refusal((m, k), (16, k, n), BF16) is None
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in (((m, k), BF16), ((16, k, n), BF16),
+                                     ((16,), jnp.int32))]
+        text = _compile_uncached(jax.jit(grouped_matmul), *args).as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert not _copies_of(text, f"bf16[16,{k},{n}]")
+
     def test_rms_norm_and_rope(self):
         x, w = sds(4, 2048, 2048), sds(2048, dtype=jnp.float32)
 
@@ -450,3 +503,76 @@ class TestStateLayerProgramsLower:
             donate_argnums=(2,))
         assert kernels_in(prefill)["ssm_state_update"] == 0
         assert kernels_in(prefill)["rms_norm_fwd"] >= 2
+
+
+@pytest.mark.usefixtures("on_tpu")
+class TestLatentLayerProgramsLower:
+    """The serving programs of a model with latent-attention layers
+    (DeepSeek-V3's MLA widths per head and its latent row, fewer heads and a
+    narrow hidden size; one dense and one expert layer), cross-lowered for
+    TPU: the absorbed page walk and the grouped expert matmul are in the
+    decode program, the prefill program expands and keeps the matmul."""
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        from paddle_tpu.models import (DeepseekV3Config,
+                                       DeepseekV3ForCausalLM)
+        from paddle_tpu.serving import ServingEngine
+
+        paddle.seed(0)
+        model = DeepseekV3ForCausalLM(DeepseekV3Config(
+            vocab_size=1024, hidden_size=512, intermediate_size=1024,
+            moe_intermediate_size=256, num_hidden_layers=2,
+            first_k_dense_replace=1, num_attention_heads=16,
+            num_key_value_heads=16, q_lora_rank=256, n_routed_experts=32,
+            experts_held=(0, 8), max_position_embeddings=4096))
+        model.eval()
+        model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+        return ServingEngine(model, max_batch=16, page_tokens=128,
+                             num_pages=513, max_pages_per_seq=4)
+
+    def test_both_programs_lower_with_their_kernels(self, engine):
+        eng = engine
+        pa, ba = eng._param_arrays()
+        R, MP, P = eng.max_batch, eng.max_pages_per_seq, eng.page_tokens
+        assert eng._arena_shape == (513, 128, 640)
+        tables = jnp.zeros((R, MP), jnp.int32)
+        decode = tpu_text(
+            eng._decode_fn, pa, ba, eng._arenas,
+            jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
+            tables, jnp.ones((R,), jnp.int32), donate_argnums=(2,))
+        # each lowered once, inside the function every layer calls
+        assert kernels_in(decode)["mla_paged_decode_attention"] == 1
+        assert decode.count("call @mla_paged_decode_attention") == 1
+        assert kernels_in(decode)["moe_grouped_matmul"] == 2   # two shapes
+        assert kernels_in(decode)["paged_decode_attention"] == 0
+        prefill = tpu_text(
+            eng._prefill_fn, pa, ba, eng._arenas,
+            jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
+            jnp.int32(P - 1), jnp.int32(3), jnp.int32(P - 7),
+            donate_argnums=(2,))
+        assert kernels_in(prefill)["mla_paged_decode_attention"] == 0
+        assert kernels_in(prefill)["moe_grouped_matmul"] == 2
+
+    def test_decode_updates_the_latent_arenas_in_place(self, engine,
+                                                       one_chip):
+        """Compiled for a described v5e: the latent arenas are aliased
+        (donated in, returned) and never copied."""
+        from paddle_tpu.jit import named_program
+        from paddle_tpu.serving.engine import DECODE_PROGRAM
+
+        eng = engine
+        pa, ba = eng._param_arrays()
+        R, MP = eng.max_batch, eng.max_pages_per_seq
+        args = (pa, ba, eng._arenas, jnp.zeros((R, 1), jnp.int32),
+                jnp.zeros((R,), jnp.int32), jnp.zeros((R, MP), jnp.int32),
+                jnp.ones((R,), jnp.int32))
+        args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), args)
+        compiled = _compile_uncached(
+            jax.jit(named_program(eng._decode_fn, DECODE_PROGRAM),
+                    donate_argnums=(2,)), *args)
+        assert eng._arena_bytes == 2 * 513 * 128 * 640 * 2
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            eng._arena_bytes
+        assert not _copies_of(compiled.as_text(), "bf16[513,128,640]")
