@@ -17,21 +17,31 @@ stays full across row boundaries.
 Shape contract:
 
 - q         [R, S, h, d]   — S query tokens a row (1; 1 + k speculative)
-- k, v      [N, P, kv, d]  — ONE layer's arenas, as ``kv_pool`` lays them
-  out, this step's tokens already scattered in (the scatter stays in XLA)
+- k, v      [N, P, kv, d]  — ONE layer's arenas, as the serving engine lays
+  them out, this step's tokens already scattered in (the scatter stays in
+  XLA); or ``[N, P, kv*d]``, a token's heads merged into one row, which is
+  how the engine keeps heads narrower than a lane register
 - tables    [R, MP] int32  — physical page of each logical page
 - positions [R] int32      — absolute position of the row's first query
 - n_tok     [R] int32      — valid queries of the row (0: idle row)
+- scale     static float   — multiplies the scores (None: ``d ** -0.5``)
 
 Query ``i`` of a row attends columns ``<= positions + i``.  Returns
 ``out [R, S, h, d]``; rows with ``n_tok == 0`` come back zero.
 
-A page is used through the ``[N, P*kv, d]`` view (rows ordered slot, kv
+A 4-D page is used through the ``[N, P*kv, d]`` view (rows ordered slot, kv
 head): Mosaic cannot block one head out of the second-minor ``kv`` axis, so
 as in :mod:`decode_attention` every query head is scored against every
 row of the page in one ``[S*h, d] x [d, P*kv]`` matmul and an additive
 group bias keeps the rows of the head's own kv group.  Scores, softmax and
 accumulation are f32; the cache dtype multiplies, as the einsum has it.
+
+A merged page ``[P, kv*d]`` is used as it lies, one row a token: splitting
+its heads apart outside the kernel re-lays the whole pool out.  Each query
+head is widened to ``kv*d`` lanes that are zero outside its own kv head's
+``d`` (the other heads' lanes add exact zeros to its score, so the group
+bias is all zeros), the same kernel body runs with one "kv head" of width
+``kv*d``, and the head's own ``d`` lanes are taken from the wide output.
 
 No VJP: decode runs under ``no_grad`` by construction.
 """
@@ -69,25 +79,35 @@ def paged_decode_attention_refusal(q_shape, arena_shape, tables_shape, dtype,
                                    ) -> Optional[str]:
     """None when the kernel takes the call, else the reason it does not
     (the caller's ``kernel_fallback`` reason)."""
-    if len(q_shape) != 4 or len(arena_shape) != 4 or len(tables_shape) != 2:
+    if len(q_shape) != 4 or len(arena_shape) not in (3, 4) or \
+            len(tables_shape) != 2:
         return "rank"
     R, s, h, d = q_shape
-    _, P, kv, dc = arena_shape
-    if d != dc or kv < 1 or h % kv != 0 or tables_shape[0] != R:
+    P, width = arena_shape[1], arena_shape[-1]
+    if len(arena_shape) == 3:       # a token's heads merged into one row
+        if width % d != 0:
+            return "shape"
+        kv, n = width // d, P
+    else:
+        kv, n = arena_shape[2], P * arena_shape[2]
+        if width != d:
+            return "shape"
+    if kv < 1 or h % kv != 0 or tables_shape[0] != R:
         return "shape"
-    # a page row is one lane register wide on the chip; the interpreter
-    # (CPU tests) takes any multiple of a sublane
-    if d > 256 or d % (_MIN_SUBLANES if interpret else _LANES) != 0:
+    # the kernel's page is n rows of ``width`` lanes: whole lane registers
+    # on the chip; the interpreter (CPU tests) takes any multiple of a
+    # sublane
+    if width % (_MIN_SUBLANES if interpret else _LANES) != 0:
         return "head_dim"
-    n = P * kv
     if n % _sublane_rows(dtype) != 0:
         return "page_rows"
     if R * tables_shape[1] > _MAX_WORK:
         return "table_size"
     hp = _query_rows(s, h)
     item = jnp.dtype(dtype).itemsize
-    vmem = (2 * N_BUF * n * d * item       # K and V pages in flight
-            + 2 * R * hp * d * item        # q and out, whole
+    vmem = (2 * N_BUF * n * width * item   # K and V pages in flight
+            + 2 * R * hp * width * item    # q and out, whole
+            + hp * width * 4               # the accumulator
             + 4 * hp * n * 4)              # bias, scores, probabilities
     if vmem > _VMEM_BUDGET:
         return "vmem"
@@ -192,13 +212,26 @@ def _paged_kernel(nw_ref, row_ref, j_ref, page_ref, pos_ref, live_ref,
 # jitted: a program calls this once a layer with the same shapes, and an
 # inner jit is traced and lowered to Mosaic once for all of them (16 plain
 # calls cost every process 0.9 s of set-up before its compile-cache lookup)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(q, k, v, tables, positions, n_tok, *,
+                           scale: Optional[float] = None,
                            interpret: bool = False):
     """Attend each row of ``q`` over its live pages of one layer's arenas
     (module docstring)."""
-    R, S, h, d = q.shape
-    N, P, kv, _ = k.shape
+    R, S, h, head_dim = q.shape
+    if scale is None:
+        scale = 1.0 / (head_dim ** 0.5)
+    # the kernel's page: P * kv rows of d lanes, kv of them a token
+    merged = k.ndim == 3
+    (N, P), kv, d = k.shape[:2], 1 if merged else k.shape[2], k.shape[-1]
+    if merged:
+        # one "kv head" as wide as the token's row; query head i is zero
+        # outside the lanes of its own kv head, i // group
+        group = h // (d // head_dim)
+        own = (np.arange(h) // group)[:, None] \
+            == np.arange(d // head_dim)[None, :]
+        q = jnp.where(own[:, :, None], q[:, :, :, None, :], 0) \
+            .reshape(R, S, h, d)
     g = h // kv
     hp = _query_rows(S, h)
     n = P * kv
@@ -226,8 +259,7 @@ def paged_decode_attention(q, k, v, tables, positions, n_tok, *,
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=1.0 / (d ** 0.5),
-                          page_tokens=P),
+        functools.partial(_paged_kernel, scale=scale, page_tokens=P),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(1,),
@@ -249,4 +281,10 @@ def paged_decode_attention(q, k, v, tables, positions, n_tok, *,
         interpret=interpret,
     )(n_work, row, j, page, positions, live, q3, bias, col, qoff,
       k.reshape(N, n, d), v.reshape(N, n, d))
-    return out[:, :S * h].reshape(R, S, h, d)
+    out = out[:, :S * h].reshape(R, S, h, d)
+    if merged:      # each head's own lanes of the wide output
+        out = jnp.concatenate(
+            [out[:, :, c * group:(c + 1) * group,
+                 c * head_dim:(c + 1) * head_dim]
+             for c in range(d // head_dim)], axis=2)
+    return out

@@ -568,7 +568,8 @@ class ServingEngine:
         # [P, kv_heads * head_dim]: the chip tiles the two minor axes, and
         # around every scatter and gather the compiler re-laid a 64-wide
         # arena out, a copy of the whole pool each way (PERF.md, PR 26).
-        # Under the TP mesh the kv-head axis stays an axis: it is sharded.
+        # The decode kernel reads a merged page as it lies.  Under the TP
+        # mesh the kv-head axis stays an axis: it is sharded.
         # A latent layer's page is [P, row_width]: one row a token.
         self._flat_pages = self._latent is not None or \
             (head_dim % 128 != 0 and self.tp == 1)
@@ -1918,11 +1919,12 @@ class ServingEngine:
         for the einsum below (prefill; decode where no kernel runs), or,
         with ``walk`` (:meth:`_page_walk`'s items: the decode program on a
         TPU), only the live ones, read in place by
-        ``paged_decode_attention``.  Called through ``self._attend``, a
+        ``paged_decode_attention`` from the arena as the engine keeps it,
+        heads merged or not.  Called through ``self._attend``, a
         ``jit`` of it: the layers of a program that share a shape share
         one trace and one lowering of this body.
-        ``scale`` multiplies the scores (None: ``1 / sqrt(d)``; the kernel
-        knows only that one).  ``n_tok`` [R] is the
+        ``scale`` multiplies the scores on either path (None:
+        ``1 / sqrt(d)``).  ``n_tok`` [R] is the
         per-row count of VALID tokens in the s-window (speculative verify
         rows carry 1 + k_r; idle rows 0) — invalid slots scatter to the
         trash page.  Mirrors ``generation.cached_attention``'s grouped
@@ -1972,10 +1974,8 @@ class ServingEngine:
             from ..ops.pallas.paged_decode_attention import \
                 paged_decode_attention
 
-            shape = (kp.shape[0], P, kv, d)     # the kernel's view of it
-            out = paged_decode_attention(q, kp.reshape(shape),
-                                         vp.reshape(shape), tables,
-                                         positions, n_tok, **dict(walk))
+            out = paged_decode_attention(q, kp, vp, tables, positions,
+                                         n_tok, scale=scale, **dict(walk))
             return out, {"k": kp, "v": vp}
         C = MP * P
         if quant:
@@ -2239,9 +2239,9 @@ class ServingEngine:
         """How one attention layer of the decode program attends, decided at
         trace time by what the engine can observe: the keyword arguments of
         ``paged_decode_attention`` where the Pallas dispatch is local (a
-        TPU, or the interpreter), the pages hold the compute dtype, the
-        kernel's gate takes the shape and the layer scales its scores as
-        the kernel does; else None, the gather + einsum — with a
+        TPU, or the interpreter), the pages hold the compute dtype and the
+        kernel's gate takes the arena's shape (4-D, or a token's heads
+        merged); else None, the gather + einsum — with a
         ``kernel_fallback`` event wherever a kernel could have run.  Many
         short rows against the prefill's one row of one to a few pages of
         queries: the two programs share the mask rule and nothing else, so
@@ -2255,8 +2255,6 @@ class ServingEngine:
             return None
         kind, _, interpret = mode
         pages = self._arenas["k"][0]
-        page_shape = (self.num_pages, self.page_tokens, layer.kv_heads,
-                      layer.head_dim)
         if kind != "local":
             reason = "hybrid_mesh"
         elif self.kv_dtype != "bf16":
@@ -2264,11 +2262,8 @@ class ServingEngine:
         else:
             reason = paged_decode_attention_refusal(
                 (rows, width, layer.heads, layer.head_dim),
-                page_shape, (rows, self.max_pages_per_seq), pages.dtype,
+                pages.shape, (rows, self.max_pages_per_seq), pages.dtype,
                 interpret=interpret)
-            if reason is None and layer.scale is not None and \
-                    layer.scale != layer.head_dim ** -0.5:
-                reason = "scale"
         if reason is None:
             return {"interpret": interpret}
         from ..telemetry import kernel_fallback

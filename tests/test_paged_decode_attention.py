@@ -16,7 +16,8 @@ import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.telemetry as telemetry
-from paddle_tpu.models import LlamaForCausalLM, llama_tiny
+from paddle_tpu.models import (GraniteHybridForCausalLM, LlamaForCausalLM,
+                               granite_hybrid_tiny, llama_tiny)
 from paddle_tpu.ops.pallas import (paged_decode_attention,
                                    paged_decode_attention_refusal)
 from paddle_tpu.ops.pallas.paged_decode_attention import KERNEL_NAME
@@ -24,10 +25,10 @@ from paddle_tpu.serving import ServingEngine, TRASH_PAGE
 
 pytestmark = pytest.mark.serving
 
-P, MP, N = 8, 5, 40           # page tokens, table slots a row, pool pages
+P, MP, N = 16, 5, 40          # page tokens, table slots a row, pool pages
 
 
-def einsum_attention(q, k, v, tables, positions):
+def einsum_attention(q, k, v, tables, positions, scale=None):
     """``_paged_attention`` after its scatter: gather the whole padded
     table, dense scores, causal mask, softmax."""
     R, s, h, d = q.shape
@@ -37,8 +38,9 @@ def einsum_attention(q, k, v, tables, positions):
     vv = v[tables].reshape(R, C, kv, d)
     q5 = q.reshape(R, s, kv, h // kv, d).astype(kk.dtype)
     scores = jnp.einsum("bskgd,bckd->bkgsc", q5, kk,
-                        preferred_element_type=jnp.float32) \
-        / jnp.sqrt(float(d))
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(float(d)) if scale is None \
+        else scores * float(scale)
     pos_js = positions[:, None] + jnp.arange(s)[None, :]
     scores = jnp.where(jnp.arange(C)[None, None, None, None, :]
                        <= pos_js[:, None, None, :, None], scores,
@@ -89,24 +91,35 @@ def _case(rows, width, h, kv, d, dtype, seed=0):
             jnp.asarray(tables), jnp.asarray(positions), jnp.asarray(n_tok))
 
 
+# (heads, kv heads, head width, the arena keeps a token's heads merged,
+# score scale): the merged cases are 64-wide heads on a flat arena, as the
+# engine keeps granite's, one of them under a scale that is not d ** -0.5
+HEADS = {"gqa32_8": (32, 8, 16, False, None), "mha": (4, 4, 16, False, None),
+         "merged8_2": (8, 2, 64, True, None),
+         "merged32_8_scaled": (32, 8, 64, True, 1 / 64)}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("heads", [(32, 8), (4, 4)], ids=["gqa32_8", "mha"])
+@pytest.mark.parametrize("heads", list(HEADS))
 @pytest.mark.parametrize("name,width", [(n, 1) for n in ROWS]
                          + [(n, 3) for n in SPEC_ROWS])
 def test_kernel_matches_einsum(name, width, heads, dtype):
-    h, kv = heads
+    h, kv, d, merged, scale = HEADS[heads]
     rows = (ROWS | SPEC_ROWS)[name]
-    q, k, v, tables, positions, n_tok = _case(rows, width, h, kv, 16, dtype)
+    q, k, v, tables, positions, n_tok = _case(rows, width, h, kv, d, dtype)
+    # what the kernel is handed: the arena as the engine keeps it
+    flat = (lambda a: a.reshape(N, P, kv * d)) if merged else (lambda a: a)
     assert paged_decode_attention_refusal(
-        q.shape, k.shape, tables.shape, dtype, interpret=True) is None
+        q.shape, flat(k).shape, tables.shape, dtype, interpret=True) is None
     got = np.asarray(paged_decode_attention(
-        q, k, v, tables, positions, n_tok, interpret=True), np.float32)
+        q, flat(k), flat(v), tables, positions, n_tok, scale=scale,
+        interpret=True), np.float32)
     # the einsum sees every slot of the padded table: give it a pool with
     # the poison cleared (masked columns must be finite there)
     clear = lambda a: jnp.nan_to_num(a, nan=0.0)
     want = np.asarray(einsum_attention(q, clear(k), clear(v), tables,
-                                       positions), np.float32)
+                                       positions, scale), np.float32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-6
     assert np.all(np.isfinite(got)), "a page outside a row's live ones was read"
     for r, (_, n) in enumerate(rows):
@@ -124,14 +137,29 @@ def test_kernel_matches_einsum(name, width, heads, dtype):
     (dict(kv=1, dtype=jnp.bfloat16, page=8), "page_rows"),
     (dict(rows=512, slots=64), "table_size"),
     (dict(rows=256, width=8, h=32, kv=8, d=128, page=128), "vmem"),
+    # 64-wide heads: a token's merged heads are whole lane registers, the
+    # 4-D arena's rows are half of one
+    (dict(h=32, kv=8, d=64, page=128, flat=True, interpret=False), None),
+    (dict(h=32, kv=8, d=64, page=128, interpret=False), "head_dim"),
+    (dict(h=6, kv=3, d=64, page=128, flat=True, interpret=False),
+     "head_dim"),
+    (dict(h=6, kv=4, d=64, flat=True), "shape"),
+    # q and out are whole in VMEM at the merged width: a speculative width
+    # of 3 at granite's cell no longer fits
+    (dict(rows=64, width=1, h=32, kv=8, d=64, page=128, flat=True,
+          dtype=jnp.bfloat16, interpret=False), None),
+    (dict(rows=64, width=3, h=32, kv=8, d=64, page=128, flat=True,
+          dtype=jnp.bfloat16, interpret=False), "vmem"),
 ])
 def test_gate(case, reason):
     c = dict(rows=4, width=1, h=8, kv=4, d=16, page=8, slots=MP,
-             dtype=jnp.float32, interpret=True) | case
+             dtype=jnp.float32, interpret=True, flat=False) | case
+    arena = (N, c["page"], c["kv"] * c["d"]) if c["flat"] \
+        else (N, c["page"], c["kv"], c["d"])
     assert paged_decode_attention_refusal(
-        (c["rows"], c["width"], c["h"], c["d"]),
-        (N, c["page"], c["kv"], c["d"]), (c["rows"], c["slots"]),
-        c["dtype"], interpret=c["interpret"]) == reason
+        (c["rows"], c["width"], c["h"], c["d"]), arena,
+        (c["rows"], c["slots"]), c["dtype"],
+        interpret=c["interpret"]) == reason
 
 
 # -- the engine -------------------------------------------------------------
@@ -195,6 +223,51 @@ def test_engine_tokens_match_einsum_engine(model, interpreted):
     prefill = (pa, ba, eng._arenas, jnp.zeros((1, 8), jnp.int32),
                jnp.int32(0), jnp.zeros((1, 6), jnp.int32), jnp.int32(7))
     assert KERNEL_NAME not in str(jax.make_jaxpr(eng._prefill_fn)(*prefill))
+
+
+def test_merged_pages_under_the_layers_own_scale_walk(interpreted):
+    """A model whose heads are narrower than a lane register (the engine
+    keeps a token's heads merged) and whose attention layers scale their
+    scores by ``attention_multiplier``: decode walks the pages where they
+    lie and emits the einsum engine's tokens."""
+    paddle.seed(7)
+    # weights wide enough that attention moves the logits
+    hybrid = GraniteHybridForCausalLM(
+        granite_hybrid_tiny(initializer_range=0.1))
+    hybrid.eval()
+    (spec,) = [la for la in hybrid.serve_layers() if hasattr(la, "heads")]
+    assert spec.scale != spec.head_dim ** -0.5
+    eng, got = _serve(hybrid)
+    assert eng._flat_pages and eng._arenas["k"][0].ndim == 3
+    paddle.set_flags({"pallas_interpret": False})
+    eng_plain, plain = _serve(hybrid)
+    paddle.set_flags({"pallas_interpret": True})
+    assert got == plain
+    assert eng._decode_compiles == eng_plain._decode_compiles == 1
+    assert not _fallbacks()
+    pa, ba = eng._param_arrays()
+    args = (pa, ba, eng._arenas, jnp.zeros((3, 1), jnp.int32),
+            jnp.zeros((3,), jnp.int32), jnp.zeros((3, 6), jnp.int32),
+            jnp.ones((3,), jnp.int32))
+    assert KERNEL_NAME in str(jax.make_jaxpr(eng._decode_fn)(*args))
+
+    # tokens forgive a wrong scale; the logits do not (5e-3 apart under
+    # ``d ** -0.5``, 1e-7 under the layer's own)
+    def live_logits():
+        eng = ServingEngine(hybrid, max_batch=3, page_tokens=8, num_pages=24,
+                            max_pages_per_seq=6)
+        rng = np.random.default_rng(2)
+        for n in (9, 16):
+            eng.submit(rng.integers(1, 96, n).astype(np.int32),
+                       max_new_tokens=12)
+        for _ in range(6):
+            eng.step()
+        assert len(eng._active) == 2
+        return eng.last_decode_logits[:2]
+
+    walked = live_logits()
+    paddle.set_flags({"pallas_interpret": False})
+    np.testing.assert_allclose(walked, live_logits(), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])

@@ -20,6 +20,8 @@ What Mosaic then makes of a kernel that lowers (VMEM, layouts) only the chip
 can say: ``python chip_smoke.py`` through the chip tool.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,20 +83,42 @@ def _copies_of(text: str, shape: str):
 
 
 # the page-walking decode kernel at the benchmark's serving cells (Mistral:
-# GQA 32/8, 64 rows x 20 slots of 900 pages; 16 x 33 of 545), at
-# chip_smoke.py's serve phase (MHA 16/16) and at a speculative width
+# GQA 32/8 heads of 128, 64 rows x 20 slots of 900 pages; 16 x 33 of 545;
+# Granite-4.0-H: 32/8 heads of 64 on the flat arena [900, 128, 512], scores
+# scaled by 1/64), at chip_smoke.py's serve phase (MHA 16/16) and at a
+# speculative width
 PAGED_SHAPES = [(64, 1, (32, 8), 20, 900), (16, 1, (32, 8), 33, 545),
                 (8, 1, (16, 16), 16, 129), (64, 3, (32, 8), 20, 900)]
 PAGED_IDS = ["chat", "docqa", "smoke", "speculative"]
+MERGED_SHAPE = (64, 1, (32, 8), 20, 900)
 
 
-def paged_args(rows, width, heads, slots, pages):
+def paged_args(rows, width, heads, slots, pages, head_dim=128):
+    """A 128-wide head's arena is 4-D; a narrower head's keeps a token's
+    heads merged, as the engine lays it out."""
     h, kv = heads
-    q, arena = sds(rows, width, h, 128), sds(pages, 128, kv, 128)
+    q = sds(rows, width, h, head_dim)
+    arena = sds(pages, 128, kv, head_dim) if head_dim % 128 == 0 \
+        else sds(pages, 128, kv * head_dim)
     assert paged_decode_attention_refusal(
         q.shape, arena.shape, (rows, slots), BF16) is None
     return (q, arena, arena, sds(rows, slots, dtype=jnp.int32),
             sds(rows, dtype=jnp.int32), sds(rows, dtype=jnp.int32))
+
+
+def _mosaic_calls(text: str, kernel: str) -> int:
+    """Mosaic calls of a compiled program that are the kernel ``kernel``."""
+    return sum(line.lstrip().startswith(f"%{kernel}")
+               and 'custom_call_target="tpu_custom_call"' in line
+               for line in text.splitlines())
+
+
+def _moves_of(text: str, shape: str):
+    """Lines of a compiled program that copy or re-lay out an array whose
+    shape starts with ``shape``."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if shape in line and any(f" {op}(" in line for op in
+                                     ("copy", "reshape", "transpose"))]
 
 
 @pytest.fixture(scope="module")
@@ -205,27 +229,43 @@ class TestKernelsLower:
     @pytest.mark.parametrize("rows,width,heads,slots,pages", PAGED_SHAPES,
                              ids=PAGED_IDS)
     def test_paged_decode_attention(self, rows, width, heads, slots, pages):
-        text = tpu_text(paged_decode_attention,
-                        *paged_args(rows, width, heads, slots, pages))
+        args = paged_args(rows, width, heads, slots, pages)
+        # the default scale left out, given, and another (traced from one
+        # line: a Mosaic module carries its source locations)
+        text, given, other = [
+            tpu_text(lambda *a: paged_decode_attention(*a, scale=scale),
+                     *args) for scale in (None, 128 ** -0.5, 1 / 64)]
+        assert kernels_in(text)["paged_decode_attention"] == 1
+        assert given == text, "the default scale is no longer d ** -0.5"
+        assert other != text
+
+    def test_paged_decode_attention_on_merged_heads(self):
+        text = tpu_text(
+            functools.partial(paged_decode_attention, scale=1 / 64),
+            *paged_args(*MERGED_SHAPE, head_dim=64))
         assert kernels_in(text)["paged_decode_attention"] == 1
 
-    @pytest.mark.parametrize("rows,width,heads,slots,pages", PAGED_SHAPES,
-                             ids=PAGED_IDS)
+    @pytest.mark.parametrize(
+        "rows,width,heads,slots,pages,head_dim,scale",
+        [shape + (128, None) for shape in PAGED_SHAPES]
+        + [MERGED_SHAPE + (64, 1 / 64)], ids=PAGED_IDS + ["merged"])
     def test_paged_decode_attention_compiles_for_a_v5e(
-            self, one_chip, rows, width, heads, slots, pages):
+            self, one_chip, rows, width, heads, slots, pages, head_dim,
+            scale):
         """The whole way through Mosaic and XLA's TPU compiler, for a chip
         that is described and not attached: VMEM, DMA and layouts, which
         the cross-lowering above does not see.  The arenas' ``[N, P*kv,
-        d]`` view must stay a bitcast: a copy of the pool a layer would
-        cost more than the gather the kernel replaced."""
+        d]`` view must stay a bitcast, and a merged arena must be read as
+        it lies: a copy of the pool a layer would cost more than the
+        gather the kernel replaced."""
         args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-                for a in paged_args(rows, width, heads, slots, pages)]
-        text = _compile_uncached(jax.jit(paged_decode_attention),
-                                 *args).as_text()
+                for a in paged_args(rows, width, heads, slots, pages,
+                                    head_dim)]
+        text = _compile_uncached(
+            jax.jit(functools.partial(paged_decode_attention, scale=scale)),
+            *args).as_text()
         assert text.count('custom_call_target="tpu_custom_call"') == 1
-        pool = f"bf16[{pages},128,"
-        assert not [line for line in text.splitlines()
-                    if pool in line and " copy(" in line], "the pool is copied"
+        assert not _moves_of(text, f"bf16[{pages},"), "the pool is moved"
 
     def test_ssm_state_update_compiles_for_a_v5e(self, one_chip):
         """The decode state update at granite-4.0-h-micro's shapes (64 rows
@@ -430,7 +470,8 @@ class TestProgramsLower:
 class TestStateLayerProgramsLower:
     """The serving decode program of a model with state layers (Granite-4.0-H
     widths, one Mamba-2 / attention / Mamba-2 stretch, a small vocabulary),
-    compiled for a described v5e."""
+    compiled for a described v5e: the state update and the page walk over
+    merged 64-wide heads are its kernels."""
 
     @pytest.fixture(scope="class")
     def engine(self):
@@ -465,8 +506,7 @@ class TestStateLayerProgramsLower:
                 jnp.ones((R,), jnp.int32))
         args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), args)
-        before = telemetry.counters().get(
-            "kernel_fallback.paged_decode_attention.head_dim", 0)
+        before = telemetry.counters().get("kernel_fallback.total", 0)
         compiled = _compile_uncached(
             jax.jit(named_program(eng._decode_fn, DECODE_PROGRAM),
                     donate_argnums=(2,)), *args)
@@ -476,11 +516,14 @@ class TestStateLayerProgramsLower:
         assert compiled.memory_analysis().alias_size_in_bytes >= \
             eng._arena_bytes + eng.state.nbytes
         assert not _copies_of(text, "f32[64,64,64,128]")
-        # the state update is a Mosaic call a state layer; the 64-wide
-        # heads gather, counted once an attention layer
-        assert text.count("ssm_state_update") >= 2
-        assert telemetry.counters()[
-            "kernel_fallback.paged_decode_attention.head_dim"] == before + 1
+        # the state update is a Mosaic call a state layer, the page walk
+        # one an attention layer: the 64-wide heads are read from the flat
+        # arena where it lies, and nothing falls back
+        assert _mosaic_calls(text, "ssm_state_update") == 2
+        assert _mosaic_calls(text, "paged_decode_attention") == 1
+        assert eng._arena_shape == (65, 128, 512)
+        assert not _moves_of(text, "bf16[65,128,512]")
+        assert telemetry.counters().get("kernel_fallback.total", 0) == before
 
     def test_both_programs_lower_with_the_kernel_in_decode_only(self, engine):
         eng = engine
@@ -495,7 +538,9 @@ class TestStateLayerProgramsLower:
         assert kernels_in(decode)["ssm_state_update"] == 1
         assert decode.count("call @ssm_state_update") == 1
         assert decode.count("call @_mamba_mix") == 2
-        assert kernels_in(decode)["paged_decode_attention"] == 0
+        # and the page walk once, in the one attention layer
+        assert kernels_in(decode)["paged_decode_attention"] == 1
+        assert decode.count("call @paged_decode_attention") == 1
         prefill = tpu_text(
             eng._prefill_fn, pa, ba, eng._arenas,
             jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
