@@ -124,8 +124,8 @@ class System:
             return out
 
         check = self.config["check"]
-        tol = check["logit_rms_tol"]
-        verdict = serving.compare_with_reference(sample, reference, tol)
+        verdict = serving.compare_with_reference(
+            sample, reference, check["logit_rms_tol"])
         # the recurrent state itself.  rms(got - ref) / rms(ref) of every
         # check prompt's [H, P, N] state, first and last state layer, is
         # for the record: past the first layers the bfloat16 activations'
@@ -147,13 +147,10 @@ class System:
         verdict["ref_logits_rms"] = float(np.mean(rms))
         verdict["near_tie_limit"] = checks.NEAR_TIE \
             * min(verdict["ref_logits_rms"] / NEAR_TIE_AT_RMS, 1.0)
-        verdict["ok"] = bool(
-            verdict["logits_rms_rel_err_median"] <= tol
-            and verdict["logits_rms_rel_err_worst"] <= 2 * tol
-            and verdict["short_of_best"] <= verdict["near_tie_limit"]
-            and verdict["state_head_rms_rel_err_worst"]
-            <= check["state_head_rms_tol"])
-        return verdict
+        verdict["limits"].update(
+            short_of_best=verdict["near_tie_limit"],
+            state_head_rms_rel_err_worst=check["state_head_rms_tol"])
+        return checks.decide(verdict)
 
 
 def build(config: dict, traffic: dict, seed: int, devices) -> System:

@@ -40,12 +40,22 @@ def row_errors(got, ref) -> np.ndarray:
     return num / np.maximum(den, 1e-30)
 
 
+def decide(verdict: dict) -> dict:
+    """``ok`` is every compared number within its limit.  ``limits`` names
+    each number a verdict compares beside its limit; the run's line and its
+    last lines on standard error print them (``run.compared``)."""
+    verdict["ok"] = all(bool(verdict[name] <= limit)
+                        for name, limit in verdict["limits"].items())
+    return verdict
+
+
 def logits_agree(errors, tol: float) -> dict:
     errors = np.asarray(errors, np.float64)
-    median, worst = float(np.median(errors)), float(np.max(errors))
-    return {"logits_rms_rel_err_median": median,
-            "logits_rms_rel_err_worst": worst, "rows": int(errors.size),
-            "ok": bool(median <= tol and worst <= 2 * tol)}
+    return decide({"logits_rms_rel_err_median": float(np.median(errors)),
+                   "logits_rms_rel_err_worst": float(np.max(errors)),
+                   "rows": int(errors.size),
+                   "limits": {"logits_rms_rel_err_median": tol,
+                              "logits_rms_rel_err_worst": 2 * tol}})
 
 
 def short_of_best(ref_logits, chosen) -> float:

@@ -32,6 +32,27 @@ def median(values: Sequence[float]) -> Optional[float]:
     return percentile(values, 50.0)
 
 
+def tail_mean(values: Sequence[float], lo: float = 90.0,
+              hi: float = 99.0) -> Optional[float]:
+    """The mean over the ranks ``lo``..``hi`` (percent) of the sorted
+    sample: value ``i`` of ``n`` owns the rank interval [i/n, (i+1)/n) and
+    counts by the part of it that lies inside [lo, hi).  Where a sample is a
+    mixture of two populations, a percentile steps by the whole distance
+    between them as the upper one's weight crosses it; this mean moves by
+    that distance times the change of weight over the width of the band, so
+    it is continuous in the weight and in ``n``.  ``None`` for an empty
+    sample."""
+    xs = sorted(float(v) for v in values)
+    if not xs:
+        return None
+    if not 0.0 <= lo < hi <= 100.0:
+        raise ValueError(f"ranks {lo}..{hi} outside 0..100 or empty")
+    a, b = len(xs) * lo / 100.0, len(xs) * hi / 100.0
+    total = sum(xs[i] * (min(i + 1, b) - max(i, a))
+                for i in range(math.floor(a), min(math.ceil(b), len(xs))))
+    return total / (b - a)
+
+
 def samples_beyond(n: int, q: float) -> int:
     """How many of ``n`` samples lie beyond the q-th percentile: a tail is
     worth reporting where this is ten or more."""

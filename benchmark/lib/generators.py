@@ -6,8 +6,9 @@ drawn from its own ``population_seed``.  ``--seed`` fills in the token values
 at the same times with other contents: runs with different seeds do the same
 work, and a difference between them is noise, not traffic.  (The first sets
 of PR 23 permuted the schedule by the seed: which long prompts met moved
-``itl_p95_ms`` by +-5 % between seeds, where two runs of one seed agreed to
-2 %.  The order is part of the work.)
+the gap tail (``itl_p95_ms``, judged then; ``itl_tail_mean_ms`` is since
+PR 33) by +-5 % between seeds, where two runs of one seed agreed to 2 %.
+The order is part of the work.)
 
 Length distributions (``dist``): ``lognormal`` (``median``, ``sigma``),
 ``uniform``, ``fixed`` (``value``); all clipped to ``min``..``max``.

@@ -8,9 +8,11 @@ first traced runs of PR 23): one plane per chip named ``/device:TPU:<n>``
 whose line ``XLA Ops`` holds one event per executed HLO operation, named by
 the whole instruction (``%jvp_flash_fwd_.12 = (bf16[...]) custom-call(...)``:
 a Pallas kernel carries its ``name`` there), and whose line ``XLA Modules``
-holds one event per execution of a compiled program (``jit__decode_fn(<id>)``;
-``TrainStep``'s is ``jit__unknown``).  Lines ``Steps`` and ``Async XLA Ops``
-(copies in flight) are not read.  The host's threads are lines of the plane
+holds one event per execution of a compiled program
+(``jit_serve_decode_fn(<id>)``, ``jit_serve_prefill_fn``,
+``jit_train_step_guarded``: the program names its own since PR 24; at PR 23
+they read ``jit__decode_fn`` and ``jit__unknown``).  Lines ``Steps`` and
+``Async XLA Ops`` (copies in flight) are not read.  The host's threads are lines of the plane
 ``/host:CPU``, where ``jax.profiler.TraceAnnotation`` spans appear under their
 own names.  All times are nanoseconds from the start of the profile, on one
 clock for host and device.

@@ -35,7 +35,7 @@ def reference_check(model, config: dict, reference, weights: dict, rng,
     verdict = checks.logits_agree(
         checks.row_errors(np.asarray(logits.value[0], np.float32), ref),
         config["check"]["logit_rms_tol"])
-    verdict.update(loss=float(loss), reference_loss=ref_loss)
-    verdict["ok"] = verdict["ok"] and \
-        abs(float(loss) - ref_loss) <= checks.LOSS_TOL
-    return verdict
+    verdict.update(loss=float(loss), reference_loss=ref_loss,
+                   loss_abs_err=abs(float(loss) - ref_loss))
+    verdict["limits"]["loss_abs_err"] = checks.LOSS_TOL
+    return checks.decide(verdict)

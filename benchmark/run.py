@@ -172,6 +172,13 @@ def _plain(x):
     return x if isinstance(x, (int, float, str, bool, type(None))) else str(x)
 
 
+def compared(result: dict) -> dict:
+    """Each number the run's verdict compared, beside its limit."""
+    check = result["facts"].get("check", {})
+    return {name: {"value": check.get(name), "limit": limit}
+            for name, limit in check.get("limits", {}).items()}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="benchmark.run", description=__doc__,
                                  formatter_class=argparse.
@@ -182,8 +189,16 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
     a = ap.parse_args(argv)
     result = execute(a.workload, a.seed, a.seconds, bool(a.trace))
+    # the line's last key and the last lines on standard error: what a
+    # record of a run that was not correct keeps
+    result["compared"] = compared(result)
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr)
+    print(f"correct {result['correct']} failed {result['failed']} of "
+          f"{result['attempted']}", file=sys.stderr, flush=True)
     return 0
 
 

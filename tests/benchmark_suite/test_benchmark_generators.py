@@ -100,3 +100,81 @@ def test_percentile_edges():
     assert clock.samples_beyond(100, 90) == 10
     assert clock.samples_beyond(99, 95) == 4
     assert clock.process_age_s() > 0
+
+
+# -- the tail mean (``itl_tail_mean_ms``, PR 33) -------------------------------
+def _gaps(n):
+    """``n`` gaps of ONE distribution, by its quantile function: a plain
+    step of about 20 ms and a tenth of the steps behind a prefill."""
+    u = (np.arange(n) + 0.5) / n
+    return np.where(u < 0.9, 18.0 + 4.0 * u, 30.0 + 400.0 * (u - 0.9) ** 2)
+
+
+def _two_populations(n, upper_share, lower=24.0, upper=34.0):
+    k = int(round(n * upper_share))
+    return [lower] * (n - k) + [upper] * k
+
+
+@pytest.mark.parametrize("n", [100, 1000, 2700])
+def test_tail_mean_is_the_plain_mean_of_the_ranks_90_to_99(n):
+    xs = np.random.default_rng(n).gamma(2.0, 3.0, n)
+    plain = np.sort(xs)[n * 90 // 100:n * 99 // 100].mean()
+    assert clock.tail_mean(xs) == pytest.approx(plain, rel=1e-9)
+    assert clock.tail_mean(xs[::-1], 90.0, 99.0) == pytest.approx(plain)
+
+
+@pytest.mark.parametrize("n", [999, 1001, 27183])
+def test_tail_mean_is_continuous_in_the_sample_size(n):
+    assert clock.tail_mean(_gaps(n)) == pytest.approx(
+        clock.tail_mean(_gaps(1000)), rel=0.01)
+
+
+@pytest.mark.parametrize("n", [2000, 20000])
+def test_tail_mean_does_not_step_where_the_percentile_does(n):
+    """The share of gaps behind a prefill crosses 5 %: the 95th percentile
+    moves by the whole distance between the two populations, the tail mean
+    by a ninth of it (1 % of the sample over the 9 % the band is wide)."""
+    below, above = _two_populations(n, 0.045), _two_populations(n, 0.055)
+    step = 34.0 - 24.0
+    assert clock.percentile(above, 95) - clock.percentile(below, 95) == \
+        pytest.approx(step)
+    moved = clock.tail_mean(above) - clock.tail_mean(below)
+    assert 0 < moved < step / 8
+    assert moved == pytest.approx(step / 9, rel=1e-6)
+
+
+@pytest.mark.parametrize("n", [100, 1000, 20000])
+def test_tail_mean_leaves_out_the_slowest_hundredth(n):
+    """A freeze of the shared host owns the slowest gaps of a run: a
+    hundredth of the sample at 1000 times its value moves nothing."""
+    xs = np.sort(np.random.default_rng(n).gamma(2.0, 3.0, n))
+    frozen = xs.copy()
+    frozen[n - n // 100:] *= 1000.0
+    assert clock.tail_mean(frozen) == pytest.approx(clock.tail_mean(xs),
+                                                    rel=1e-9)
+    # and it is a tail: above the median, below the 99th percentile
+    assert clock.median(xs) < clock.tail_mean(xs) < clock.percentile(xs, 99)
+
+
+def test_tail_mean_edges():
+    assert clock.tail_mean([]) is None
+    assert clock.tail_mean([5.0]) == 5.0
+    assert clock.tail_mean([1, 2, 3], 0.0, 100.0) == pytest.approx(2.0)
+    with pytest.raises(ValueError):
+        clock.tail_mean([1, 2], 99.0, 90.0)
+
+
+def test_end_to_end_prints_the_tail_mean_beside_every_percentile():
+    from benchmark.lib import serving
+
+    gaps = _gaps(5000).tolist()
+    out = serving.end_to_end({"ttft_ms": [100.0, 140.0], "itl_ms": gaps},
+                             tokens_completed=800, seconds=40.0)
+    assert out["itl_tail_mean_ms"] == pytest.approx(clock.tail_mean(gaps))
+    assert out["itl_p95_ms"] == pytest.approx(np.percentile(gaps, 95))
+    assert out["itl_p90_ms"] < out["itl_tail_mean_ms"] < out["itl_p99_ms"]
+    assert {f"{k}_p{q}_ms" for k in ("ttft", "itl")
+            for q in (50, 75, 90, 95, 99)} <= set(out)
+    assert out["ttft_mean_ms"] == 120.0 and out["serve_tok_s"] == 20.0
+    empty = serving.end_to_end({"ttft_ms": [], "itl_ms": []}, 0, 40.0)
+    assert empty["itl_tail_mean_ms"] is None and empty["itl_p95_ms"] is None

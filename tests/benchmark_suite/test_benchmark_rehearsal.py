@@ -73,6 +73,11 @@ def test_runner_rehearsal(tmp_path, cell):
     assert r["device"]["platform"] == "cpu" and r["device"]["count"] >= 1
     check = r["facts"]["check"]
     assert check["ok"]
+    # every number compared, beside its limit: the line's last key
+    pairs = run.compared(r)
+    assert {"logits_rms_rel_err_median", "logits_rms_rel_err_worst"} <= \
+        set(pairs)
+    assert all(0 <= c["value"] <= c["limit"] for c in pairs.values()), pairs
     if cell[2] == "tiny-chat":
         assert r["samples"]["ttft_ms"] == r["attempted"]
         assert "measured" not in r      # no time from a CPU run

@@ -164,3 +164,41 @@ def test_no_published_width_is_changed(reg):
          "layer_norm_epsilon": 1e-5}
     assert gpt["published"] == {"n_layer": 24, "vocab_size": 50257}
     assert gpt["token_id_limit"] == 50257 and gpt["vocab_size"] % 128 == 0
+
+
+def test_the_gap_tail_that_is_judged_and_the_one_beside_it(reg, bm):
+    """Since PR 33 the serving cells are judged on ``itl_tail_mean_ms``;
+    ``itl_p95_ms``, which every earlier line of the ledger carries, is read
+    in every traced run by ``sched.itl_p95.serve``."""
+    serving = [w["name"] for w in bm["workloads"]
+               if w["name"].startswith("serve-")]
+    e2e = {m["name"]: m for m in bm["end_to_end"]}
+    assert "itl_p95_ms" not in e2e
+    assert e2e["itl_tail_mean_ms"]["workloads"] == serving
+    assert (e2e["itl_tail_mean_ms"]["unit"], e2e["itl_tail_mean_ms"]["better"],
+            e2e["itl_tail_mean_ms"]["source"]) == ("ms", "lower", "host_clock")
+    assert reg._entry("per_layer", "sched.itl_p95.serve")["workloads"] == \
+        serving
+    spec = reg.layer_metric("sched.itl_p95.serve")
+    assert (spec["reader"], spec["args"]) == ("measured",
+                                              {"name": "itl_p95_ms"})
+    import types
+    ctx = types.SimpleNamespace(end_to_end={"itl_p95_ms": 24.5,
+                                            "itl_tail_mean_ms": 27.0})
+    assert reg.module("readers", "measured").read(ctx, **spec["args"]) == 24.5
+    assert not any(m["moves"] == "itl_p95_ms" for m in bm["per_layer"])
+
+
+@pytest.mark.parametrize("counters, refusals", [
+    ({}, 0.0),
+    # the program counts a refusal under its own name and under the total
+    ({"kernel_fallback.paged_decode_attention.scale": 4,
+      "kernel_fallback.total": 4}, 4.0),
+    ({"kernel_fallback.paged_decode_attention.head_dim": 4,
+      "kernel_fallback.ssm_state_update.rows": 1,
+      "kernel_fallback.total": 5}, 5.0),
+])
+def test_a_refusal_is_counted_once(reg, counters, refusals):
+    import types
+    ctx = types.SimpleNamespace(fallbacks=counters)
+    assert reg.module("readers", "fallbacks").read(ctx) == refusals
