@@ -42,9 +42,37 @@ from ..tensor.tensor import Tensor, apply_op
 from .serve_protocol import AttentionLayer, StateLayer
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridModel",
-           "GraniteHybridForCausalLM", "granite_hybrid_tiny"]
+           "GraniteHybridForCausalLM", "granite_hybrid_tiny", "Mamba2Dims",
+           "Mamba2Mixer"]
 
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+@dataclass(frozen=True)
+class Mamba2Dims:
+    """One Mamba-2 mixer's sizes, whatever keys a family publishes them
+    under.  ``norm_groups``: the gated norm's groups — 1 norms ``y *
+    silu(z)`` over the whole inner width (Granite-4.0-H), ``n_groups`` norms
+    each B/C group's channels apart (Nemotron-H)."""
+    hidden: int
+    n_heads: int
+    d_head: int
+    d_state: int
+    d_conv: int
+    n_groups: int
+    chunk: int
+    conv_bias: bool = True
+    proj_bias: bool = False
+    eps: float = 1e-5
+    norm_groups: int = 1
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.d_state
 
 
 @dataclass
@@ -118,6 +146,14 @@ class GraniteHybridConfig:
         return self.mamba_d_inner + 2 * self.mamba_n_groups \
             * self.mamba_d_state
 
+    @property
+    def mamba_dims(self) -> Mamba2Dims:
+        return Mamba2Dims(
+            self.hidden_size, self.mamba_n_heads, self.mamba_d_head,
+            self.mamba_d_state, self.mamba_d_conv, self.mamba_n_groups,
+            self.mamba_chunk_size, self.mamba_conv_bias,
+            self.mamba_proj_bias, self.rms_norm_eps)
+
 
 def granite_hybrid_tiny(**kw) -> GraniteHybridConfig:
     """Test-scale config: one short period with both kinds of layer."""
@@ -162,7 +198,8 @@ class _InverseSoftplusLogUniform(I.Initializer):
 def _mamba_mix(dims, zxbcdt, conv_w, conv_b, A_log, dt_bias, D, norm_w,
                tail0, state0, n_valid, chunk, live):
     """Everything between the two projections of a Mamba-2 mixer, on arrays.
-    ``dims``: the configuration's ``(H, P, G, N, d_inner, conv_dim, eps)``;
+    ``dims``: the mixer's ``(H, P, G, N, d_inner, conv_dim, eps, norm
+    groups)``;
     ``zxbcdt [b, T, 2 * d_inner + 2 * G * N + H]``; ``tail0 [b, K - 1,
     conv_dim]``, ``state0 [b, H, P, N]`` carried in; ``n_valid [b]``.  With
     ``live`` ([b] bool: the decode step, T == 1) the recurrence is one
@@ -175,7 +212,7 @@ def _mamba_mix(dims, zxbcdt, conv_w, conv_b, A_log, dt_bias, D, norm_w,
     from ..ops import ssm
 
     b, T, _ = zxbcdt.shape
-    H, P, G, N, di, conv_dim, eps = dims
+    H, P, G, N, di, conv_dim, eps, norm_groups = dims
     z = zxbcdt[..., :di]
     xBC = zxbcdt[..., di:di + conv_dim]
     dt = zxbcdt[..., di + conv_dim:]
@@ -194,29 +231,35 @@ def _mamba_mix(dims, zxbcdt, conv_w, conv_b, A_log, dt_bias, D, norm_w,
     else:
         y, state = ssm.ssd_chunked(x, dt, A, B, C, D, state0, n_valid,
                                    chunk)
-    # the gated norm: RMSNorm(y * silu(z)) over the whole inner width
+    # the gated norm: RMSNorm(y * silu(z)) over the whole inner width, or
+    # over each of ``norm_groups`` runs of channels apart
     g = y.reshape(b, T, di).astype(jnp.float32) \
         * jax.nn.silu(z.astype(jnp.float32))
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True)
-                          + eps) * norm_w.astype(jnp.float32)
+    if norm_groups > 1:
+        g = g.reshape(b, T, norm_groups, di // norm_groups)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
+    g = g.reshape(b, T, di) * norm_w.astype(jnp.float32)
     return g.astype(zxbcdt.dtype), tail, state
 
 
-class GraniteMambaMixer(nn.Layer):
-    def __init__(self, config: GraniteHybridConfig):
+class Mamba2Mixer(nn.Layer):
+    """``in_proj``, the convolution, the recurrence, the gated norm and
+    ``out_proj`` of one Mamba-2 layer (module docstring).  ``out_init``:
+    the output projection's initializer where it is not ``init``."""
+
+    def __init__(self, dims: Mamba2Dims, init, out_init=None):
         super().__init__()
-        self.config = config
-        init = nn.initializer.Normal(0.0, config.initializer_range)
-        H, K = config.mamba_n_heads, config.mamba_d_conv
-        di, cd = config.mamba_d_inner, config.mamba_conv_dim
-        bias = None if config.mamba_proj_bias else False
-        self.in_proj = nn.Linear(config.hidden_size, di + cd + H,
+        self.dims = dims
+        H, K = dims.n_heads, dims.d_conv
+        di, cd = dims.d_inner, dims.conv_dim
+        bias = None if dims.proj_bias else False
+        self.in_proj = nn.Linear(dims.hidden, di + cd + H,
                                  weight_attr=init, bias_attr=bias)
         # depthwise taps [conv_dim, K]; torch's conv1d default range
         self.conv_weight = self.create_parameter(
             [cd, K], default_initializer=I.Uniform(-K ** -0.5, K ** -0.5))
         self.conv_bias = self.create_parameter(
-            [cd], is_bias=True) if config.mamba_conv_bias else None
+            [cd], is_bias=True) if dims.conv_bias else None
         self.A_log = self.create_parameter(
             [H], default_initializer=_LogUniformExp(1.0, 16.0))
         self.dt_bias = self.create_parameter(
@@ -225,23 +268,23 @@ class GraniteMambaMixer(nn.Layer):
             [H], default_initializer=I.Constant(1.0))
         self.norm_weight = self.create_parameter(
             [di], default_initializer=I.Constant(1.0))
-        self.out_proj = nn.Linear(di, config.hidden_size, weight_attr=init,
+        self.out_proj = nn.Linear(di, dims.hidden,
+                                  weight_attr=out_init or init,
                                   bias_attr=bias)
 
     def state_shapes(self, dtype):
         """One request's carried arrays: ``(shape, dtype)`` by name.  The
         recurrent state is float32 whatever the compute dtype."""
-        cfg = self.config
-        return {"conv": ((cfg.mamba_d_conv - 1, cfg.mamba_conv_dim), dtype),
-                "ssm": ((cfg.mamba_n_heads, cfg.mamba_d_head,
-                         cfg.mamba_d_state), "float32")}
+        d = self.dims
+        return {"conv": ((d.d_conv - 1, d.conv_dim), dtype),
+                "ssm": ((d.n_heads, d.d_head, d.d_state), "float32")}
 
     def forward(self, u, tail0=None, state0=None, n_valid=None,
                 chunk: Optional[int] = None, live=None):
         """``u [b, T, hidden]``.  Alone it is the full-sequence mixer (zero
         state in, states dropped); with ``tail0`` / ``state0`` (arrays) it
         returns ``(out, tail, state)``."""
-        cfg = self.config
+        d = self.dims
         b, T = u.shape[0], u.shape[1]
         carried = tail0 is not None
         zx = self.in_proj(u)
@@ -251,19 +294,18 @@ class GraniteMambaMixer(nn.Layer):
             state0 = jnp.zeros((b, *shapes["ssm"][0]), jnp.float32)
         if n_valid is None:
             n_valid = jnp.full((b,), T, jnp.int32)
-        chunk = chunk or cfg.mamba_chunk_size
+        chunk = chunk or d.chunk
         params = [self.conv_weight, self.A_log, self.dt_bias, self.D,
                   self.norm_weight]
         if self.conv_bias is not None:
             params.append(self.conv_bias)
 
-        dims = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
-                cfg.mamba_d_state, cfg.mamba_d_inner, cfg.mamba_conv_dim,
-                cfg.rms_norm_eps)
+        dims = (d.n_heads, d.d_head, d.n_groups, d.d_state, d.d_inner,
+                d.conv_dim, d.eps, d.norm_groups)
 
-        def fn(zx_v, w, a_log, dt_b, d, nw, cb=None):
-            return _mamba_mix(dims, zx_v, w, cb, a_log, dt_b, d, nw, tail0,
-                              state0, n_valid, chunk, live)
+        def fn(zx_v, w, a_log, dt_b, d_skip, nw, cb=None):
+            return _mamba_mix(dims, zx_v, w, cb, a_log, dt_b, d_skip, nw,
+                              tail0, state0, n_valid, chunk, live)
 
         y, tail, state = apply_op("mamba2_mix", fn, (zx, *params),
                                   multi_out=True)
@@ -332,7 +374,9 @@ class GraniteHybridLayer(nn.Layer):
         self.input_layernorm = nn.RMSNorm(config.hidden_size,
                                           config.rms_norm_eps)
         if kind == "mamba":
-            self.mamba = GraniteMambaMixer(config)
+            self.mamba = Mamba2Mixer(
+                config.mamba_dims,
+                nn.initializer.Normal(0.0, config.initializer_range))
         else:
             self.self_attn = GraniteAttention(config)
         self.post_attention_layernorm = nn.RMSNorm(config.hidden_size,

@@ -1,7 +1,7 @@
 """How a causal LM tells ``ServingEngine`` what each of its layers keeps.
 
 A served model describes itself layer by layer (``serve_layers()``), and a
-layer is one of three kinds: an :class:`AttentionLayer` keeps K/V that grow
+layer is one of four kinds: an :class:`AttentionLayer` keeps K/V that grow
 by a token a step, in pages of the engine's ``PagedKVPool``; a
 :class:`LatentAttentionLayer` (multi-head latent attention) keeps ONE latent
 row a token in the same pages, ``[c_kv | k_rope]``, shared by every head and
@@ -9,12 +9,16 @@ never expanded to per-head K/V in the cache; a :class:`StateLayer` (a
 state-space / recurrent mixer) keeps arrays of a FIXED shape per request —
 for Mamba-2 the last ``d_conv - 1`` convolution inputs and the ``[H, P, N]``
 recurrent state — however long the request is (``serving/state_pool.py``
-holds those).
+holds those); a :class:`StatelessLayer` keeps NOTHING per request (a
+feed-forward or expert block that is a layer of its own, as in Nemotron-H,
+where a block is one mixer OR one feed-forward part): the engine gives it no
+arena, and it sees only which tokens are real.
 
 The engine walks ``serve_layers()`` once per program and owns pages, tables
 and state slots; the model owns its block math::
 
-    model.serve_layers()     -> [AttentionLayer | LatentAttentionLayer | StateLayer]
+    model.serve_layers()     -> [AttentionLayer | LatentAttentionLayer
+                                 | StateLayer | StatelessLayer]
     model.serve_begin(tokens, positions)       -> (x, shared)
     model.serve_layer(i, x, shared, io)        -> x
     model.serve_end(x)                         -> logits
@@ -29,7 +33,12 @@ engine a count made inside the program (it rides the step's one fetch);
 step's logits, for a tolerance harness to fetch (no step does);
 ``io.read_state(name)`` / ``io.write_state(name, value)`` read and write the
 rows' slots of one state array; ``io.n_valid [R]`` says how many of a row's
-tokens are real and ``io.live [R]`` which rows step at all.
+tokens are real and ``io.live [R]`` which rows step at all.  A layer may
+ask only what its kind keeps: ``attend`` is an :class:`AttentionLayer`'s,
+``attend_latent`` a :class:`LatentAttentionLayer`'s, the state calls a
+:class:`StateLayer`'s, and any of them from another kind raises a
+``TypeError`` that names the call and the kind; ``note``, ``keep``,
+``n_valid`` and ``live`` are every kind's.
 
 It lives under ``models/`` so that a model need not import ``serving/``.
 """
@@ -41,7 +50,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-__all__ = ["AttentionLayer", "LatentAttentionLayer", "StateLayer"]
+__all__ = ["AttentionLayer", "LatentAttentionLayer", "StateLayer",
+           "StatelessLayer"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,3 +100,10 @@ class StateLayer:
         return StateLayer(tuple(
             (name, (tuple(int(s) for s in shape), str(np.dtype(dtype))))
             for name, (shape, dtype) in sorted(arrays.items())))
+
+
+@dataclasses.dataclass(frozen=True)
+class StatelessLayer:
+    """A layer that keeps nothing per request: no pages, no state slot.
+    The engine walks it like the others and hands it ``io.n_valid`` /
+    ``io.live`` / ``io.note`` / ``io.keep`` only."""

@@ -23,6 +23,11 @@ parallelism), routes over all of them all the same, and returns the held
 experts' weighted part of the result.  What the other experts would add is
 the other chips' to compute; on one chip the layer runs without the
 exchange.  ``(0, n_routed_experts)`` is the whole layer.
+
+An expert is a SwiGLU of three matrices, ``W_down (silu(W_gate x) * W_up
+x)`` (``gated=True``, ``activation="silu"``: DeepSeek-V3), or of two with no
+gate matrix, ``W_down act(W_up x)`` (``gated=False``): Nemotron-H's experts
+are ``W_down relu(W_up x) ** 2`` (``activation="relu2"``).
 """
 
 from __future__ import annotations
@@ -38,7 +43,11 @@ from ...tensor.tensor import apply_op
 from .layers import Layer
 
 __all__ = ["RoutedExperts", "group_limited_topk", "grouped_matmul",
-           "held_experts_mlp"]
+           "held_experts_mlp", "ACTIVATIONS"]
+
+# an expert's activation by its published name, in float32
+ACTIVATIONS = {"silu": jax.nn.silu,
+               "relu2": lambda v: jnp.square(jax.nn.relu(v))}
 
 
 def group_limited_topk(scores, bias, *, top_k: int, n_group: int,
@@ -74,7 +83,7 @@ def _route(x, gate_weight, bias, **routing):
     return group_limited_topk(scores, bias, **routing)
 
 
-def grouped_matmul_kernel(rows: int, w_gate_shape, w_down_shape, dtype):
+def grouped_matmul_kernel(rows: int, w_up_shape, w_down_shape, dtype):
     """How an expert layer multiplies ``rows`` sorted pairs by their
     experts, decided at trace time like the decode kernels
     (``pallas_mode("use_decode_attention")``): ``"mosaic"`` or
@@ -90,7 +99,7 @@ def grouped_matmul_kernel(rows: int, w_gate_shape, w_down_shape, dtype):
         return None
     kind, _, interpret = mode
     reason = "hybrid_mesh" if kind != "local" else (
-        grouped_matmul_refusal((rows, w_gate_shape[1]), w_gate_shape, dtype,
+        grouped_matmul_refusal((rows, w_up_shape[1]), w_up_shape, dtype,
                                interpret=interpret)
         or grouped_matmul_refusal((rows, w_down_shape[1]), w_down_shape,
                                   dtype, interpret=interpret))
@@ -140,26 +149,30 @@ def grouped_matmul(x, w, group_sizes, kernel: Optional[str] = None):
 
 # jitted: the expert layers of a program share one trace, and an eager call
 # is one dispatch, not one an operation
-@functools.partial(jax.jit, static_argnames=("kernel",))
+@functools.partial(jax.jit, static_argnames=("kernel", "activation"))
 def held_experts_mlp(x, idx, weight, w_gate, w_up, w_down, first,
-                     *, kernel: Optional[str] = None):
+                     *, kernel: Optional[str] = None,
+                     activation: str = "silu"):
     """The held experts' part of ``sum_chosen w_e * E_e(x)``.  ``x`` [N, D];
     ``idx`` / ``weight`` [N, k]: every token's experts and weights;
     ``w_gate`` / ``w_up`` [G, D, H], ``w_down`` [G, H, D]: experts ``first ..
-    first + G - 1``, each a SwiGLU.  Returns ``(y [N, D], group_sizes
-    [G])``: a pair whose expert is not held adds nothing, and no pair whose
-    expert is held is dropped."""
+    first + G - 1``, each ``W_down (act(W_gate x) * W_up x)``, or with
+    ``w_gate`` None ``W_down act(W_up x)``: two grouped matmuls, not three.
+    Returns ``(y [N, D], group_sizes [G])``: a pair whose expert is not
+    held adds nothing, and no pair whose expert is held is dropped."""
     n, k = idx.shape
-    count = w_gate.shape[0]
+    act = ACTIVATIONS[activation]
+    count = w_up.shape[0]
     local = idx - first
     held = (local >= 0) & (local < count)
     key = jnp.where(held, local, count).reshape(-1)            # [N * k]
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
     xs = x[order // k]                                         # [N * k, D]
-    h = jax.nn.silu(grouped_matmul(xs, w_gate, sizes, kernel)
-                    .astype(jnp.float32)).astype(x.dtype) \
-        * grouped_matmul(xs, w_up, sizes, kernel)
+    h = act(grouped_matmul(xs, w_up if w_gate is None else w_gate, sizes,
+                           kernel).astype(jnp.float32)).astype(x.dtype)
+    if w_gate is not None:
+        h = h * grouped_matmul(xs, w_up, sizes, kernel)
     y = grouped_matmul(h, w_down, sizes, kernel)               # sorted rows
     # back to (token, choice) order; rows of experts not held are zero
     back = jnp.zeros_like(order).at[order].set(
@@ -181,6 +194,7 @@ class RoutedExperts(Layer):
                  topk_group: int = 1, norm_topk_prob: bool = True,
                  routed_scaling_factor: float = 1.0,
                  experts_held: Optional[Tuple[int, int]] = None,
+                 activation: str = "silu", gated: bool = True,
                  weight_attr=None, bias_attr=None):
         super().__init__()
         first, count = experts_held or (0, n_routed_experts)
@@ -192,7 +206,11 @@ class RoutedExperts(Layer):
                 or top_k > topk_group * (n_routed_experts // n_group):
             raise ValueError("n_group must divide n_routed_experts and the "
                              "kept groups must hold top_k experts")
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"activation {activation!r}: one of "
+                             f"{sorted(ACTIVATIONS)}")
         self.experts_held = (int(first), int(count))
+        self.activation = activation
         self.routing = dict(top_k=top_k, n_group=n_group,
                             topk_group=topk_group,
                             norm_topk_prob=norm_topk_prob,
@@ -204,8 +222,9 @@ class RoutedExperts(Layer):
         self.e_score_correction_bias = self.create_parameter(
             [n_routed_experts], attr=bias_attr,
             default_initializer=I.Constant(0.0))
-        self.gate_proj = self.create_parameter([count, d, h],
-                                               attr=weight_attr)
+        # no gate matrix: the expert is W_down act(W_up x)
+        self.gate_proj = self.create_parameter(
+            [count, d, h], attr=weight_attr) if gated else None
         self.up_proj = self.create_parameter([count, d, h], attr=weight_attr)
         self.down_proj = self.create_parameter([count, h, d],
                                                attr=weight_attr)
@@ -217,22 +236,29 @@ class RoutedExperts(Layer):
         padding) are routed nowhere and cost no expert a row."""
         first = self.experts_held[0]
         routing = self.routing
+        activation = self.activation
         kernel = grouped_matmul_kernel(
-            x.size // x.shape[-1] * routing["top_k"], self.gate_proj.shape,
+            x.size // x.shape[-1] * routing["top_k"], self.up_proj.shape,
             self.down_proj.shape, x._value.dtype)
 
-        def fn(xv, wg, b, w_gate, w_up, w_down):
+        def fn(xv, wg, b, *experts):
+            # (gate,) up, down
+            w_gate = experts[0] if len(experts) == 3 else None
+            w_up, w_down = experts[-2:]
             flat = xv.reshape(-1, xv.shape[-1])
             idx, w = _route(flat, wg, b, **routing)
             if valid is not None:
                 idx = jnp.where(valid.reshape(-1, 1), idx, -1)
             y, load = held_experts_mlp(flat, idx, w, w_gate, w_up, w_down,
-                                       first, kernel=kernel)
+                                       first, kernel=kernel,
+                                       activation=activation)
             return y.reshape(xv.shape), load, \
                 idx.reshape(xv.shape[:-1] + idx.shape[-1:])
 
+        experts = [w for w in (self.gate_proj, self.up_proj, self.down_proj)
+                   if w is not None]
         y, load, choice = apply_op("routed_experts", fn, (
-            x, self.gate_weight, self.e_score_correction_bias,
-            self.gate_proj, self.up_proj, self.down_proj), multi_out=True)
+            x, self.gate_weight, self.e_score_correction_bias, *experts),
+            multi_out=True)
         self.last_load, self.last_choice = load._value, choice._value
         return y

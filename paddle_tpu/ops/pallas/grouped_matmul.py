@@ -54,11 +54,14 @@ MAX_TILE = 1024
 
 def _tile(n: int, unit: int) -> Optional[int]:
     """The largest divisor of ``n`` that is a multiple of ``unit`` and at
-    most ``MAX_TILE`` (``n`` itself where it is smaller), or None."""
+    most ``MAX_TILE`` (``n`` itself where it is smaller).  A width no
+    multiple of ``unit`` divides (1856 = 14.5 lane registers) is one tile,
+    the whole axis, which a block may always be, up to ``2 * MAX_TILE``;
+    else None."""
     for t in range(min(n, MAX_TILE) // unit * unit, 0, -unit):
         if n % t == 0:
             return t
-    return None
+    return n if n <= 2 * MAX_TILE and n % _MIN_SUBLANES == 0 else None
 
 
 def _tiles(m: int, k: int, n: int, dtype, interpret: bool
@@ -112,15 +115,19 @@ def _work_list(group_sizes, m: int, tm: int):
 
 
 def _gmm_kernel(nw_ref, group_ref, tile_ref, start_ref, end_ref,
-                x_ref, w_ref, o_ref, acc_ref, *, tm: int, k_tiles: int):
+                x_ref, w_ref, o_ref, acc_ref, *, tm: int, k_tiles: int,
+                transposed: bool):
     i, kk = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kk == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
-                            preferred_element_type=jnp.float32)
+    # w_ref[0] is [tk, tn], or [tn, tk] where the weights lie transposed
+    acc_ref[...] += jax.lax.dot_general(
+        x_ref[...], w_ref[0],
+        (((1,), (1 if transposed else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     @pl.when(kk == k_tiles - 1)
     def _store():
@@ -143,16 +150,30 @@ def grouped_matmul(x, w, group_sizes, *, interpret: bool = False):
     tm, tk, tn = _tiles(m, k, n, x.dtype, interpret)
     n_work, group, tile, starts, ends = _work_list(group_sizes, m, tm)
 
+    # A width that is not whole lane registers (1856) is no minor axis the
+    # chip keeps: it lays ``w`` [G, K, N] out with K minor, so the kernel
+    # takes that view as it lies ([G, N, K], the swap is a bitcast) and
+    # contracts both operands' last axes, rather than have XLA re-lay the
+    # weights out ahead of every call
+    transposed = n % _LANES != 0 and k % _LANES == 0
+    w = w.astype(x.dtype)
+    if transposed:
+        w = jnp.swapaxes(w, 1, 2)
+        w_spec = pl.BlockSpec(
+            (1, tn, tk), lambda j, i, kk, nw, g, t, s, e: (g[i], j, kk))
+    else:
+        w_spec = pl.BlockSpec(
+            (1, tk, tn), lambda j, i, kk, nw, g, t, s, e: (g[i], kk, j))
     out = pl.pallas_call(
-        functools.partial(_gmm_kernel, tm=tm, k_tiles=k // tk),
+        functools.partial(_gmm_kernel, tm=tm, k_tiles=k // tk,
+                          transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n // tn, n_work[0], k // tk),
             in_specs=[
                 pl.BlockSpec((tm, tk),
                              lambda j, i, kk, nw, g, t, s, e: (t[i], kk)),
-                pl.BlockSpec((1, tk, tn),
-                             lambda j, i, kk, nw, g, t, s, e: (g[i], kk, j)),
+                w_spec,
             ],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda j, i, kk, nw, g, t, s, e: (t[i], j)),
@@ -163,7 +184,7 @@ def grouped_matmul(x, w, group_sizes, *, interpret: bool = False):
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         name=KERNEL_NAME,
         interpret=interpret,
-    )(n_work, group, tile, starts, ends, x, w.astype(x.dtype))
+    )(n_work, group, tile, starts, ends, x, w)
     # tiles no group touches were never written
     rows = jnp.arange(m, dtype=jnp.int32)[:, None]
     return jnp.where(rows < ends[-1], out, jnp.zeros_like(out))

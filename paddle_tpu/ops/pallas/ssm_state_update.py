@@ -16,16 +16,20 @@ Shape contract (one state layer):
 - live  [R] bool             — rows to update; the others are neither read
   nor written, and their ``y`` comes back zero
 - x [R, H, P], dt [R, H] (after the softplus), A [H] (negative), D [H]
-- B, C [R, 1, N]             — one group (the gate refuses more)
+- B, C [R, G, N]             — ``G`` groups of ``H / G`` consecutive heads:
+  head ``h`` reads row ``h // (H / G)``
 
 Grid ``(R, H / hb)``: a step moves one ``[hb, P, N]`` block (1 MB at hb =
-32) in and out.  The rows are visited in the order of a list built in the
-program — live rows first — and every step past the last live one maps to
-the block of the step before it, so the pipeline sees an unchanged block
-index and moves no data for it.  Per head the state tile ``[P, N]`` has the
-head's width on sublanes and the state on lanes: ``B`` and ``C`` are lane
-rows, and the per-(head, p) coefficients arrive transposed (``[P, hb]``)
-so that a head's column broadcasts along lanes.
+32) in and out, and with it the B / C rows of the groups its heads lie in
+(at 8 groups of 8 heads a block spans 4: the block is not shrunk to a
+group), so the per-head loop indexes them statically.  The rows are visited
+in the order of a list built in the program — live rows first — and every
+step past the last live one maps to the block of the step before it, so the
+pipeline sees an unchanged block index and moves no data for it.  Per head
+the state tile ``[P, N]`` has the head's width on sublanes and the state on
+lanes: ``B`` and ``C`` are lane rows, and the per-(head, p) coefficients
+arrive transposed (``[P, hb]``) so that a head's column broadcasts along
+lanes.
 
 No VJP: decode runs under ``no_grad`` by construction.
 """
@@ -50,9 +54,12 @@ KERNEL_NAME = "ssm_state_update"
 _BLOCK_BYTES = 1 << 20
 
 
-def _head_block(H: int, P: int, N: int) -> int:
+def _head_block(H: int, P: int, N: int, G: int = 1) -> int:
+    """Heads a grid step moves: the most that ``_BLOCK_BYTES`` hold, that
+    divide ``H``, and that are whole groups or a whole part of one."""
     hb = max(min(H, _BLOCK_BYTES // (P * N * 4)), 1)
-    while H % hb:
+    per_group = H // G
+    while H % hb or (hb % per_group and per_group % hb):
         hb -= 1
     return hb
 
@@ -65,11 +72,12 @@ def ssm_state_update_refusal(state_shape, dtype, b_shape) -> Optional[str]:
     if jnp.dtype(dtype) != jnp.float32:
         return "state_dtype"
     _, H, P, N = state_shape
-    if b_shape[1] != 1:
-        return "n_groups"
+    G = b_shape[1]
+    if G < 1 or H % G:
+        return "head_groups"
     if N % _LANES or P % _MIN_SUBLANES:
         return "state_tile"
-    hb = _head_block(H, P, N)
+    hb = _head_block(H, P, N, G)
     # state in and out, double-buffered, and the small operands
     if 4 * hb * P * N * 4 + 8 * P * max(hb, _LANES) * 4 > _VMEM_BUDGET:
         return "vmem"
@@ -77,18 +85,19 @@ def ssm_state_update_refusal(state_shape, dtype, b_shape) -> Optional[str]:
 
 
 def _kernel(rows_ref, nlive_ref, coef_ref, bc_ref, s_ref, o_ref, y_ref, *,
-            hb: int):
+            hb: int, per_group: int):
     del rows_ref
     nlive = nlive_ref[0]
     visit = pl.program_id(0) < nlive
 
     @pl.when(visit)
     def _update():
-        b_row = bc_ref[0, 0:1, :]                       # (1, N)
-        c_row = bc_ref[0, 1:2, :]
         dA_t = coef_ref[0, 0, 0]                        # (P, hb)
         dtx_t = coef_ref[0, 0, 1]
         for h in range(hb):
+            g = h // per_group      # the head's group within the block
+            b_row = bc_ref[0, 0, 2 * g:2 * g + 1, :]    # (1, N)
+            c_row = bc_ref[0, 0, 2 * g + 1:2 * g + 2, :]
             dA = dA_t[:, h:h + 1]                       # (P, 1)
             dtx = dtx_t[:, h:h + 1]
             s = dA * s_ref[0, h] + dtx * b_row          # (P, N)
@@ -112,8 +121,11 @@ def ssm_state_update(state, live, x, dt, A, B, C, D, *,
     """Update the live rows of ``state`` in place by one token and return
     ``(y [R, H, P] in x's dtype, state)`` (module docstring)."""
     R, H, P, N = state.shape
-    hb = _head_block(H, P, N)
+    G = B.shape[1]
+    hb = _head_block(H, P, N, G)
     nj = H // hb
+    # a head block holds ``gb`` whole groups, or ``hb`` heads of one
+    gb = max(hb // (H // G), 1)
     f32 = jnp.float32
 
     live = live.astype(bool)
@@ -129,13 +141,19 @@ def ssm_state_update(state, live, x, dt, A, B, C, D, *,
                       dt[:, :, None] * xf], axis=1)             # [R, 2, H, P]
     # [R, nj, 2, P, hb]: a head block's coefficients, the head on lanes
     coef = coef.reshape(R, 2, nj, hb, P).transpose(0, 2, 1, 4, 3)
-    bc = jnp.concatenate([B, C], axis=1).astype(f32)            # [R, 2, N]
+    # [R, G / gb, 2 * gb, N]: a head block's groups, B and C of a group
+    # on neighbouring rows
+    bc = jnp.stack([B, C], axis=2).astype(f32).reshape(R, G // gb, 2 * gb, N)
 
     def row_block(i, j, rows_ref, nlive_ref):
         return rows_ref[i], jnp.where(i < nlive_ref[0], j, nj - 1)
 
+    def group_block(i, j, rows_ref, nlive_ref):
+        r, j = row_block(i, j, rows_ref, nlive_ref)
+        return r, j * hb // (gb * (H // G))
+
     new, y = pl.pallas_call(
-        functools.partial(_kernel, hb=hb),
+        functools.partial(_kernel, hb=hb, per_group=H // G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, nj),
@@ -143,8 +161,9 @@ def ssm_state_update(state, live, x, dt, A, B, C, D, *,
                 pl.BlockSpec((1, 1, 2, P, hb),
                              lambda i, j, r, n: (*row_block(i, j, r, n),
                                                  0, 0, 0)),
-                pl.BlockSpec((1, 2, N),
-                             lambda i, j, r, n: (r[i], 0, 0)),
+                pl.BlockSpec((1, 1, 2 * gb, N),
+                             lambda i, j, r, n: (*group_block(i, j, r, n),
+                                                 0, 0)),
                 pl.BlockSpec((1, hb, P, N),
                              lambda i, j, r, n: (*row_block(i, j, r, n),
                                                  0, 0)),

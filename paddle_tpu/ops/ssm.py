@@ -144,7 +144,8 @@ def ssm_decode_update(state, live, x, dt, A, B, C, D):
     ``ssm_state_update`` where kernels run (a TPU, or the interpreter) and
     its gate takes the shapes — only LIVE rows' state is read and written,
     in place — else :func:`ssm_step` over every row, with a counted
-    ``kernel_fallback`` where a kernel could have run."""
+    ``kernel_fallback`` where a kernel could have run.  ``B, C [R, G, N]``:
+    any number of groups that divides the heads."""
     from . import pallas_mode
     from .pallas.ssm_state_update import (KERNEL_NAME, ssm_state_update,
                                           ssm_state_update_refusal)

@@ -61,7 +61,8 @@ from .kv_quant import (FP8_MAX, KV_DTYPES, default_fp8_scale,  # noqa: F401
                        kv_page_bytes, kv_scale_page_bytes, layer_page_bytes,
                        observe_kv_absmax, quantize_kv, quantize_kv_fp8)
 from ..models.serve_protocol import (AttentionLayer,  # noqa: F401
-                                     LatentAttentionLayer, StateLayer)
+                                     LatentAttentionLayer, StateLayer,
+                                     StatelessLayer)
 from .state_pool import RowStatePool, StateLayersUnsupported  # noqa: F401
 from .metrics import FleetMeter, RequestClock, SLOMeter  # noqa: F401
 from .admission import (AdmissionController, CircuitBreaker, Deadline,  # noqa: F401
@@ -83,7 +84,8 @@ from .disagg import (DisaggCoordinator, PrefillWorker,  # noqa: F401
 __all__ = [
     "PagedKVPool", "PoolExhausted", "TRASH_PAGE", "default_page_tokens",
     "OffloadPool", "default_offload_pages",
-    "AttentionLayer", "LatentAttentionLayer", "StateLayer", "RowStatePool",
+    "AttentionLayer", "LatentAttentionLayer", "StateLayer",
+    "StatelessLayer", "RowStatePool",
     "StateLayersUnsupported", "LatentLayersUnsupported",
     "KV_DTYPES", "kv_cache_dtype", "quantize_kv", "dequantize_kv",
     "quantize_kv_fp8", "dequantize_kv_fp8", "default_fp8_scale", "FP8_MAX",

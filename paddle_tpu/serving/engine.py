@@ -104,7 +104,7 @@ from .kv_quant import (default_fp8_scale, dequantize_kv, dequantize_kv_fp8,
 from .metrics import SLOMeter
 from .prefix_cache import PrefixCache
 from ..models.serve_protocol import AttentionLayer, LatentAttentionLayer, \
-    StateLayer
+    StateLayer, StatelessLayer
 from .state_pool import RowStatePool, StateLayersUnsupported
 
 __all__ = ["Request", "ServingEngine", "check_decode_donation"]
@@ -282,10 +282,13 @@ def check_decode_donation(compiled, arena_bytes: int,
 class _LayerIO:
     """The engine's side of ONE layer inside a traced program: what
     ``model.serve_layer(i, x, shared, io)`` may touch.  An attention layer
-    calls :meth:`attend`; a state layer reads and writes its rows' slots
-    (:meth:`read_state` / :meth:`write_state`) and looks at ``n_valid``
-    [R] (tokens of each row that are real) and ``live`` [R] (rows that
-    step at all).  The updated arenas land in the program's result."""
+    calls :meth:`attend` (a latent one :meth:`attend_latent`); a state
+    layer reads and writes its rows' slots (:meth:`read_state` /
+    :meth:`write_state`); every kind, a layer that keeps nothing too, may
+    look at ``n_valid`` [R] (tokens of each row that are real) and ``live``
+    [R] (rows that step at all) and call :meth:`note` / :meth:`keep`.  A
+    call that is another kind's raises a ``TypeError`` that names it.  The
+    updated arenas land in the program's result."""
 
     def __init__(self, engine, spec, arenas, index, tables, positions,
                  n_tok, n_valid, row, fresh, notes, kept):
@@ -296,9 +299,17 @@ class _LayerIO:
         self.n_valid = n_valid
         self.live = n_valid > 0
 
+    def _only(self, kind, call: str) -> None:
+        if not isinstance(self._spec, kind):
+            raise TypeError(
+                f"io.{call} is a {kind.__name__}'s call; this layer "
+                f"described itself as {type(self._spec).__name__}, which "
+                f"keeps no such memory")
+
     def attend(self, q, k, v):
         """Scatter this step's ``k`` / ``v`` [R, s, kv, d] into the layer's
         pages and attend ``q`` [R, s, h, d] over each row's pages."""
+        self._only(AttentionLayer, "attend")
         eng, spec = self._eng, self._spec
         walk = eng._page_walk(q.shape[0], q.shape[1], spec) \
             if self._row is None else None      # the decode program
@@ -320,6 +331,7 @@ class _LayerIO:
         absorbs them into the query and the output and walks the latent
         rows as they lie; the prefill program expands the row's pages to
         per-head K/V inside the program.  Returns [R, s, h, v]."""
+        self._only(LatentAttentionLayer, "attend_latent")
         eng, spec = self._eng, self._spec
         decode = self._row is None
         walk = eng._latent_walk(q_nope.shape[0], q_nope.shape[1], spec) \
@@ -360,6 +372,7 @@ class _LayerIO:
         import jax
         import jax.numpy as jnp
 
+        self._only(StateLayer, "read_state")
         arena = self._arenas[name][self._index]
         if self._row is None:
             return arena
@@ -370,6 +383,7 @@ class _LayerIO:
     def write_state(self, name: str, value) -> None:
         import jax
 
+        self._only(StateLayer, "write_state")
         arena = self._arenas[name][self._index]
         value = value.astype(arena.dtype)
         self._arenas[name][self._index] = value if self._row is None else \
@@ -391,9 +405,11 @@ class ServingEngine:
     disaggregated prefill — raises :class:`StateLayersUnsupported`) and
     ``DeepseekV3ForCausalLM`` (latent-attention layers: the prefix cache,
     speculation and fp8 pages work; int8 pages, TP / CP meshes, offload
-    and disaggregated prefill raise :class:`LatentLayersUnsupported`) are
-    served.  Greedy decoding — determinism is what makes eviction-replay
-    byte-exact."""
+    and disaggregated prefill raise :class:`LatentLayersUnsupported`) and
+    ``NemotronHForCausalLM`` (a block is ONE part: a Mamba-2 state layer, an
+    attention layer, or an expert layer that keeps nothing per request; it
+    has state layers, so what they refuse it refuses) are served.  Greedy
+    decoding — determinism is what makes eviction-replay byte-exact."""
 
     def __init__(self, model, *, max_batch: Optional[int] = None,
                  page_tokens: Optional[int] = None,
@@ -417,7 +433,8 @@ class ServingEngine:
                 "ServingEngine serves causal LMs that describe their layers "
                 "to it (serve_layers / serve_begin / serve_layer / "
                 "serve_end, see models/serve_protocol.py: LlamaForCausalLM, "
-                "GraniteHybridForCausalLM, DeepseekV3ForCausalLM); got "
+                "GraniteHybridForCausalLM, DeepseekV3ForCausalLM, "
+                "NemotronHForCausalLM); got "
                 + type(model).__name__)
         self.model = model
         # the model's layers as the engine sees them, and each layer's
@@ -428,11 +445,14 @@ class ServingEngine:
         paged = [sp for sp in self._layers
                  if isinstance(sp, (AttentionLayer, LatentAttentionLayer))]
         stl = [sp for sp in self._layers if isinstance(sp, StateLayer)]
-        if len(paged) + len(stl) != len(self._layers) or not paged:
+        # layers that keep nothing per request: walked, given no arena
+        bare = [sp for sp in self._layers if isinstance(sp, StatelessLayer)]
+        if len(paged) + len(stl) + len(bare) != len(self._layers) \
+                or not paged:
             raise TypeError(
                 "serve_layers() must name AttentionLayer / "
-                "LatentAttentionLayer / StateLayer entries, at least one of "
-                "them a layer that keeps pages")
+                "LatentAttentionLayer / StateLayer / StatelessLayer "
+                "entries, at least one of them a layer that keeps pages")
         if len({(a.kv_heads, a.head_dim) if isinstance(a, AttentionLayer)
                 else a for a in paged}) != 1:
             raise ValueError("every layer that keeps pages must keep rows "
@@ -445,6 +465,9 @@ class ServingEngine:
         counts = {StateLayer: 0, "paged": 0}
         self._family_index = []
         for sp in self._layers:
+            if isinstance(sp, StatelessLayer):      # in no family of arenas
+                self._family_index.append(None)
+                continue
             family = StateLayer if isinstance(sp, StateLayer) else "paged"
             self._family_index.append(counts[family])
             counts[family] += 1

@@ -139,7 +139,9 @@ def test_ssm_state_update_kernel_touches_live_rows_only(live):
 @pytest.mark.parametrize("shape,dtype,groups,reason", [
     ((4, 8, 16, 128), "float32", 1, None),
     ((4, 8, 16, 128), "bfloat16", 1, "state_dtype"),
-    ((4, 8, 16, 128), "float32", 2, "n_groups"),
+    ((4, 8, 16, 128), "float32", 2, None),
+    ((4, 8, 16, 128), "float32", 8, None),
+    ((4, 8, 16, 128), "float32", 3, "head_groups"),
     ((4, 8, 16, 96), "float32", 1, "state_tile"),
     ((4, 8, 16), "float32", 1, "rank")])
 def test_ssm_state_update_gate(shape, dtype, groups, reason):

@@ -292,6 +292,32 @@ class TestKernelsLower:
         assert compiled.memory_analysis().alias_size_in_bytes >= arena
         assert not _copies_of(text, f"f32[{R},{H},{P},{N}]")
 
+    def test_ssm_state_update_at_eight_groups_compiles_for_a_v5e(
+            self, one_chip):
+        """The same update at Nemotron-3-Nano's grouping (64 heads in 8
+        B/C groups): a grid step still moves 32 heads' state (1 MB) and
+        the 4 groups they lie in, one kernel, the arena aliased."""
+        from paddle_tpu.ops.pallas.ssm_state_update import (
+            _head_block, ssm_state_update, ssm_state_update_refusal)
+
+        R, H, P, N, G = 64, 64, 64, 128, 8
+        assert _head_block(H, P, N, G) == _head_block(H, P, N) == 32
+        shapes = [((R, H, P, N), jnp.float32), ((R,), jnp.bool_),
+                  ((R, H, P), BF16), ((R, H), jnp.float32), ((H,), BF16),
+                  ((R, G, N), BF16), ((R, G, N), BF16), ((H,), BF16)]
+        assert ssm_state_update_refusal(shapes[0][0], jnp.float32,
+                                        (R, G, N)) is None
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        compiled = _compile_uncached(
+            jax.jit(lambda *a: ssm_state_update(*a), donate_argnums=(0,)),
+            *args)
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            R * H * P * N * 4
+        assert not _copies_of(text, f"f32[{R},{H},{P},{N}]")
+
     @pytest.mark.parametrize("rows,width", [(128, 1), (16, 3)],
                              ids=["reasoning", "speculative"])
     def test_mla_paged_decode_attention_compiles_for_a_v5e(
@@ -327,12 +353,21 @@ class TestKernelsLower:
 
     @pytest.mark.parametrize("m,k,n", [(1024, 7168, 2048),
                                        (1024, 2048, 7168),
-                                       (4096, 7168, 2048)],
-                             ids=["decode-up", "decode-down", "prefill-up"])
+                                       (4096, 7168, 2048),
+                                       (384, 2688, 1856),
+                                       (384, 1856, 2688),
+                                       (3072, 2688, 1856)],
+                             ids=["decode-up", "decode-down", "prefill-up",
+                                  "two-matrix-decode-up",
+                                  "two-matrix-decode-down",
+                                  "two-matrix-prefill-up"])
     def test_moe_grouped_matmul_compiles_for_a_v5e(self, one_chip, m, k, n):
         """The grouped expert matmul at DeepSeek-V3's expert widths, 16
         experts held, the pairs of 128 decode rows and of a 512-token
-        prefill launch: one kernel, and no copy of the experts' weights."""
+        prefill launch, and at Nemotron-3-Nano's two-matrix experts (2688 x
+        1856, 64 rows x 6; 1856 is 14.5 lane registers: the chip keeps such
+        weights with 2688 minor and the kernel reads them as they lie): one
+        kernel, and no copy of the experts' weights."""
         from paddle_tpu.ops.pallas.grouped_matmul import (
             grouped_matmul, grouped_matmul_refusal)
 
@@ -621,3 +656,84 @@ class TestLatentLayerProgramsLower:
         assert compiled.memory_analysis().alias_size_in_bytes >= \
             eng._arena_bytes
         assert not _copies_of(compiled.as_text(), "bf16[513,128,640]")
+
+
+@pytest.mark.usefixtures("on_tpu")
+class TestOnePartABlockProgramsLower:
+    """The serving programs of a model whose blocks are ONE part each
+    (Nemotron-3-Nano's widths: a Mamba-2 block with 8 B/C groups, an expert
+    block of two-matrix experts that keeps nothing per request, an attention
+    block with 2 KV heads; 16 of 128 experts held, a small vocabulary)."""
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        from paddle_tpu.models import NemotronHConfig, NemotronHForCausalLM
+        from paddle_tpu.serving import ServingEngine
+
+        paddle.seed(0)
+        model = NemotronHForCausalLM(NemotronHConfig(
+            vocab_size=1024, num_hidden_layers=3,
+            hybrid_override_pattern="ME*", experts_held=(0, 16),
+            max_position_embeddings=8192))
+        model.eval()
+        model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+        return ServingEngine(model, max_batch=64, page_tokens=128,
+                             num_pages=65, max_pages_per_seq=4)
+
+    def test_both_programs_lower_with_their_kernels(self, engine):
+        eng = engine
+        pa, ba = eng._param_arrays()
+        R, MP, P = eng.max_batch, eng.max_pages_per_seq, eng.page_tokens
+        tables = jnp.zeros((R, MP), jnp.int32)
+        decode = tpu_text(
+            eng._decode_fn, pa, ba, eng._arenas,
+            jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
+            tables, jnp.ones((R,), jnp.int32), donate_argnums=(2,))
+        kernels = kernels_in(decode)
+        assert kernels["ssm_state_update"] == 1
+        assert kernels["paged_decode_attention"] == 1
+        assert kernels["moe_grouped_matmul"] == 2       # up and down: no gate
+        prefill = tpu_text(
+            eng._prefill_fn, pa, ba, eng._arenas,
+            jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
+            jnp.int32(P - 1), jnp.int32(3), jnp.int32(P - 7),
+            donate_argnums=(2,))
+        assert kernels_in(prefill)["ssm_state_update"] == 0
+        assert kernels_in(prefill)["moe_grouped_matmul"] == 2
+
+    def test_decode_compiles_for_a_v5e_and_copies_no_weight(self, engine,
+                                                            one_chip):
+        """Compiled for a described v5e: pages and row state aliased, one
+        state update, one page walk, two grouped matmuls, nothing falls
+        back, and neither the state arena nor an expert matrix is copied
+        (a copy of the 16 held up-projections would cost 160 MB of traffic
+        an expert block a step)."""
+        from paddle_tpu import telemetry
+        from paddle_tpu.jit import named_program
+        from paddle_tpu.serving.engine import DECODE_PROGRAM
+
+        eng = engine
+        pa, ba = eng._param_arrays()
+        R, MP = eng.max_batch, eng.max_pages_per_seq
+        args = (pa, ba, eng._arenas, jnp.zeros((R, 1), jnp.int32),
+                jnp.zeros((R,), jnp.int32), jnp.zeros((R, MP), jnp.int32),
+                jnp.ones((R,), jnp.int32))
+        args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), args)
+        before = telemetry.counters().get("kernel_fallback.total", 0)
+        compiled = _compile_uncached(
+            jax.jit(named_program(eng._decode_fn, DECODE_PROGRAM),
+                    donate_argnums=(2,)), *args)
+        text = compiled.as_text()
+        # one state layer of three blocks: the expert block keeps nothing
+        assert eng.state.nbytes == 64 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            eng._arena_bytes + eng.state.nbytes
+        assert _mosaic_calls(text, "ssm_state_update") == 1
+        assert _mosaic_calls(text, "paged_decode_attention") == 1
+        assert _mosaic_calls(text, "moe_grouped_matmul") == 2
+        assert not _copies_of(text, "f32[64,64,64,128]")
+        assert not _copies_of(text, "bf16[16,2688,1856]")
+        assert not _copies_of(text, "bf16[16,1856,2688]")
+        assert telemetry.counters().get("kernel_fallback.total", 0) == before
+
