@@ -179,11 +179,14 @@ define_flag("use_fused_rope", True,
             "(reference: fused_rotary_position_embedding.py surface).")
 define_flag("flash_block_q", 512,
             "Pallas flash attention query-block rows; the dispatcher uses "
-            "the largest power-of-two fraction that divides the sequence "
-            "(512 against 256: not measured on the current tree, see "
-            "PERF.md).")
+            "the largest sublane-aligned divisor of the sequence up to it. "
+            "512 x 512 measured on a v5e at s 2048, d 128 against every "
+            "pair of {256, 512, 1024}: 2.67 ms for the three kernels, "
+            "2.88-4.30 for the others (PERF.md, PR 35).")
 define_flag("flash_block_k", 512,
-            "Pallas flash attention key-block rows (see flash_block_q).")
+            "Pallas flash attention key-block rows (see flash_block_q): the "
+            "chunk of keys a product covers; a grid step holds up to 2048 "
+            "rows of the streamed operand and walks them in such chunks.")
 define_flag("use_decode_attention", True,
             "Dispatch single-token KV-cache decode attention to the fused "
             "Pallas kernel with the aliased in-place cache append "

@@ -94,6 +94,101 @@ class TestFlashAttention:
                                    np.asarray(ref, dtype=np.float32),
                                    rtol=2e-2, atol=2e-2)
 
+    # (sq, sk, hq, hkv, block_q, block_k, causal): a grid step walks the
+    # live chunks of its major tile and no others, whatever the tiles
+    TILE_CASES = {
+        "wide_q": (128, 128, 2, 2, 64, 32, True),
+        "wide_k": (128, 128, 2, 2, 32, 64, True),
+        # offset 64 is a multiple of neither tile: of query block 0's key
+        # chunks 0 is whole, 1 and 2 straddle the diagonal, 3 is dead
+        "offset_off_tile": (96, 160, 2, 2, 32, 40, True),
+        "gqa_4_to_1": (128, 128, 8, 2, 64, 64, True),
+        "gqa_offset": (64, 128, 4, 1, 32, 64, True),
+        "open_wide_q": (128, 64, 2, 2, 64, 32, False),
+        "open_wide_k": (64, 128, 4, 2, 32, 64, False),
+    }
+
+    @pytest.mark.parametrize("mode", ["forward", "grads"])
+    @pytest.mark.parametrize("case", sorted(TILE_CASES))
+    def test_tiles_match_reference(self, case, mode):
+        sq, sk, hq, hkv, bq, bk, causal = self.TILE_CASES[case]
+        q = _rand(20, (2, sq, hq, 32))
+        k = _rand(21, (2, sk, hkv, 32))
+        v = _rand(22, (2, sk, hkv, 32))
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=causal, block_q=bq,
+                                   block_k=bk, interpret=True)
+
+        def ref(q, k, v):
+            return sdpa_reference(q, k, v, is_causal=causal)
+
+        if mode == "forward":
+            np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                       np.asarray(ref(q, k, v)),
+                                       rtol=2e-5, atol=2e-5)
+            return
+        w = _rand(23, (2, sq, hq, 32))
+        gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+            q, k, v)
+        gr = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(
+            q, k, v)
+        for a, b_ in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("major", [32, 64])
+    @pytest.mark.parametrize("case", ["offset_off_tile", "gqa_4_to_1",
+                                      "open_wide_k"])
+    def test_several_major_tiles_a_row(self, monkeypatch, case, major):
+        """With more rows than a grid step holds, the streamed operand takes
+        several major tiles: the accumulators carry across them, and a dead
+        step names the resident one (``major`` 32: a chunk a step)."""
+        import importlib
+
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.ops.pallas.flash_attention"), "_MAJOR", major)
+        self.test_tiles_match_reference(case, "forward")
+        self.test_tiles_match_reference(case, "grads")
+
+    @pytest.mark.parametrize("bq,bk,sq,sk,n", [
+        (8, 8, 32, 32, 1), (8, 16, 64, 64, 2), (16, 8, 64, 64, 4),
+        (8, 8, 32, 40, 5), (8, 16, 56, 80, 1), (16, 8, 64, 88, 11),
+        (24, 16, 72, 80, 5), (8, 24, 32, 72, 3), (8, 8, 64, 64, 8)])
+    def test_live_chunks_against_the_elementwise_mask(self, bq, bk, sq, sk, n):
+        """A block is live where the element-wise mask keeps anything.  For
+        every resident block and every major tile of ``n`` chunks of the
+        streamed operand (and of one chunk: a grid step a block), the chunks
+        the kernels walk are the live ones, and the index-map clamps name
+        the last live key tile of a query block and the first live query
+        tile of a key block."""
+        from paddle_tpu.ops.pallas.flash_attention import (
+            _dead_q_chunks, _first_live_q, _last_live_k, _live_k_chunks)
+
+        offset = sk - sq
+        keep = np.arange(sq)[:, None] + offset >= np.arange(sk)[None, :]
+        nq, nk = sq // bq, sk // bk
+        live = np.array([[keep[iq * bq:(iq + 1) * bq,
+                               ik * bk:(ik + 1) * bk].any()
+                          for ik in range(nk)] for iq in range(nq)])
+        assert live.any(axis=1).all() and not live.all()
+        for iq in range(nq):
+            assert int(_last_live_k(iq, bq, bk, offset)) \
+                == np.flatnonzero(live[iq]).max()
+            for n_k in {1, n} if nk % n == 0 else {1}:
+                for ikm in range(nk // n_k):
+                    count = int(_live_k_chunks(iq, ikm, bq, bk, n_k, offset))
+                    assert (np.arange(n_k) < count).tolist() \
+                        == live[iq, ikm * n_k:(ikm + 1) * n_k].tolist()
+        for ik in range(nk):
+            assert int(_first_live_q(ik, bq, bk, offset)) \
+                == np.flatnonzero(live[:, ik]).min()
+            for n_q in {1, n} if nq % n == 0 else {1}:
+                for iqm in range(nq // n_q):
+                    dead = int(_dead_q_chunks(ik, iqm, bq, bk, n_q, offset))
+                    assert (np.arange(n_q) >= dead).tolist() \
+                        == live[iqm * n_q:(iqm + 1) * n_q, ik].tolist()
+
 
 class TestFusedRMSNorm:
     def _ref(self, x, w, eps=1e-6):
