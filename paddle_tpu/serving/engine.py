@@ -101,7 +101,7 @@ from .kv_pool import LatentLayersUnsupported, OffloadPool, PagedKVPool, \
 from .kv_quant import (default_fp8_scale, dequantize_kv, dequantize_kv_fp8,
                        kv_cache_dtype, kv_scale_page_bytes, layer_page_bytes,
                        quantize_kv, quantize_kv_fp8)
-from .metrics import SLOMeter
+from .metrics import Cycle, SLOMeter
 from .prefix_cache import PrefixCache
 from ..models.serve_protocol import AttentionLayer, LatentAttentionLayer, \
     StateLayer, StatelessLayer
@@ -485,6 +485,11 @@ class ServingEngine:
         self.pool = PagedKVPool(N, P)
         self._now = now if now is not None else time.monotonic
         self.meter = SLOMeter(now=self._now)
+        # the cycle in progress (metrics.Cycle), on the meter's clock, and
+        # the requests the last delivering flush handed a token
+        self._cycle = Cycle(time.monotonic_ns if now is None else
+                            (lambda: int(round(now() * 1e9))))
+        self._cycle_rids: frozenset = frozenset()
         self.admission = admission if admission is not None else \
             AdmissionController(max_queue=max_queue, now=self._now)
         # journal_ship: optional ``ship(seq, data)`` — a fleet replica
@@ -1073,25 +1078,29 @@ class ServingEngine:
         After ``PADDLE_TPU_SERVE_MAX_STEP_FAILURES`` consecutive failures
         the error propagates."""
         self.steps_total += 1
-        with _span("serve.step", step=self.steps_total,
-                   active=len(self._active), queued=len(self._queue)):
-            try:
-                did_work = self._step_inner()
-            except OSError as e:
-                self._step_failures += 1
-                self.admission.breaker.note_failure()
-                _event("serve_step_error", type(e).__name__,
-                       error=repr(e)[:200],
-                       consecutive=self._step_failures)
-                _bump("serving.step_failures_total")
-                if self._step_failures >= self._max_step_failures:
-                    raise
-                return
-            if did_work:
-                self._step_failures = 0
-                self.admission.breaker.note_success()
-                if self.first_step_wall is None:
-                    self.first_step_wall = time.time()
+        self._cycle.enter("admit")      # what ends here lay outside step()
+        try:
+            with _span("serve.step", step=self.steps_total,
+                       active=len(self._active), queued=len(self._queue)):
+                try:
+                    did_work = self._step_inner()
+                except OSError as e:
+                    self._step_failures += 1
+                    self.admission.breaker.note_failure()
+                    _event("serve_step_error", type(e).__name__,
+                           error=repr(e)[:200],
+                           consecutive=self._step_failures)
+                    _bump("serving.step_failures_total")
+                    if self._step_failures >= self._max_step_failures:
+                        raise
+                    return
+                if did_work:
+                    self._step_failures = 0
+                    self.admission.breaker.note_success()
+                    if self.first_step_wall is None:
+                        self.first_step_wall = time.time()
+        finally:
+            self._cycle.enter("outside")
 
     def _undelivered(self) -> bool:
         """Tokens or journal records still awaiting a successful flush."""
@@ -1107,7 +1116,12 @@ class ServingEngine:
             sp.note(admitted=len(self._active) - occupied)
         did_work = self._undelivered()   # a retried flush is real work:
         # succeeding must reset the failure streak and close the breaker
-        for r in [r for r in self._active.values() if not r.generated]:
+        cy = self._cycle
+        fresh = [r for r in self._active.values() if not r.generated]
+        if fresh:
+            cy.enter("prefill")
+        for r in fresh:
+            cy.prefill_requests += 1
             with _span("serve.prefill", rid=r.rid, trace=r.trace_id or "",
                        prompt_tokens=len(r.prompt),
                        cached_tokens=r.cached_tokens) as sp:
@@ -1116,6 +1130,7 @@ class ServingEngine:
             did_work = True
             self._retire_if_done(r)
         if self._active:
+            cy.enter("decode")
             self._decode_step()
             did_work = True
         self._flush_delivery()
@@ -1460,6 +1475,8 @@ class ServingEngine:
             logits = self._run_prefill(
                 jnp.asarray(chunk), jnp.int32(c * P), table,
                 jnp.int32(take), jnp.int32(row), jnp.int32(len(part)))
+            self._cycle.prefill_launches += 1
+            self._cycle.prefill_tokens += len(part)
             c += w
         return logits, len(widths)
 
@@ -1651,6 +1668,7 @@ class ServingEngine:
                     self._retire_if_done(r)
                 return
             stepped, tokens, n_tok, positions, tables, drafts = batch
+            self._cycle.decode_rows += len(stepped)
             # live_pages: what the rows' queries can see (idle rows: none),
             # the decode kernel's own rule; table_pages: what the padded
             # tables hold
@@ -1815,24 +1833,38 @@ class ServingEngine:
         """Durability barrier, then client emission: journal records hit
         disk BEFORE any of the tokens they cover reach the sink.  On a
         flush failure everything stays pending — the step-failure path
-        retries, and a crash instead re-generates the tokens exactly."""
-        per_rid: Dict[int, int] = {}
-        for rid, _idx, _tok in self._pending_delivery:
-            per_rid[rid] = per_rid.get(rid, 0) + 1
+        retries, and a crash instead re-generates the tokens exactly.
+
+        A flush that delivers tokens ends the cycle (:class:`Cycle`): WHEN
+        tokens became client-visible and what stood between this flush and
+        the last is on the ``serve.deliver`` span (counts only: its stamps
+        are the profiler's) and, for the longest cycles, in the meter; the
+        journal holds the per-token detail."""
+        cy = self._cycle
+        cy.enter("deliver")
+        per_rid: Dict[int, int] = {}    # the lowest index a request is handed
+        for rid, idx, _tok in self._pending_delivery:
+            per_rid.setdefault(rid, idx)
         with _span("serve.deliver", requests=len(per_rid),
-                   tokens=len(self._pending_delivery)):
+                   tokens=len(self._pending_delivery)) as sp:
             if self.journal is not None:
                 self.journal.flush()
             if self._on_token is not None:
                 for rid, idx, tok in self._pending_delivery:
                     self._on_token(rid, idx, tok)
-            # one flight-recorder instant per request per flush (not per
-            # token): it shows WHEN tokens became client-visible, the
-            # journal holds the per-token detail
-            for rid, n in per_rid.items():
-                _event("serve_deliver", str(rid), tokens=n,
-                       trace=self.meter.trace_of(rid))
             self._pending_delivery.clear()
+            if not per_rid:
+                sp.note(gaps=0)         # nothing delivered: the cycle goes on
+                return
+            waited = [rid for rid, idx in per_rid.items() if idx >= 1]
+            cy.gaps = len(waited)
+            cy.gaps_long = sum(rid not in self._cycle_rids for rid in waited)
+            cy.first_tokens = len(per_rid) - len(waited)
+            self._cycle_rids = frozenset(per_rid)
+            sp.note(seq=cy.seq, compiled=int(cy.compiled), **cy.counts())
+            cy.enter("deliver")         # the cycle ends here
+            self.meter.cycle_closed(cy, step=self.steps_total)
+            cy.begin()
 
     def recover(self) -> dict:
         """Replay the journal into this (fresh) engine after a crash:
@@ -2354,6 +2386,7 @@ class ServingEngine:
 
         scope = gspmd_program() if self._mesh is not None \
             else contextlib.nullcontext()
+        self._cycle.compiled = True
         with _span("serve.compile", program=name), _SWAP_LOCK, scope:
             return jax.jit(named_program(fn, name), donate_argnums=(2,)) \
                 .lower(*args).compile()
@@ -2583,5 +2616,7 @@ class ServingEngine:
         tokens[0, :len(prompt)] = np.asarray(prompt, np.int32)
         tbl = np.full((1, nc_pad), TRASH_PAGE, np.int32)
         tbl[0, :n_chunks] = np.asarray(pages[:n_chunks], np.int32)
+        self._cycle.prefill_launches += 1
+        self._cycle.prefill_tokens += len(prompt)
         return self._run_cp_prefill(jnp.asarray(tokens), jnp.asarray(tbl),
                                     jnp.int32(len(prompt) - 1))

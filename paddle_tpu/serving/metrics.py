@@ -21,10 +21,17 @@ are narrated into the flight recorder (``serve_admit`` / ``serve_evict`` /
 ``serve_shed`` / ``serve_reject`` / ``serve_finish`` events) so a hung or
 thrashing server dumps its recent scheduling story the same way a hung
 train step dumps its collectives.
+
+The gap between two of a user's tokens is accounted where it is made: the
+engine fills one :class:`Cycle` between two flushes that delivered tokens,
+and the meter keeps the longest of them (``summary()["longest_cycles"]``),
+so that a run says of its worst gaps whether they lay in a prompt's
+prefill, in the decode step, in the delivery, or outside ``step()``.
 """
 
 from __future__ import annotations
 
+import heapq
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -34,7 +41,7 @@ from ..telemetry import record_event
 from ..telemetry.aggregator import Histogram
 from ..telemetry.runtime import bump, identity, set_gauge
 
-__all__ = ["RequestClock", "SLOMeter", "FleetMeter"]
+__all__ = ["RequestClock", "Cycle", "SLOMeter", "FleetMeter"]
 
 
 def default_slo_window() -> int:
@@ -98,6 +105,67 @@ def _pct(xs: List[float], q: float) -> Optional[float]:
     return s[idx]
 
 
+# the longest prefill any traffic puts ahead of a step is about half a
+# second (a 4 096-token prompt): a cycle over a second is a stall and not a
+# schedule.  A constant, not a setting.
+STALL_NS = 1_000_000_000
+LONGEST_CYCLES = 8
+
+
+class Cycle:
+    """The engine's account of one cycle: the time from one
+    ``_flush_delivery`` that delivered tokens to the next that does.  Every
+    request that gets a token in both waits exactly that long between the
+    two, so the account of a cycle is the make-up of its users' gaps.
+
+    ``enter(part)`` is called at the boundaries ``step()`` already has
+    spans on: the time since the last boundary goes to the part that was
+    running, and ``part`` runs from here.  So the five parts sum to the
+    cycle's length, and what a step spends between two phases counts with
+    the phase before it.  ``outside`` is the time between two ``step()``
+    calls: the caller, the load generator, a host that froze.  Waiting for
+    the device (``serve.decode.to_host``) is inside ``decode``.
+
+    The counts are made where the work happens: ``gaps`` the requests whose
+    inter-token gap the closing flush ended (its lowest delivered index is
+    >= 1), ``gaps_long`` those of them that got no token in the flush
+    before (a row that sat out a step: an eviction's replay, a recall),
+    ``first_tokens`` the requests whose first token it delivered,
+    ``prefill_requests`` / ``prefill_tokens`` / ``prefill_launches`` the
+    prompts prefilled, the prompt tokens actually computed (a prefix-cached
+    page is not) and the program launches that took, ``decode_rows`` the
+    rows the decode steps stepped; ``compiled`` says that a program
+    compiled inside the cycle."""
+
+    PARTS = ("outside", "admit", "prefill", "decode", "deliver")
+    COUNTS = ("gaps", "gaps_long", "first_tokens", "prefill_requests",
+              "prefill_tokens", "prefill_launches", "decode_rows")
+    __slots__ = ("_now_ns", "seq", "part", "t", "ns", "compiled") + COUNTS
+
+    def __init__(self, now_ns=time.monotonic_ns):
+        self._now_ns = now_ns
+        self.seq = 0
+        self.part = "outside"
+        self.t = now_ns()
+        self.begin()
+
+    def begin(self) -> None:
+        """The next cycle starts where the last boundary was."""
+        self.seq += 1
+        self.ns = dict.fromkeys(self.PARTS, 0)
+        self.compiled = False
+        for name in self.COUNTS:
+            setattr(self, name, 0)
+
+    def enter(self, part: str) -> None:
+        t = self._now_ns()
+        self.ns[self.part] += t - self.t
+        self.t, self.part = t, part
+
+    def counts(self) -> Dict[str, int]:
+        return {name: getattr(self, name) for name in self.COUNTS}
+
+
 class SLOMeter:
     """Aggregates :class:`RequestClock` milestones into p50/p99 SLO lines
     over a bounded window and exports live gauges through telemetry."""
@@ -158,6 +226,12 @@ class SLOMeter:
         # complete traced span chain (counters, not clocks — clocks are
         # dropped at finish)
         self._trace_complete = 0
+        # the cycle account (:class:`Cycle`): a min-heap of the longest
+        # cycles in which somebody waited and nothing compiled
+        self.cycles_total = 0
+        self.cycles_compiled = 0
+        self.cycles_over_1s = 0
+        self._longest_cycles: List[tuple] = []
 
     def clock(self, rid) -> RequestClock:
         return self._clocks[rid]
@@ -226,6 +300,33 @@ class SLOMeter:
     def prefill_launched(self, launches: int) -> None:
         """One prompt's prefill took ``launches`` program launches."""
         self.prefill_launches_total += int(launches)
+
+    def cycle_closed(self, cy: Cycle, *, step: int) -> None:
+        """A flush delivered tokens and so ended ``cy``.  A cycle in which
+        a program compiled is counted apart (the warm-up's; none inside a
+        measured window); one that closed nobody's gap (an empty engine
+        asleep until the next arrival) is nobody's wait.  Of the rest the
+        ``LONGEST_CYCLES`` longest are kept, and those over a second
+        counted."""
+        self.cycles_total += 1
+        if cy.compiled:
+            self.cycles_compiled += 1
+            return
+        if cy.gaps <= 0:
+            return
+        length = sum(cy.ns.values())
+        if length > STALL_NS:
+            self.cycles_over_1s += 1
+        heap = self._longest_cycles
+        if len(heap) == LONGEST_CYCLES and length <= heap[0][0]:
+            return
+        entry = {"seq": cy.seq, "steps_total": step,
+                 "end_s": cy.t / 1e9, "ms": length / 1e6}
+        entry.update((part + "_ms", ns / 1e6) for part, ns in cy.ns.items())
+        entry.update(cy.counts(), compiled=False)
+        keep = heapq.heappush if len(heap) < LONGEST_CYCLES \
+            else heapq.heapreplace
+        keep(heap, (length, cy.seq, entry))     # seq breaks a tie of lengths
 
     def decode_logits_fetched(self) -> None:
         """Someone read the decode logits: one whole array came to the
@@ -462,7 +563,16 @@ class SLOMeter:
     # -- rollup ------------------------------------------------------------
     def summary(self) -> Dict[str, object]:
         """SLO rollup (milliseconds); percentiles over the bounded window,
-        totals exact."""
+        totals exact.  The cycle account: ``cycles_total`` (flushes that
+        delivered tokens), ``cycles_compiled`` (of them, those in which a
+        program compiled), ``cycles_over_1s`` (of the rest, those in which
+        somebody waited over a second: stalls) and ``longest_cycles`` (the
+        :data:`LONGEST_CYCLES` longest of the rest in which somebody
+        waited, longest first; each with ``seq``, ``steps_total``, the
+        monotonic second it ended ``end_s``, its length ``ms``, the five
+        parts ``outside_ms`` / ``admit_ms`` / ``prefill_ms`` /
+        ``decode_ms`` / ``deliver_ms`` that sum to it, and :class:`Cycle`'s
+        counts)."""
         ttft = [t * 1e3 for (_, t, _, _, _) in self._window if t is not None]
         tpot = [t * 1e3 for (_, _, t, _, _) in self._window if t is not None]
         lat = [t * 1e3 for (_, _, _, t, _) in self._window if t is not None]
@@ -516,6 +626,11 @@ class SLOMeter:
                 self.kv_recall_bytes_per_token(), 3),
             "tpot_ema_ms": _r(None if self.tpot_ema_s is None
                               else self.tpot_ema_s * 1e3),
+            "cycles_total": self.cycles_total,
+            "cycles_compiled": self.cycles_compiled,
+            "cycles_over_1s": self.cycles_over_1s,
+            "longest_cycles": [dict(entry) for _, _, entry in
+                               sorted(self._longest_cycles, reverse=True)],
         }
 
 

@@ -20,13 +20,16 @@ host side is first-class because XLA owns the device):
   arms), dumped to JSON on demand / unhandled exception / watchdog hang.
   The profiler merges these events onto its chrome-trace timeline under the
   ``telemetry`` category.  The serving engine's ``serve_submit`` …
-  ``serve_deliver`` … ``serve_finish`` events are INSTANTS on
-  ``perf_counter_ns`` for crash dumps and ``trace_coverage``: a loaded
-  engine overwrites the ring within about a second, and they never reach
-  the device trace.  They are not the source of per-layer time — that is
+  ``serve_finish`` events are INSTANTS on ``perf_counter_ns`` for crash
+  dumps and ``trace_coverage``: a request's lifecycle (a handful of events
+  a request; none a token or a flush, so a loaded engine's ring holds the
+  recent requests' whole stories), and they never reach the device trace.
+  They are not the source of per-layer time — that is
   :func:`paddle_tpu.profiler.span`, whose ``serve.*`` / ``train.*`` scopes
   lie on the profiler's clock beside the device lines and write nothing
-  here.
+  here.  WHEN tokens became visible is on the ``serve.deliver`` span, and
+  what the slowest deliveries waited for is in
+  ``SLOMeter.summary()["longest_cycles"]``.
 
 Env vars: ``PADDLE_TPU_TELEMETRY=0`` disables recording;
 ``PADDLE_TPU_TELEMETRY_DIR`` makes StepMeters write JSONL there by default;

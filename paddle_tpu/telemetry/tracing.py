@@ -7,9 +7,12 @@ journal submit record, in every depot frame the journal ships, in the
 hand-back descriptor a draining replica returns, and in the re-submit a
 fail-over makes to a survivor — so the spans a request leaves behind
 (``serve_submit → serve_route → serve_admit → serve_first_token[prefill]
-→ serve_token[decode] → serve_deliver → serve_finish``, plus
-``serve_evict`` / ``serve_replay`` detours) share one id no matter how
-many processes, evictions, fencings or replays the request lived through.
+→ serve_finish``, plus ``serve_evict`` / ``serve_replay`` detours) share
+one id no matter how many processes, evictions, fencings or replays the
+request lived through.  The chain is a request's lifecycle: no event a
+token or a flush (the ``serve.deliver`` profiler span and the meter's
+``longest_cycles`` say when tokens became visible and what they waited
+for), so the ring keeps whole stories under load.
 
 Spans are ordinary flight-recorder events with a ``trace`` key: no new
 storage, no sampling daemon — the existing ring, dumps and the profiler's

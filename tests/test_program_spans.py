@@ -40,7 +40,7 @@ SPANS = {
     "serve.decode.dispatch": ("serve.decode", set()),
     "serve.decode.to_host": ("serve.decode", {"bytes"}),
     "serve.decode.sample": ("serve.decode", set()),
-    "serve.deliver": ("serve.step", {"requests", "tokens"}),
+    "serve.deliver": ("serve.step", {"requests", "tokens", "gaps"}),
     "serve.compile": (None, {"program"}),
     "train.step": (None, {"step"}),
     "train.marshal": ("train.step", set()),
@@ -232,6 +232,37 @@ def test_serving_span_counts_match_steps_and_requests(recorded):
     assert eng.meter.summary()["decode_logits_fetches"] == 0
     assert sum(s[2]["tokens"] for s in spans["serve.deliver"]) == \
         len(PROMPTS) * NEW_TOKENS
+
+
+def test_deliver_span_carries_the_cycle_account(recorded):
+    """Counts only: the durations are the spans' own stamps."""
+    eng = recorded["engine"]
+    flushes = [s[2] for s in recorded["spans"]["serve.deliver"]]
+    closing = [f for f in flushes if f["requests"] > 0]
+    account = {"seq", "gaps", "gaps_long", "first_tokens",
+               "prefill_requests", "prefill_tokens", "prefill_launches",
+               "decode_rows", "compiled"}
+    assert all(account <= set(f) for f in closing)
+    assert all(f["gaps"] == 0 for f in flushes if f["requests"] == 0)
+    assert [f["seq"] for f in closing] == list(range(1, len(closing) + 1))
+    assert len(closing) == eng.meter.summary()["cycles_total"]
+    total = {k: sum(f[k] for f in closing) for k in account}
+    assert total["first_tokens"] == total["prefill_requests"] == len(PROMPTS)
+    assert total["prefill_tokens"] == sum(PROMPTS.values())
+    assert total["prefill_launches"] == \
+        eng.meter.summary()["prefill_launches"]
+    assert total["gaps_long"] == 0
+    assert all(f["gaps"] + f["first_tokens"] == f["requests"]
+               for f in closing)
+    # a token each is a first token, a gap a cycle closed, or came with the
+    # one before it (index 1 in the flush of index 0)
+    assert total["gaps"] + sum(f["tokens"] - f["requests"] for f in closing) \
+        + len(PROMPTS) == len(PROMPTS) * NEW_TOKENS
+    assert total["decode_rows"] == sum(
+        s[2]["rows"] for s in recorded["spans"]["serve.decode"])
+    # the warm-up: both programs compile in the first cycle, none later
+    assert [f["compiled"] for f in closing] == [1] + [0] * (len(closing) - 1)
+    assert eng.meter.summary()["cycles_compiled"] == 1
 
 
 def test_decode_span_counts_live_pages_as_the_pool_does(recorded):

@@ -287,7 +287,14 @@ class TestEngineTracePropagation:
         assert trace_coverage(evs, finished_rids=[rid0, rid1]) == 1.0
         kinds = {e["kind"] for e in spans(evs, tid1)}
         assert set(REQUIRED_SPANS) <= kinds
-        assert "serve_deliver" in kinds   # the client-visible flush span
+        # no instant a request a flush any more (they used to evict what
+        # follows): the whole lifecycle chain of the finished request is
+        # still in the ring after the run, in order
+        chain = [e["kind"] for e in spans(evs, tid1)
+                 if e["name"] == str(rid1)]
+        assert chain == ["serve_submit", "serve_admit", "serve_first_token",
+                         "serve_finish"]
+        assert "serve_deliver" not in {e["kind"] for e in evs}
         finish = {e["name"]: e["trace"] for e in evs
                   if e["kind"] == "serve_finish"}
         assert finish[str(rid1)] == tid1
