@@ -396,12 +396,13 @@ class DeepseekV3ForCausalLM(nn.Layer):
 
     def serve_begin(self, tokens, positions):
         """``tokens`` [R, s] ids, ``positions`` [R] the absolute position of
-        each row's first token: the embeddings, and the rows' rotary cos /
-        sin [R, s, rope] that every layer shares."""
-        s = tokens.shape[1]
-        pos_ids = positions[:, None] + jnp.arange(s)[None, :]
+        each row's first token, or [R, s] every token's: the embeddings,
+        and the rows' rotary cos / sin [R, s, rope] that every layer
+        shares."""
+        if positions.ndim == 1:
+            positions = positions[:, None] + jnp.arange(tokens.shape[1])
         return self.model.embed_tokens(tokens), \
-            rope_cos_sin(self.config, pos_ids)
+            rope_cos_sin(self.config, positions)
 
     def serve_layer(self, i, x, shared, io):
         layer = self.model.layers[i]
@@ -416,8 +417,7 @@ class DeepseekV3ForCausalLM(nn.Layer):
         if not layer.is_moe:
             return x + layer.mlp(xin)
         # idle rows and a launch's padding are routed nowhere
-        y = layer.mlp(xin,
-                      valid=jnp.arange(s)[None, :] < io.n_valid[:, None])
+        y = layer.mlp(xin, valid=io.valid)
         # pairs computed on the held experts, how many of them got one,
         # and the fullest; every token's chosen experts stay on the device
         # for whoever holds the routing to a reference

@@ -403,13 +403,14 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
 
     def serve_begin(self, tokens, positions):
         """``tokens`` [R, s] ids, ``positions`` [R] the absolute position of
-        each row's first token: the embeddings, and the rows' rotary tables
-        that every layer shares."""
+        each row's first token, or [R, s] every token's: the embeddings,
+        and the rows' rotary tables that every layer shares."""
         base = self.llama
         s = tokens.shape[1]
         cos, sin = base.rope_cos._value, base.rope_sin._value
-        pos_ids = jnp.clip(positions[:, None] + jnp.arange(s)[None, :],
-                           0, cos.shape[0] - 1)          # [R, s]
+        if positions.ndim == 1:
+            positions = positions[:, None] + jnp.arange(s)[None, :]
+        pos_ids = jnp.clip(positions, 0, cos.shape[0] - 1)      # [R, s]
         cos_s = jnp.take(cos, pos_ids, axis=0)[:, :, None, :]
         sin_s = jnp.take(sin, pos_ids, axis=0)[:, :, None, :]
         return base.embed_tokens(tokens), (cos_s, sin_s)

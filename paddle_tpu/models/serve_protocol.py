@@ -32,13 +32,24 @@ engine a count made inside the program (it rides the step's one fetch);
 ``io.keep(name, value)`` leaves an array on the device beside the decode
 step's logits, for a tolerance harness to fetch (no step does);
 ``io.read_state(name)`` / ``io.write_state(name, value)`` read and write the
-rows' slots of one state array; ``io.n_valid [R]`` says how many of a row's
-tokens are real and ``io.live [R]`` which rows step at all.  A layer may
+rows' slots of one state array; ``io.valid [R, s]`` says which tokens are
+real, ``io.n_valid [R]`` how many of a row's tokens are real and
+``io.live [R]`` which rows step at all.  A layer may
 ask only what its kind keeps: ``attend`` is an :class:`AttentionLayer`'s,
 ``attend_latent`` a :class:`LatentAttentionLayer`'s, the state calls a
 :class:`StateLayer`'s, and any of them from another kind raises a
 ``TypeError`` that names the call and the kind; ``note``, ``keep``,
-``n_valid`` and ``live`` are every kind's.
+``valid``, ``n_valid`` and ``live`` are every kind's.
+
+Where no layer is a :class:`StateLayer`, the engine runs a step's decode
+rows inside its last prefill launch where that launch is of the narrowest
+width (``ServingEngine.rides_prefill``): the model then sees ONE row, the
+prompt's tokens followed by the decode rows' ``[R, S]`` tokens, and
+``serve_begin`` gets ``positions`` [1, s], every
+token's own, in place of [R] row starts.  ``attend`` / ``attend_latent``
+split the row by part themselves; a layer that keeps nothing reads
+``io.valid`` there (``io.n_valid`` and ``io.live`` are None: the real
+tokens of that row are no prefix).
 
 It lives under ``models/`` so that a model need not import ``serving/``.
 """

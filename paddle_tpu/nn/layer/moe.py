@@ -98,6 +98,7 @@ def grouped_matmul_kernel(rows: int, w_up_shape, w_down_shape, dtype):
     if mode is None:
         return None
     kind, _, interpret = mode
+    rows = _whole_tiles(rows)
     reason = "hybrid_mesh" if kind != "local" else (
         grouped_matmul_refusal((rows, w_up_shape[1]), w_up_shape, dtype,
                                interpret=interpret)
@@ -109,6 +110,16 @@ def grouped_matmul_kernel(rows: int, w_up_shape, w_down_shape, dtype):
 
     kernel_fallback(KERNEL_NAME, reason, rows=rows)
     return None
+
+
+def _whole_tiles(rows: int) -> int:
+    """``rows`` pairs as the kernel takes them: more than one row tile's
+    worth padded to whole tiles (a serving launch's pairs need not be: a
+    prompt's tokens and the decode rows riding beside them).  The padding
+    lies past every group and is computed by no tile."""
+    from ...ops.pallas.grouped_matmul import TILE_M
+
+    return rows if rows <= TILE_M else -(-rows // TILE_M) * TILE_M
 
 
 def _ragged_dot(x, w, group_sizes):
@@ -144,7 +155,12 @@ def grouped_matmul(x, w, group_sizes, kernel: Optional[str] = None):
     through ``ragged_dot``), else ``jax.lax.ragged_dot``."""
     if kernel is None:
         return _ragged_dot(x, w, group_sizes)
-    return _kernel_matmul(x, w, group_sizes, kernel == "interpret")
+    m = x.shape[0]
+    pad = _whole_tiles(m) - m
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    y = _kernel_matmul(x, w, group_sizes, kernel == "interpret")
+    return y[:m] if pad else y
 
 
 # jitted: the expert layers of a program share one trace, and an eager call

@@ -82,7 +82,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -279,25 +279,49 @@ def check_decode_donation(compiled, arena_bytes: int,
     return report
 
 
+class _Ride(NamedTuple):
+    """The decode part of a riding prefill launch
+    (:meth:`ServingEngine._prefill_fn`).  The model sees ONE row: the
+    prompt's ``split`` tokens, then the decode rows' ``[R, width]`` tokens
+    token-major; ``positions`` / ``valid`` [1, split + R * width] are every
+    token's position and whether it is real, ``head`` the tokens whose
+    logits are wanted (the prompt's last, then every decode token).
+    ``tables`` [R, MP], ``starts`` [R] and ``n_tok`` [R] are the decode
+    program's own inputs."""
+    split: int
+    width: int
+    tables: object
+    starts: object
+    n_tok: object
+    positions: object
+    valid: object
+    head: object
+
+
 class _LayerIO:
     """The engine's side of ONE layer inside a traced program: what
     ``model.serve_layer(i, x, shared, io)`` may touch.  An attention layer
     calls :meth:`attend` (a latent one :meth:`attend_latent`); a state
     layer reads and writes its rows' slots (:meth:`read_state` /
     :meth:`write_state`); every kind, a layer that keeps nothing too, may
-    look at ``n_valid`` [R] (tokens of each row that are real) and ``live``
-    [R] (rows that step at all) and call :meth:`note` / :meth:`keep`.  A
-    call that is another kind's raises a ``TypeError`` that names it.  The
-    updated arenas land in the program's result."""
+    look at ``valid`` [R, s] (tokens that are real), ``n_valid`` [R]
+    (tokens of each row that are real) and ``live`` [R] (rows that step at
+    all) and call :meth:`note` / :meth:`keep`.  In a riding launch
+    (``ride``: :class:`_Ride`) the one row's real tokens are no prefix, so
+    ``n_valid`` and ``live`` are None there.  A call that is another
+    kind's raises a ``TypeError`` that names it.  The updated arenas land
+    in the program's result."""
 
     def __init__(self, engine, spec, arenas, index, tables, positions,
-                 n_tok, n_valid, row, fresh, notes, kept):
+                 n_tok, n_valid, row, fresh, notes, kept, valid=None,
+                 ride=None):
         self._eng, self._spec, self._arenas = engine, spec, arenas
         self._index, self._notes, self._kept = index, notes, kept
         self._tables, self._positions, self._n_tok = tables, positions, n_tok
-        self._row, self._fresh = row, fresh
-        self.n_valid = n_valid
-        self.live = n_valid > 0
+        self._row, self._fresh, self._ride = row, fresh, ride
+        self.valid = valid
+        self.n_valid = None if ride is not None else n_valid
+        self.live = None if ride is not None else n_valid > 0
 
     def _only(self, kind, call: str) -> None:
         if not isinstance(self._spec, kind):
@@ -306,21 +330,58 @@ class _LayerIO:
                 f"described itself as {type(self._spec).__name__}, which "
                 f"keeps no such memory")
 
+    def _parts(self, *xs):
+        """Each of ``xs`` [1, split + R * width, ...] of a riding launch as
+        its prompt part [1, split, ...] and its decode part [R, width,
+        ...]."""
+        ride = self._ride
+        rows = ride.n_tok.shape[0]
+        return [(x[:, :ride.split],
+                 x[0, ride.split:].reshape((rows, ride.width) + x.shape[2:]))
+                for x in xs]
+
+    @staticmethod
+    def _join(prompt, decode):
+        """The two parts' outputs as the one row again."""
+        import jax.numpy as jnp
+
+        return jnp.concatenate(
+            [prompt, decode.reshape((1, -1) + decode.shape[2:])], axis=1)
+
     def attend(self, q, k, v):
         """Scatter this step's ``k`` / ``v`` [R, s, kv, d] into the layer's
-        pages and attend ``q`` [R, s, h, d] over each row's pages."""
+        pages and attend ``q`` [R, s, h, d] over each row's pages.  A
+        riding launch attends its prompt part as the prefill program does
+        and its decode part as the decode program does."""
         self._only(AttentionLayer, "attend")
-        eng, spec = self._eng, self._spec
-        walk = eng._page_walk(q.shape[0], q.shape[1], spec) \
-            if self._row is None else None      # the decode program
-        pages = {key: self._arenas[key][self._index] for key in eng._planes}
-        out, new = eng._attend(
-            q, k, v, pages, self._tables, self._positions, self._n_tok,
-            walk=None if walk is None else tuple(walk.items()),
-            scale=spec.scale)
-        for key, arena in new.items():
+        pages = {key: self._arenas[key][self._index]
+                 for key in self._eng._planes}
+        if self._ride is None:
+            out, pages = self._attend(q, k, v, pages, self._tables,
+                                      self._positions, self._n_tok,
+                                      decode=self._row is None)
+        else:
+            ride = self._ride
+            (qp, qd), (kp, kd), (vp, vd) = self._parts(q, k, v)
+            out_p, pages = self._attend(qp, kp, vp, pages, self._tables,
+                                        self._positions, self._n_tok,
+                                        decode=False)
+            out_d, pages = self._attend(qd, kd, vd, pages, ride.tables,
+                                        ride.starts, ride.n_tok, decode=True)
+            out = self._join(out_p, out_d)
+        for key, arena in pages.items():
             self._arenas[key][self._index] = arena
         return out
+
+    def _attend(self, q, k, v, pages, tables, positions, n_tok, *, decode):
+        eng, spec = self._eng, self._spec
+        walk = eng._page_walk(q.shape[0], q.shape[1], spec) \
+            if decode else None
+        out, new = eng._attend(
+            q, k, v, pages, tables, positions, n_tok,
+            walk=None if walk is None else tuple(walk.items()),
+            scale=spec.scale)
+        return out, dict(pages, **new)
 
     def attend_latent(self, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv):
         """Scatter this step's latent rows (``c_kv`` [R, s, latent],
@@ -330,19 +391,37 @@ class _LayerIO:
         [latent, h, v] are the layer's up-projections: the decode program
         absorbs them into the query and the output and walks the latent
         rows as they lie; the prefill program expands the row's pages to
-        per-head K/V inside the program.  Returns [R, s, h, v]."""
+        per-head K/V inside the program; a riding launch, each part as its
+        own program does.  Returns [R, s, h, v]."""
         self._only(LatentAttentionLayer, "attend_latent")
-        eng, spec = self._eng, self._spec
-        decode = self._row is None
-        walk = eng._latent_walk(q_nope.shape[0], q_nope.shape[1], spec) \
-            if decode else None
-        out, pages = eng._attend_latent(
-            q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
-            self._arenas["c"][self._index], self._tables, self._positions,
-            self._n_tok, spec=spec, absorbed=decode,
-            walk=None if walk is None else tuple(walk.items()))
+        pages = self._arenas["c"][self._index]
+        if self._ride is None:
+            out, pages = self._attend_latent(
+                q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, pages, self._tables,
+                self._positions, self._n_tok, decode=self._row is None)
+        else:
+            ride = self._ride
+            (qn, qn_d), (qr, qr_d), (c, c_d), (kr, kr_d) = self._parts(
+                q_nope, q_rope, c_kv, k_rope)
+            out_p, pages = self._attend_latent(
+                qn, qr, c, kr, w_uk, w_uv, pages, self._tables,
+                self._positions, self._n_tok, decode=False)
+            out_d, pages = self._attend_latent(
+                qn_d, qr_d, c_d, kr_d, w_uk, w_uv, pages, ride.tables,
+                ride.starts, ride.n_tok, decode=True)
+            out = self._join(out_p, out_d)
         self._arenas["c"][self._index] = pages
         return out
+
+    def _attend_latent(self, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
+                       pages, tables, positions, n_tok, *, decode):
+        eng, spec = self._eng, self._spec
+        walk = eng._latent_walk(q_nope.shape[0], q_nope.shape[1], spec) \
+            if decode else None
+        return eng._attend_latent(
+            q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, pages, tables,
+            positions, n_tok, spec=spec, absorbed=decode,
+            walk=None if walk is None else tuple(walk.items()))
 
     def note(self, name: str, value, reduce: str = "sum") -> None:
         """A count made inside the program, for the step's span: summed
@@ -734,6 +813,26 @@ class ServingEngine:
         self._adapt = AdaptiveK(self.spec.k, self.spec.adaptive,
                                 decay=self.spec.ema_decay) \
             if self.spec else None
+        # a step that prefills carries its decode rows on its LAST prefill
+        # launch (one weight read and one launch path for both;
+        # :meth:`_step_inner`) where every layer can split its work by
+        # part: attention by its own tables, a latent layer absorbed for
+        # the decode part, a layer that keeps nothing by ``io.valid``.  A
+        # state layer would have to split its conv tail and scan, and the
+        # TP / CP meshes run programs of their own: those keep the two
+        # programs apart.  Only the narrowest launch of the ladder carries
+        # a decode part (:meth:`_carries_rows`).  Set False before the
+        # first step to keep the programs apart anyway (they are built for
+        # what it says then).
+        refusal = "state_layers" if self.state is not None else \
+            "mesh" if self._mesh is not None else None
+        self.rides_prefill = refusal is None
+        self.rides_prefill_refusal: Optional[str] = refusal
+        if refusal is not None:
+            _event("serve_rides_prefill", refusal, rides_prefill=False)
+        self._idle_ride = None          # the decode part of a launch that
+        # carries no rows (:meth:`_ride_args`)
+        self._ride_choice = None        # the riding launch's [R, S] choice
 
         self._queue: deque = deque()
         self._active: Dict[int, Request] = {}          # row -> Request
@@ -1068,8 +1167,12 @@ class ServingEngine:
     def step(self) -> None:
         """One scheduler iteration: shed what cannot meet its deadline,
         admit what fits, prefill the newly admitted, take one decode step
-        for every active row, retire finished rows, then flush the journal
-        and surface newly delivered tokens to the sink.
+        for every row that holds a token, retire finished rows, then flush
+        the journal and surface newly delivered tokens to the sink.  Where
+        the engine rides (:attr:`rides_prefill`) a step that prefills runs
+        no decode program: its decode rows ride the last prompt's last
+        launch, and that prompt's row steps from the next step on
+        (:meth:`_step_inner`).
 
         Transient (``OSError``-class) failures — storage flake on the
         journal, injected ``serve`` faults — are absorbed: request state
@@ -1108,6 +1211,18 @@ class ServingEngine:
             self.journal is not None and self.journal.pending > 0)
 
     def _step_inner(self) -> bool:
+        """The phases of :meth:`step`, in order: shed, admit; the fresh
+        prompts' prefills; the decode step over every row that holds a
+        token; the flush.  Where the engine rides, the last fresh prompt
+        whose prefill ends in a launch that carries a decode part
+        (:meth:`_rides_last`; the rider) is prefilled after the decode
+        step's preparation, and its last launch carries the decode rows:
+        ``_decode_step`` then launches nothing, the rider's one fetch
+        brings its first token and the rows' choice, and both are flushed
+        at the end of the step.  The rider's row steps from the next step
+        on; a prompt prefilled before it already holds its token and
+        rides as a decode row.  A step with no rider, or whose rider the
+        preparation evicted, runs the decode program."""
         with _span("serve.shed_scan"):
             self._shed_scan()
         with _span("serve.admit") as sp:
@@ -1118,25 +1233,66 @@ class ServingEngine:
         # succeeding must reset the failure streak and close the breaker
         cy = self._cycle
         fresh = [r for r in self._active.values() if not r.generated]
+        rider = next((r for r in reversed(fresh) if self._rides_last(r)),
+                     None) if self.rides_prefill else None
         if fresh:
             cy.enter("prefill")
         for r in fresh:
-            cy.prefill_requests += 1
-            with _span("serve.prefill", rid=r.rid, trace=r.trace_id or "",
-                       prompt_tokens=len(r.prompt),
-                       cached_tokens=r.cached_tokens) as sp:
-                chunks, launches, noted = self._prefill(r)
-                sp.note(chunks=chunks, launches=launches, **noted)
-            did_work = True
-            self._retire_if_done(r)
-        if self._active:
+            if r is not rider:
+                self._prefill_request(r)
+                did_work = True
+        ride = None
+        if any(r.generated for r in self._active.values()):
             cy.enter("decode")
-            self._decode_step()
+            ride = self._decode_step(rider)
+            did_work = True
+        if rider is not None and rider.state == RUNNING:
+            cy.enter("prefill")
+            self._prefill_request(rider, ride)
             did_work = True
         self._flush_delivery()
         self.meter.set_queue_depth(len(self._queue))
         self.meter.set_occupancy(self.pool.occupancy())
         return did_work
+
+    def _pages_to_run(self, r: Request):
+        """``r``'s prompt pages and the first one its prefill runs: pages
+        ``[0, c0)`` were adopted already filled from the prefix cache, and
+        the match cap guarantees ``c0 < n_chunks`` (the last prompt
+        token's logits are always computed fresh)."""
+        n_chunks = -(-len(r.prompt) // self.page_tokens)
+        return n_chunks, min(r.cached_tokens // self.page_tokens,
+                             n_chunks - 1)
+
+    def _carries_rows(self, width: int) -> bool:
+        """Whether a prefill launch of ``width`` pages carries a decode
+        part: only the narrowest of the ladder, the launch whose rows lie
+        under the chip's ridge, where the rows beside it ride the weight
+        read it makes anyway.  A wider launch is bound by its matmuls, and
+        its idle decode slots (every launch but a step's last) would cost
+        it rows: it keeps the plain program."""
+        return self.rides_prefill and width == self._prefill_widths[0]
+
+    def _rides_last(self, r: Request) -> bool:
+        """Whether ``r``'s prefill ends in a launch that carries a decode
+        part (imported pages launch nothing)."""
+        if r.kv_import is not None:
+            return False
+        n_chunks, c0 = self._pages_to_run(r)
+        return self._carries_rows(
+            prefill_plan(n_chunks - c0, self._prefill_widths)[-1])
+
+    def _prefill_request(self, r: Request, ride=None) -> None:
+        """``r``'s prefill under its span, then its retirement if its first
+        token was its last.  ``ride``: the decode batch its last launch
+        carries (:meth:`_decode_prep`), or None."""
+        self._cycle.prefill_requests += 1
+        with _span("serve.prefill", rid=r.rid, trace=r.trace_id or "",
+                   prompt_tokens=len(r.prompt),
+                   cached_tokens=r.cached_tokens) as sp:
+            chunks, launches, noted = self._prefill(r, ride)
+            sp.note(chunks=chunks, launches=launches, **noted)
+        self._retire_if_done(r)
 
     # -- scheduling --------------------------------------------------------
     def _free_rows(self) -> List[int]:
@@ -1431,7 +1587,7 @@ class ServingEngine:
         return True
 
     def _retire_if_done(self, r: Request) -> None:
-        if not r.done():
+        if r.state != RUNNING or not r.done():
             return
         freed = self.pool.free(r.rid)
         self._release_row(r)
@@ -1451,14 +1607,19 @@ class ServingEngine:
         t[:len(pages)] = pages
         return t
 
-    def _prefill_chunks(self, prompt, table, c0: int = 0, row: int = 0):
+    def _prefill_chunks(self, prompt, table, c0: int = 0, row: int = 0,
+                        ride=None):
         """Drive the compiled prefill program over ``prompt``'s pages
         ``[c0, n_chunks)`` in the launches of :func:`prefill_plan`.
         Returns the last-prompt-token logits and the number of launches.
         Shared by scheduled prefills (:meth:`_prefill`, where ``c0`` skips
-        prefix-cached pages) and the standalone :meth:`prefill_export` path.  ``row``: the decode row
-        whose state slot the state layers carry the prompt through; each
-        launch is told how many of its tokens are real."""
+        prefix-cached pages) and the standalone :meth:`prefill_export`
+        path.  ``row``: the decode row whose state slot the state layers
+        carry the prompt through; each launch is told how many of its
+        tokens are real.  ``ride``: the step's decode batch
+        (:meth:`_decode_prep`), which the LAST launch carries (one of the
+        width that carries a decode part: :meth:`_carries_rows`); the
+        other launches of that width carry idle decode slots."""
         import jax.numpy as jnp
 
         P = self.page_tokens
@@ -1466,7 +1627,7 @@ class ServingEngine:
         widths = prefill_plan(n_chunks - c0, self._prefill_widths)
         logits, c = None, c0
         self._prefill_notes, self._prefill_kept = [], []
-        for w in widths:
+        for i, w in enumerate(widths):
             part = prompt[c * P:(c + w) * P]
             chunk = np.zeros((1, w * P), np.int32)
             chunk[0, :len(part)] = part
@@ -1474,17 +1635,20 @@ class ServingEngine:
             take = len(prompt) - 1 - c * P if c + w >= n_chunks else 0
             logits = self._run_prefill(
                 jnp.asarray(chunk), jnp.int32(c * P), table,
-                jnp.int32(take), jnp.int32(row), jnp.int32(len(part)))
+                jnp.int32(take), jnp.int32(row), jnp.int32(len(part)),
+                ride=ride if i == len(widths) - 1 else None)
             self._cycle.prefill_launches += 1
             self._cycle.prefill_tokens += len(part)
             c += w
         return logits, len(widths)
 
-    def _prefill(self, r: Request):
+    def _prefill(self, r: Request, ride=None):
         """Fill ``r``'s pages and deliver its first token.  Returns the
         pages run, the program launches that took (both 0 where the pages
         were imported) and what the launches' layers noted, for the
-        span."""
+        span.  ``ride``: the step's decode batch, carried by the last
+        launch; its choice comes with the logits in the one fetch, and
+        its rows' tokens are booked after ``r``'s."""
         import jax
         import jax.numpy as jnp
 
@@ -1497,12 +1661,8 @@ class ServingEngine:
             return 0, 0, {}
         _faults.fire("serve_prefill", f"rid{r.rid}")
         prompt = r.prompt
-        n_chunks = -(-len(prompt) // self.page_tokens)
-        # prefix-cache hit: chunks [0, c0) were adopted already-filled, so
-        # the forward pass resumes at the first uncached chunk; the match
-        # cap guarantees c0 < n_chunks — the last prompt token's logits
-        # are always computed fresh
-        c0 = min(r.cached_tokens // self.page_tokens, n_chunks - 1)
+        # a prefix-cache hit resumes at the first uncached page
+        n_chunks, c0 = self._pages_to_run(r)
         with _span("serve.prefill.dispatch"):
             if self._cp_accepts(len(prompt), cached_tokens=r.cached_tokens):
                 logits = self._cp_prefill_run(prompt, self.pool.table(r.rid))
@@ -1510,12 +1670,19 @@ class ServingEngine:
             else:
                 table = jnp.asarray(self._padded_table(r.rid)[None])
                 logits, launches = self._prefill_chunks(prompt, table, c0,
-                                                        r.row)
+                                                        r.row, ride)
             self.meter.prefill_launched(launches)
+            if self.rides_prefill and self._decode_exec is None:
+                # the row's next step runs the decode program: it compiles
+                # with the prefill widths, in the same cycle
+                self._decode_program(self._decode_args(*self._ride_args()))
         with _span("serve.prefill.to_host"):
-            # the launches' notes come with the logits: one sync point
-            logits, noted = jax.device_get((logits, self._prefill_notes))
-            self._prefill_notes = []
+            # the launches' notes come with the logits, and the riding
+            # rows' choice: one sync point
+            logits, noted, choice = jax.device_get((
+                logits, self._prefill_notes,
+                None if ride is None else self._ride_choice))
+            self._prefill_notes, self._ride_choice = [], None
         with _span("serve.prefill.sample"):
             tok = int(np.argmax(logits))
             r.generated.append(tok)
@@ -1533,6 +1700,9 @@ class ServingEngine:
                 r.drafter = self.spec.make_drafter()
                 r.drafter.begin([int(t) for t in r.prompt])
                 r.drafter.observe([tok])
+            if ride is not None:
+                stepped, _, n_tok, _, _, drafts = ride
+                self._decode_sample(stepped, choice, n_tok, drafts)
         # a launch reads the prompt's pages so far, its own included
         pages = 0 if self._latent is None else int((c0 + np.cumsum(
             prefill_plan(n_chunks - c0, self._prefill_widths))).sum())
@@ -1642,8 +1812,12 @@ class ServingEngine:
             if key in ("k", "v") else np.asarray(a[pid]) for a in arrs])
             for key, arrs in self._arenas.items()}
 
-    def _decode_step(self) -> None:
-        """One verify-wide decode step.  Serial mode (spec off) is the
+    def _decode_step(self, rider: Optional[Request] = None):
+        """One verify-wide decode step.  With a ``rider`` still running
+        after the preparation, the step launches nothing: it returns the
+        batch for the rider's last prefill launch to carry
+        (:meth:`_step_inner`), and its span says ``rode=1``; else it runs
+        the decode program and returns None.  Serial mode (spec off) is the
         degenerate S=1 case: every row carries n_tok=1 and the program
         trace is value-identical to the old single-token decode.  With
         speculation, each row drafts k_r tokens host-side, the ONE
@@ -1663,24 +1837,33 @@ class ServingEngine:
                 batch = self._decode_prep()
             if batch is None:
                 sp.note(rows=0, n_tok=0, live_pages=0, table_pages=0,
-                        state_rows=0)
+                        state_rows=0, rode=0)
                 for r in list(self._active.values()):
                     self._retire_if_done(r)
-                return
+                return None
             stepped, tokens, n_tok, positions, tables, drafts = batch
+            rode = rider is not None and rider.state == RUNNING
             self._cycle.decode_rows += len(stepped)
             # live_pages: what the rows' queries can see (idle rows: none),
             # the decode kernel's own rule; table_pages: what the padded
             # tables hold
             live = np.where(n_tok > 0, -(-(positions + n_tok)
                                          // self.page_tokens), 0)
+            seen = int(np.where(n_tok > 0, positions + n_tok, 0).sum())
             # state_rows: rows whose recurrent state the step updates
             sp.note(rows=len(stepped), n_tok=int(n_tok.sum()),
                     live_pages=int(live.sum()), table_pages=tables.size,
                     state_rows=len(stepped) if self.state is not None
-                    else 0)
+                    else 0, rode=int(rode))
             _faults.fire("serve_decode", f"step{self.steps_total}")
             _faults.fire("slow_serve", f"{self.fault_scope}/decode")
+            self.meter.decode_step(rode=rode)
+            if rode:
+                # what the rows will see is counted here; what the riding
+                # launch's layers note, on that launch's own span
+                if self._latent is not None:
+                    sp.note(**self._step_facts([], int(live.sum()), seen))
+                return batch
             with _span("serve.decode.dispatch"):
                 choice = self._run_decode(jnp.asarray(tokens),
                                           jnp.asarray(positions),
@@ -1694,10 +1877,10 @@ class ServingEngine:
                              + (0 if noted is None else noted.nbytes))
             if self._latent is not None or noted is not None:
                 sp.note(**self._step_facts(
-                    [] if noted is None else [noted], int(live.sum()),
-                    int(np.where(n_tok > 0, positions + n_tok, 0).sum())))
+                    [] if noted is None else [noted], int(live.sum()), seen))
             with _span("serve.decode.sample"):
                 self._decode_sample(stepped, choice, n_tok, drafts)
+        return None
 
     def _step_facts(self, noted, pages: int, tokens: int = 0) -> dict:
         """A ``serve.decode`` / ``serve.prefill`` span's facts from what
@@ -1733,8 +1916,10 @@ class ServingEngine:
         stepped: List[Request] = []
         for r in [self._active[row] for row in sorted(self._active)]:
             # _ensure_page can evict LATER snapshot entries; skip anything
-            # no longer running so an evictee never allocates while queued
-            if r.state != RUNNING or r.row is None or r.done():
+            # no longer running so an evictee never allocates while queued;
+            # a row without a token is a prompt not prefilled yet (a rider)
+            if r.state != RUNNING or r.row is None or r.done() \
+                    or not r.generated:
                 continue
             d: List[int] = []
             if self.spec is not None and r.drafter is not None:
@@ -1748,7 +1933,7 @@ class ServingEngine:
             self._ensure_page(r, 1 + len(d))
         # _ensure_page may have evicted rows; rebuild the live view
         for row, r in sorted(self._active.items()):
-            if r.done():
+            if r.done() or not r.generated:
                 continue
             d = drafts.get(r.rid, [])
             seq = [r.generated[-1]] + d
@@ -2236,7 +2421,7 @@ class ServingEngine:
 
     def _forward(self, param_arrays, buffer_arrays, arenas, tokens,
                  positions, tables, n_tok, *, n_valid=None, row=None,
-                 fresh=None):
+                 fresh=None, ride: Optional[_Ride] = None):
         """Shared model step for both programs: ONE walk over the model's
         layers, each given its :class:`_LayerIO`.  ``tokens`` [R, s]
         (decode/verify: s=spec width; prefill: R=1, s=a width of
@@ -2246,7 +2431,10 @@ class ServingEngine:
         trash); ``n_valid`` [R] tokens per row that are real (None:
         ``n_tok``) — what a state layer may let into its state; ``row``
         (prefill): the decode row whose state slot the one prompt row uses,
-        zeroed first where ``fresh``; None (decode): row r is slot r."""
+        zeroed first where ``fresh``; None (decode): row r is slot r.
+        ``ride`` (a riding launch): ``tokens`` is the one row of the prompt
+        part and the decode part, which the model sees at
+        ``ride.positions``; only ``ride.head``'s tokens reach the head."""
         import jax.numpy as jnp
 
         from ..autograd import no_grad
@@ -2257,15 +2445,23 @@ class ServingEngine:
         new_arenas = {key: list(arrs) for key, arrs in arenas.items()}
         notes: Dict[str, object] = {}
         kept: Dict[str, list] = {}
+        n_valid = n_tok if n_valid is None else n_valid
+        if ride is None:
+            at = positions
+            valid = jnp.arange(tokens.shape[1])[None, :] < n_valid[:, None]
+        else:
+            at, valid = ride.positions, ride.valid
         with _StateSwap(self._params, param_arrays), \
                 _StateSwap(self._buffers, buffer_arrays), no_grad():
-            x, shared = model.serve_begin(Tensor(tokens), positions)
+            x, shared = model.serve_begin(Tensor(tokens), at)
             for li, spec in enumerate(self._layers):
                 io = _LayerIO(self, spec, new_arenas,
                               self._family_index[li], tables, positions,
-                              n_tok, n_tok if n_valid is None else n_valid,
-                              row, fresh, notes, kept)
+                              n_tok, n_valid, row, fresh, notes, kept,
+                              valid, ride)
                 x = model.serve_layer(li, x, shared, io)
+            if ride is not None:
+                x = Tensor(jnp.take(x._value, ride.head, axis=1))
             logits = model.serve_end(x)
             self._note_reduce = {name: notes[name][1]
                                  for name in sorted(notes)}
@@ -2328,7 +2524,8 @@ class ServingEngine:
         return None
 
     def _prefill_fn(self, param_arrays, buffer_arrays, arenas, tokens,
-                    chunk_start, tables, take_idx, row=None, n_valid=None):
+                    chunk_start, tables, take_idx, row=None, n_valid=None,
+                    ride=None):
         """The prefill signature, compiled once a width of
         ``PREFILL_WIDTHS``: ``tokens`` [1, w * page_tokens] of one prompt
         from position ``chunk_start`` on.  ``row`` / ``n_valid``: the
@@ -2338,7 +2535,18 @@ class ServingEngine:
         exposes it); whole junk pages behind it, which only a launch wider
         than the prompt's rest has, scatter to the trash page.  State
         layers let only the real tokens into the state: a recurrence would
-        swallow the junk."""
+        swallow the junk.
+
+        ``ride`` (the riding form, the narrowest width where the engine
+        rides: :meth:`_carries_rows`): the decode program's four inputs
+        ``(tokens [R, S], positions, tables, n_tok)``, idle (``n_tok`` 0)
+        on every launch of that width but a step's last.
+        The model sees the prompt's tokens and the decode rows' as one
+        row, so every weight is read once for both; only the prompt's last
+        token and the decode tokens reach the head.  Returns, beside the
+        logits row and the notes, the rows' choice [R, S]; beside what the
+        prompt's tokens kept, the rows' logits [R, S, V] and what their
+        tokens kept, as the decode program leaves them."""
         import jax.numpy as jnp
 
         positions = chunk_start[None]                 # [1]
@@ -2346,14 +2554,42 @@ class ServingEngine:
         # n_valid <= s, a whole number of pages
         keep = s if n_valid is None else -(-n_valid // P) * P
         n_tok = jnp.full((1,), keep, jnp.int32)
+        row = jnp.int32(0) if row is None else row
+        if ride is None:
+            logits, arenas, noted, kept = self._forward(
+                param_arrays, buffer_arrays, arenas, tokens, positions,
+                tables, n_tok,
+                n_valid=None if n_valid is None else n_valid[None], row=row,
+                fresh=chunk_start == 0)
+            # what the layers noted rides beside the logits row, and what
+            # they kept stays on the device, as in decode
+            return (jnp.take(logits[0], take_idx, axis=0), noted), kept, \
+                arenas
+        d_tokens, d_starts, d_tables, d_n_tok = ride
+        R, S = d_tokens.shape
+        offs = jnp.arange(S)
+        real = s if n_valid is None else n_valid
+        ride = _Ride(
+            split=s, width=S, tables=d_tables, starts=d_starts, n_tok=d_n_tok,
+            positions=jnp.concatenate([
+                chunk_start + jnp.arange(s),
+                (d_starts[:, None] + offs).reshape(-1)])[None],
+            valid=jnp.concatenate([
+                jnp.arange(s) < real,
+                (offs[None, :] < d_n_tok[:, None]).reshape(-1)])[None],
+            head=jnp.concatenate([take_idx[None], s + jnp.arange(R * S)]))
         logits, arenas, noted, kept = self._forward(
-            param_arrays, buffer_arrays, arenas, tokens, positions, tables,
-            n_tok, n_valid=None if n_valid is None else n_valid[None],
-            row=jnp.int32(0) if row is None else row,
-            fresh=chunk_start == 0)
-        # what the layers noted rides beside the logits row, and what they
-        # kept stays on the device, as in decode
-        return (jnp.take(logits[0], take_idx, axis=0), noted), kept, arenas
+            param_arrays, buffer_arrays, arenas,
+            jnp.concatenate([tokens[0], d_tokens.reshape(-1)])[None],
+            positions, tables, n_tok, row=row, fresh=chunk_start == 0,
+            ride=ride)
+        d_logits = logits[0, 1:].reshape(R, S, -1)
+        kept_prompt = {name: v[:, :, :s] for name, v in kept.items()}
+        kept_rows = {name: v[:, 0, s:].reshape((v.shape[0], R, S)
+                                               + v.shape[3:])
+                     for name, v in kept.items()}
+        return (logits[0, 0], noted, greedy_choice(d_logits)), \
+            (kept_prompt, d_logits, kept_rows), arenas
 
     def _param_arrays(self):
         with _SWAP_LOCK:
@@ -2391,11 +2627,15 @@ class ServingEngine:
             return jax.jit(named_program(fn, name), donate_argnums=(2,)) \
                 .lower(*args).compile()
 
-    def _run_decode(self, tokens, positions, tables, n_tok):
+    def _decode_args(self, tokens, positions, tables, n_tok):
         pa, ba = self._param_arrays()
-        args = (pa, ba, self._arenas, self._repl(tokens),
+        return (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(positions), self._repl(tables),
                 self._repl(n_tok))
+
+    def _decode_program(self, args):
+        """The decode program, compiled (and held to the donation gate) the
+        first time it is asked for."""
         if self._decode_exec is None:
             self._decode_compiles += 1
             self._decode_exec = self._compile(self._decode_fn, args,
@@ -2405,18 +2645,45 @@ class ServingEngine:
                     self._decode_exec, self._arena_bytes,
                     scale_bytes=self._scale_bytes, shards=self.tp,
                     state_bytes=self.state.nbytes if self.state else 0)
+        return self._decode_exec
+
+    def _run_decode(self, tokens, positions, tables, n_tok):
+        args = self._decode_args(tokens, positions, tables, n_tok)
         # the previous step's logits are dropped here, never fetched unless
         # someone asked (:attr:`last_decode_logits`)
         (choice, self._decode_noted), \
             (self._decode_logits, self._decode_kept), self._arenas = \
-            self._decode_exec(*args)
+            self._decode_program(args)(*args)
         return choice
 
+    def _ride_args(self, ride=None):
+        """The decode part of a riding launch, on the device: the four
+        inputs of ``ride`` (a batch of :meth:`_decode_prep`), or the idle
+        slots that a launch of the riding width carries where it is not a
+        step's last (kept, not copied again)."""
+        import jax.numpy as jnp
+
+        if ride is not None:
+            _, tokens, n_tok, positions, tables, _ = ride
+            return tuple(self._repl(jnp.asarray(a))
+                         for a in (tokens, positions, tables, n_tok))
+        if self._idle_ride is None:
+            R, S = self.max_batch, self._spec_width
+            self._idle_ride = tuple(self._repl(a) for a in (
+                jnp.zeros((R, S), jnp.int32), jnp.zeros((R,), jnp.int32),
+                jnp.full((R, self.max_pages_per_seq), TRASH_PAGE, jnp.int32),
+                jnp.zeros((R,), jnp.int32)))
+        return self._idle_ride
+
     def _run_prefill(self, tokens, chunk_start, tables, take_idx, row,
-                     n_valid):
+                     n_valid, ride=None):
         """Launch the prefill program of ``tokens``' width.  The first
         launch compiles every width of the ladder, so none compiles once
-        requests are being served."""
+        requests are being served.  A launch of the width that carries a
+        decode part (:meth:`_carries_rows`) carries ``ride``'s rows, or
+        idle slots; one that carried rows leaves their choice for the
+        step's one fetch and their logits where the decode program's would
+        be."""
         pa, ba = self._param_arrays()
         args = (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(chunk_start), self._repl(tables),
@@ -2428,11 +2695,20 @@ class ServingEngine:
                 wide = self._repl(jnp.zeros((1, w * self.page_tokens),
                                             tokens.dtype))
                 self._prefill_exec[w] = self._compile(
-                    self._prefill_fn, args[:3] + (wide,) + args[4:],
-                    PREFILL_PROGRAM)
+                    self._prefill_fn, args[:3] + (wide,) + args[4:] + (
+                        (self._ride_args(),) if self._carries_rows(w)
+                        else ()), PREFILL_PROGRAM)
         width = tokens.shape[1] // self.page_tokens
-        (logits, noted), kept, self._arenas = \
-            self._prefill_exec[width](*args)
+        carries = self._carries_rows(width)
+        if carries:
+            args += (self._ride_args(ride),)
+        out, kept, self._arenas = self._prefill_exec[width](*args)
+        logits, noted = out[:2]
+        if carries:
+            kept, d_logits, d_kept = kept
+            if ride is not None:
+                self._ride_choice = out[2]
+                self._decode_logits, self._decode_kept = d_logits, d_kept
         if noted is not None:
             self._prefill_notes.append(noted)   # fetched with the logits
         if kept:

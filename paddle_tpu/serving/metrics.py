@@ -185,6 +185,9 @@ class SLOMeter:
         # model has no state layers
         self.finished_total = 0
         self.prefill_launches_total = 0  # launches of a prefill program
+        self.decode_steps_total = 0      # steps that decoded any row
+        self.decode_steps_rode = 0       # of them, those whose rows rode a
+        # prefill launch (``ServingEngine.rides_prefill``)
         self.decode_logits_fetches_total = 0   # whole [R, S, V] arrays
         # pulled to the host (``ServingEngine.last_decode_logits``)
         self.evictions_total = 0
@@ -300,6 +303,12 @@ class SLOMeter:
     def prefill_launched(self, launches: int) -> None:
         """One prompt's prefill took ``launches`` program launches."""
         self.prefill_launches_total += int(launches)
+
+    def decode_step(self, *, rode: bool) -> None:
+        """A step decoded its rows: through the decode program, or riding
+        the step's last prefill launch (``rode``)."""
+        self.decode_steps_total += 1
+        self.decode_steps_rode += int(bool(rode))
 
     def cycle_closed(self, cy: Cycle, *, step: int) -> None:
         """A flush delivered tokens and so ended ``cy``.  A cycle in which
@@ -572,7 +581,9 @@ class SLOMeter:
         monotonic second it ended ``end_s``, its length ``ms``, the five
         parts ``outside_ms`` / ``admit_ms`` / ``prefill_ms`` /
         ``decode_ms`` / ``deliver_ms`` that sum to it, and :class:`Cycle`'s
-        counts)."""
+        counts).  ``decode_steps``: steps that decoded rows;
+        ``decode_steps_rode``: of them, those whose rows rode the step's
+        last prefill launch."""
         ttft = [t * 1e3 for (_, t, _, _, _) in self._window if t is not None]
         tpot = [t * 1e3 for (_, _, t, _, _) in self._window if t is not None]
         lat = [t * 1e3 for (_, _, _, t, _) in self._window if t is not None]
@@ -607,6 +618,8 @@ class SLOMeter:
             "deadline_miss_rate": round(self.deadline_miss_rate(), 4),
             "evictions": self.evictions_total,
             "prefill_launches": self.prefill_launches_total,
+            "decode_steps": self.decode_steps_total,
+            "decode_steps_rode": self.decode_steps_rode,
             "decode_logits_fetches": self.decode_logits_fetches_total,
             "kv_pool_occupancy_peak": round(self.occupancy_peak, 4),
             "state_slots_peak": (None if self.state_slots_peak is None

@@ -77,6 +77,43 @@ def test_grouped_matmul_kernel_agrees_with_ragged_dot(sizes, m):
     assert not np.asarray(got[sum(sizes):]).any()
 
 
+@pytest.mark.parametrize("m", [272, 200])
+def test_pairs_that_are_no_whole_row_tiles_still_take_the_kernel(m):
+    """A serving launch's pairs (a prompt's tokens and the decode rows that
+    ride beside them, times the experts a token) need not fill whole row
+    tiles: the layer's ``grouped_matmul`` pads them past every group, and
+    its gate asks the kernel about the padded count."""
+    from paddle_tpu import telemetry
+    from paddle_tpu.distributed import topology
+    from paddle_tpu.nn.layer import moe
+
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(m, 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(3, 16, 24)), jnp.float32)
+    sizes = jnp.asarray([70, 0, m - 90], jnp.int32)     # 20 rows of no group
+    assert grouped_matmul_refusal(x.shape, w.shape, x.dtype,
+                                  interpret=True) == "tiling"
+    flags = ["pallas_interpret", "use_decode_attention"]
+    prior, prior_hcg = paddle.get_flags(flags), topology._hcg
+    topology._hcg = None          # an earlier distributed test's mesh
+    paddle.set_flags(dict.fromkeys(flags, True))
+    try:
+        before = dict(telemetry.counters())
+        assert moe.grouped_matmul_kernel(m, w.shape, (3, 24, 16),
+                                         x.dtype) == "interpret"
+        assert {k: v for k, v in telemetry.counters().items()
+                if k.startswith("kernel_fallback.")} == \
+            {k: v for k, v in before.items()
+             if k.startswith("kernel_fallback.")}
+    finally:
+        paddle.set_flags(prior)
+        topology._hcg = prior_hcg
+    got = moe.grouped_matmul(x, w, sizes, "interpret")
+    assert got.shape == (m, 24)
+    np.testing.assert_allclose(got, jax.lax.ragged_dot(x, w, sizes),
+                               atol=1e-4)
+
+
 def test_grouped_matmul_gate_names_its_reason():
     bf16 = jnp.bfloat16
     assert KERNEL_NAME == "moe_grouped_matmul"

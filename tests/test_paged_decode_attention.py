@@ -270,13 +270,22 @@ def test_merged_pages_under_the_layers_own_scale_walk(interpreted):
     np.testing.assert_allclose(walked, live_logits(), rtol=1e-5, atol=1e-5)
 
 
+def _walking_programs(eng) -> int:
+    """The programs whose decode rows ask the page walk: the decode
+    program, and the prefill width whose launches the rows ride."""
+    return 1 + sum(map(eng._carries_rows, eng._prefill_widths))
+
+
 @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
 def test_quantized_pools_fall_back_loudly(model, interpreted, kv_dtype):
     eng, got = _serve(model, kv_dtype=kv_dtype)
     assert all(len(g) for g in got) and eng._decode_compiles == 1
-    # one count an attention layer: each of them gathers
+    # one count an attention layer a program that walks decode rows: each
+    # of them gathers, in the decode program and in the decode part of
+    # every riding prefill width
     assert _fallbacks() == {f"kernel_fallback.{KERNEL_NAME}.kv_dtype":
-                            model.config.num_hidden_layers}
+                            model.config.num_hidden_layers
+                            * _walking_programs(eng)}
 
 
 def test_live_hybrid_mesh_falls_back_loudly(model, interpreted):
@@ -294,7 +303,8 @@ def test_live_hybrid_mesh_falls_back_loudly(model, interpreted):
         topology._hcg = None
     assert all(len(g) for g in got) and eng._decode_compiles == 1
     assert _fallbacks() == {f"kernel_fallback.{KERNEL_NAME}.hybrid_mesh":
-                            model.config.num_hidden_layers}
+                            model.config.num_hidden_layers
+                            * _walking_programs(eng)}
 
 
 def test_speculative_width_walks_pages(model, interpreted):

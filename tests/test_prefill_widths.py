@@ -209,8 +209,8 @@ def test_prefix_hit_at_a_page_that_is_no_multiple_of_the_width(llama,
     seen = []
     run_chunks = eng._prefill_chunks
 
-    def spy(p, table, c0=0, row=0):
-        out = run_chunks(p, table, c0, row)
+    def spy(p, table, c0=0, row=0, ride=None):
+        out = run_chunks(p, table, c0, row, ride)
         seen.append((c0, out[1]))
         return out
 
@@ -267,12 +267,17 @@ def test_every_width_compiles_once_on_the_first_prefill(which, request):
     eng.submit(prompt(3), max_new_tokens=1)
     eng.run()                   # one page, one token: prefill only
     ladder = eng._prefill_widths
+    # where the rows ride the launches, the decode program compiles with
+    # the widths: a prompt's row runs it from the step after its prefill
+    assert eng.rides_prefill == (which == "llama")
     assert [(name, shape) for name, shape, _ in compiled] == \
-        [(serving_engine.PREFILL_PROGRAM, (1, w * P)) for w in ladder]
+        [(serving_engine.PREFILL_PROGRAM, (1, w * P)) for w in ladder] + \
+        [(serving_engine.DECODE_PROGRAM, (KNOBS["max_batch"], 1))] \
+        * eng.rides_prefill
     assert sorted(eng._prefill_exec) == list(ladder)
-    for _, _, exe in compiled:
+    for name, _, exe in compiled:
         assert exe.as_text().splitlines()[0].split()[1].rstrip(",") == \
-            "jit_" + serving_engine.PREFILL_PROGRAM
+            "jit_" + name
     for n in (1, P + 1, W * P, MP * P - 3):
         eng.submit(prompt(n, 9), max_new_tokens=3)
     eng.run()

@@ -35,7 +35,7 @@ SPANS = {
     "serve.prefill.to_host": ("serve.prefill", set()),
     "serve.prefill.sample": ("serve.prefill", set()),
     "serve.decode": ("serve.step", {"rows", "n_tok", "live_pages",
-                                    "table_pages"}),
+                                    "table_pages", "rode"}),
     "serve.decode.prep": ("serve.decode", set()),
     "serve.decode.dispatch": ("serve.decode", set()),
     "serve.decode.to_host": ("serve.decode", {"bytes"}),
@@ -222,10 +222,17 @@ def test_serving_span_counts_match_steps_and_requests(recorded):
     assert sum(s[2]["n_tok"] for s in decodes) == \
         len(PROMPTS) * (NEW_TOKENS - 1)
     ran = [s for s in decodes if s[2]["rows"] > 0]
+    # the first step's rows (the two prompts prefilled ahead of the third)
+    # rode the third's launch: that step launched no decode program
+    rode = [s for s in ran if s[2]["rode"]]
+    assert len(rode) == 1 and rode[0][2]["rows"] == len(PROMPTS) - 1
     for name in ("serve.decode.dispatch", "serve.decode.to_host",
                  "serve.decode.sample"):
-        assert len(spans[name]) == len(ran), name
+        assert len(spans[name]) == len(ran) - len(rode), name
     assert len(spans["serve.decode.prep"]) == len(decodes)
+    summary = eng.meter.summary()
+    assert (summary["decode_steps"], summary["decode_steps_rode"]) == \
+        (len(ran), len(rode))
     # what a step fetches: one int32 a position [R, S], never the logits
     assert {s[2]["bytes"] for s in spans["serve.decode.to_host"]} == \
         {eng.max_batch * 1 * 4}

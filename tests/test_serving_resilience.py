@@ -145,8 +145,9 @@ class TestCircuitBreaker:
                             num_pages=24, max_pages_per_seq=4)
         eng.admission.breaker = CircuitBreaker(threshold=3, cooldown_s=60.0)
         rid = eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=4)
+        eng.step()      # the prefill: its row decodes from the next step on
         with faults.inject(op="serve_decode", mode="error", times=3) as spec:
-            for _ in range(3):          # prefill ok; 3 decode steps flake
+            for _ in range(3):          # 3 decode steps flake
                 eng.step()
             assert spec.fired == 3
         assert eng.admission.breaker.state == "open"

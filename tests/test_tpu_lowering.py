@@ -514,6 +514,19 @@ class TestProgramsLower:
             jnp.int32(P - 1), donate_argnums=(2,))
         assert kernels_in(prefill)["rms_norm_fwd"] >= 2
         assert kernels_in(prefill)["paged_decode_attention"] == 0
+        # the riding form the engine launches at its narrowest width: the
+        # prompt gathers, the decode rows beside it walk their pages as the
+        # decode program does
+        assert eng.rides_prefill and eng._carries_rows(1)
+        ride = tpu_text(
+            eng._prefill_fn, pa, ba, eng._arenas,
+            jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
+            jnp.int32(P - 1), jnp.int32(0), jnp.int32(P - 3),
+            (jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
+             tables, jnp.ones((R,), jnp.int32)), donate_argnums=(2,))
+        assert kernels_in(ride)["paged_decode_attention"] == 1
+        assert ride.count("call @_paged_attention") == \
+            2 * WIDTHS["num_hidden_layers"]
 
     def test_generate_decode_step(self, eval_model):
         """One decode step of ``generate()``'s loop — the model called the
@@ -672,6 +685,17 @@ class TestLatentLayerProgramsLower:
             donate_argnums=(2,))
         assert kernels_in(prefill)["mla_paged_decode_attention"] == 0
         assert kernels_in(prefill)["moe_grouped_matmul"] == 2
+        # the riding form: the decode rows' absorbed walk beside the
+        # prompt's expanded form, the experts over both parts' tokens
+        assert eng.rides_prefill
+        ride = tpu_text(
+            eng._prefill_fn, pa, ba, eng._arenas,
+            jnp.zeros((1, P), jnp.int32), jnp.int32(0), tables[:1],
+            jnp.int32(P - 1), jnp.int32(3), jnp.int32(P - 7),
+            (jnp.zeros((R, 1), jnp.int32), jnp.zeros((R,), jnp.int32),
+             tables, jnp.ones((R,), jnp.int32)), donate_argnums=(2,))
+        assert kernels_in(ride)["mla_paged_decode_attention"] == 1
+        assert kernels_in(ride)["moe_grouped_matmul"] == 2
 
     def test_decode_updates_the_latent_arenas_in_place(self, engine,
                                                        one_chip):
