@@ -174,6 +174,17 @@ def apply_rotary_pos_emb(q: Tensor, k: Tensor, cos, sin, position_offset: int = 
     return apply_op("rope", fn, (q, k), multi_out=True)
 
 
+def rope_rows(cos, sin, positions, s: int):
+    """The rotary tables ``cos`` / ``sin`` [max_pos, d] at ``positions``
+    ([R] the first token of each row of ``s`` tokens, or [R, s] every
+    token's), shaped [R, s, 1, d] for :func:`rotate_half_apply`."""
+    if positions.ndim == 1:
+        positions = positions[:, None] + jnp.arange(s)[None, :]
+    pos_ids = jnp.clip(positions, 0, cos.shape[0] - 1)      # [R, s]
+    return (jnp.take(cos, pos_ids, axis=0)[:, :, None, :],
+            jnp.take(sin, pos_ids, axis=0)[:, :, None, :])
+
+
 def rotate_half_apply(qv, kv, cos_s, sin_s):
     """The rotate-half rope application in fp32 (shared by the training
     path above and the per-row decode path in generation/): q/k [b,s,h,d],
@@ -406,14 +417,9 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
         each row's first token, or [R, s] every token's: the embeddings,
         and the rows' rotary tables that every layer shares."""
         base = self.llama
-        s = tokens.shape[1]
-        cos, sin = base.rope_cos._value, base.rope_sin._value
-        if positions.ndim == 1:
-            positions = positions[:, None] + jnp.arange(s)[None, :]
-        pos_ids = jnp.clip(positions, 0, cos.shape[0] - 1)      # [R, s]
-        cos_s = jnp.take(cos, pos_ids, axis=0)[:, :, None, :]
-        sin_s = jnp.take(sin, pos_ids, axis=0)[:, :, None, :]
-        return base.embed_tokens(tokens), (cos_s, sin_s)
+        shared = rope_rows(base.rope_cos._value, base.rope_sin._value,
+                           positions, tokens.shape[1])
+        return base.embed_tokens(tokens), shared
 
     def serve_layer(self, i, x, shared, io):
         layer = self.llama.layers[i]

@@ -23,6 +23,22 @@ and state slots; the model owns its block math::
     model.serve_layer(i, x, shared, io)        -> x
     model.serve_end(x)                         -> logits
 
+A model whose layers run several times a step (a looped model, Ouro)
+says so with two more methods; a model without them is walked once::
+
+    model.serve_passes()                       -> P (1 where absent)
+    model.serve_pass_end(x)                    -> x, after every pass
+
+The engine then walks ``serve_layers()`` P times inside ONE compiled loop
+(the programs hold each layer once), calls ``serve_pass_end`` after each
+pass, the last included, and hands the result to ``serve_end``.  Each
+(pass, layer) keeps K/V of its own: pass ``t`` of a layer reads and writes
+pages ``t * num_pages + page`` of that layer's arena, which holds
+``P * num_pages`` pages, while the pool and the tables count
+``num_pages``.  What does not compose with that is refused by name
+(``serving.PassesUnsupported``), as is ``io.note`` / ``io.keep`` in a
+walk that repeats.
+
 ``io`` is the engine's side of layer ``i``: ``io.attend(q, k, v)`` scatters
 this step's K/V into the layer's pages and attends each row over its pages;
 ``io.attend_latent(q_nope, q_rope, c_kv, k_rope, w_uk, w_uv)`` does the same

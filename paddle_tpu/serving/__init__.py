@@ -54,8 +54,9 @@ and ``kv_dtype="fp8"`` stores f8e4m3fn pages under one static scale at
 exactly half the bf16 page bytes."""
 
 from .kv_pool import (LatentLayersUnsupported, OffloadPool,  # noqa: F401
-                      PagedKVPool, PoolExhausted, TRASH_PAGE,
-                      default_offload_pages, default_page_tokens)
+                      PagedKVPool, PassesUnsupported, PoolExhausted,
+                      TRASH_PAGE, default_offload_pages,
+                      default_page_tokens)
 from .kv_quant import (FP8_MAX, KV_DTYPES, default_fp8_scale,  # noqa: F401
                        dequantize_kv, dequantize_kv_fp8, kv_cache_dtype,
                        kv_page_bytes, kv_scale_page_bytes, layer_page_bytes,
@@ -87,6 +88,7 @@ __all__ = [
     "AttentionLayer", "LatentAttentionLayer", "StateLayer",
     "StatelessLayer", "RowStatePool",
     "StateLayersUnsupported", "LatentLayersUnsupported",
+    "PassesUnsupported",
     "KV_DTYPES", "kv_cache_dtype", "quantize_kv", "dequantize_kv",
     "quantize_kv_fp8", "dequantize_kv_fp8", "default_fp8_scale", "FP8_MAX",
     "observe_kv_absmax", "kv_page_bytes", "kv_scale_page_bytes",

@@ -97,7 +97,7 @@ from ..telemetry.runtime import bump as _bump
 from .admission import AdmissionController, Deadline, Overloaded
 from .journal import ServingJournal
 from .kv_pool import LatentLayersUnsupported, OffloadPool, PagedKVPool, \
-    PoolExhausted, TRASH_PAGE, default_page_tokens
+    PassesUnsupported, PoolExhausted, TRASH_PAGE, default_page_tokens
 from .kv_quant import (default_fp8_scale, dequantize_kv, dequantize_kv_fp8,
                        kv_cache_dtype, kv_scale_page_bytes, layer_page_bytes,
                        quantize_kv, quantize_kv_fp8)
@@ -513,7 +513,7 @@ class ServingEngine:
                 "to it (serve_layers / serve_begin / serve_layer / "
                 "serve_end, see models/serve_protocol.py: LlamaForCausalLM, "
                 "GraniteHybridForCausalLM, DeepseekV3ForCausalLM, "
-                "NemotronHForCausalLM); got "
+                "NemotronHForCausalLM, OuroForCausalLM); got "
                 + type(model).__name__)
         self.model = model
         # the model's layers as the engine sees them, and each layer's
@@ -541,6 +541,20 @@ class ServingEngine:
         self._latent: Optional[LatentAttentionLayer] = \
             paged[0] if isinstance(paged[0], LatentAttentionLayer) else None
         att = paged
+        # how many times a step walks the layers (serve_protocol: a looped
+        # model's passes); each pass keeps its K/V in pages of its own
+        self.passes = int(model.serve_passes()) \
+            if hasattr(model, "serve_passes") else 1
+        if self.passes < 1:
+            raise ValueError(f"serve_passes() is {self.passes}: a step "
+                             f"walks the layers at least once")
+        if self.passes > 1 and (stl or self._latent is not None):
+            raise PassesUnsupported(
+                "state or latent layers", "a pass keeps pages of K/V rows "
+                "of its own; a state slot or a latent row has no pass yet")
+        # the layers whose K/V a step's attention reads, over every pass
+        self._kv_layer_passes = self.passes * sum(
+            isinstance(sp, AttentionLayer) for sp in self._layers)
         counts = {StateLayer: 0, "paged": 0}
         self._family_index = []
         for sp in self._layers:
@@ -564,6 +578,7 @@ class ServingEngine:
         self.pool = PagedKVPool(N, P)
         self._now = now if now is not None else time.monotonic
         self.meter = SLOMeter(now=self._now)
+        self.meter.passes = self.passes
         # the cycle in progress (metrics.Cycle), on the meter's clock, and
         # the requests the last delivering flush handed a token
         self._cycle = Cycle(time.monotonic_ns if now is None else
@@ -616,6 +631,9 @@ class ServingEngine:
             self._refuse_with_latent(
                 "tp > 1", "every head reads the one latent row: the heads "
                 "and the experts need a mesh of their own (two axes)")
+            self._refuse_with_passes(
+                "tp > 1", "the looped walk is not partitioned over a model "
+                "mesh yet")
             from .disagg import decode_mesh, shard_llama_params
 
             h_att = att[0].heads
@@ -642,6 +660,8 @@ class ServingEngine:
             self._refuse_with_latent(
                 "cp > 1", "the ring prefill unrolls the Llama block and "
                 "rotates per-head K/V")
+            self._refuse_with_passes(
+                "cp > 1", "the ring prefill walks the layers once")
             from jax.sharding import Mesh as _Mesh
 
             if self.tp > 1:
@@ -681,13 +701,17 @@ class ServingEngine:
         self._flat_pages = self._latent is not None or \
             (head_dim % 128 != 0 and self.tp == 1)
         self._page_shape = (P, kv_heads, head_dim)
-        self._arena_shape = (N, P, kv_heads * head_dim) if self._flat_pages \
-            else (N,) + self._page_shape
+        # a pass's pages lie at ``t * N`` in the arena of P * N pages
+        self._arena_shape = (self.passes * N, P, kv_heads * head_dim) \
+            if self._flat_pages else (self.passes * N,) + self._page_shape
         self.kv_dtype = kv_cache_dtype(kv_dtype)
         if self.kv_dtype != "bf16":
             self._refuse_with_state(
                 f"kv_dtype={self.kv_dtype!r}", "quantized pages beside a "
                 "float32 recurrent state have no measured tolerance yet")
+            self._refuse_with_passes(
+                f"kv_dtype={self.kv_dtype!r}", "quantized pages of every "
+                "pass have no measured tolerance yet")
         if self.kv_dtype == "int8":
             self._refuse_with_latent(
                 "kv_dtype='int8'", "the scales are one a kv head, and a "
@@ -736,9 +760,10 @@ class ServingEngine:
         if self.state is not None:
             arenas.update(self.state.zeros())
         self._arenas = arenas
-        # a page is priced by its layer's kind, over every layer
+        # a page is priced by its layer's kind, over every layer and pass
         self.pool.set_page_bytes(
-            sum(layer_page_bytes(sp, P, self.kv_dtype) for sp in att),
+            self.passes * sum(layer_page_bytes(sp, P, self.kv_dtype)
+                              for sp in att),
             kv_scale_page_bytes(P, kv_heads, self.kv_dtype,
                                 n_layers=n_layers),
             self.kv_dtype)
@@ -762,6 +787,9 @@ class ServingEngine:
                 "prefix_cache", "a cached prefix's pages would be adopted "
                 "without the recurrent state at their end (no state "
                 "snapshots yet)")
+            self._refuse_with_passes(
+                "prefix_cache", "adopting a page across passes is not "
+                "measured yet")
 
         # host-RAM KV offload (long-context ladder): preemption swaps a
         # victim's private pages to the OffloadPool instead of discarding
@@ -786,6 +814,8 @@ class ServingEngine:
             self._refuse_with_latent(
                 "offload", "the host frames are K/V frames [layers, P, kv, "
                 "d]; latent rows have no frame format yet")
+            self._refuse_with_passes(
+                "offload", "a host frame holds one pass's page")
         self._offload_lost: set = set()   # parked rids whose host frames
         # were LRU-dropped: recall is impossible, re-admission downgrades
         # them to the eviction-replay re-prefill path (the README failure
@@ -809,6 +839,9 @@ class ServingEngine:
             self._refuse_with_state(
                 "speculative", "a rejected draft would have to roll the "
                 "recurrent state back")
+            self._refuse_with_passes(
+                "speculative", "verifying drafts through every pass is not "
+                "measured yet")
         self._spec_width = 1 + (self.spec.k if self.spec else 0)
         self._adapt = AdaptiveK(self.spec.k, self.spec.adaptive,
                                 decay=self.spec.ema_decay) \
@@ -882,6 +915,11 @@ class ServingEngine:
         """The same for pages of latent rows."""
         if self._latent is not None:
             raise LatentLayersUnsupported(feature, why)
+
+    def _refuse_with_passes(self, feature: str, why: str) -> None:
+        """The same for a model whose layers run several times a step."""
+        if self.passes > 1:
+            raise PassesUnsupported(feature, why)
 
     # -- public API --------------------------------------------------------
     def submit(self, prompt, max_new_tokens: int = 64,
@@ -989,6 +1027,8 @@ class ServingEngine:
         self._refuse_with_latent(
             "submit_prefilled", "the frames are K/V frames; latent rows "
             "have no frame format yet")
+        self._refuse_with_passes(
+            "submit_prefilled", "a frame holds one pass's page")
         frames = list(kv_frames)
         p = np.asarray(prompt, np.int32).reshape(-1)
         need = self.pool.pages_for(len(p))
@@ -1704,9 +1744,20 @@ class ServingEngine:
                 stepped, _, n_tok, _, _, drafts = ride
                 self._decode_sample(stepped, choice, n_tok, drafts)
         # a launch reads the prompt's pages so far, its own included
-        pages = 0 if self._latent is None else int((c0 + np.cumsum(
-            prefill_plan(n_chunks - c0, self._prefill_widths))).sum())
-        return n_chunks - c0, launches, self._step_facts(noted, pages)
+        ends = c0 + np.cumsum(prefill_plan(n_chunks - c0,
+                                           self._prefill_widths))
+        pages = 0 if self._latent is None else int(ends.sum())
+        facts = self._step_facts(noted, pages)
+        facts.update(passes=self.passes, kv_tokens=int(np.minimum(
+            ends * self.page_tokens, len(prompt)).sum())
+            * self._kv_layer_passes)
+        if ride is not None:
+            # the riding rows' reads, as a decode step would note them
+            _, _, n_tok, positions, _, _ = ride
+            facts["kv_tokens_decode"] = int(np.where(
+                n_tok > 0, positions + n_tok, 0).sum()) \
+                * self._kv_layer_passes
+        return n_chunks - c0, launches, facts
 
     def _import_kv(self, r: Request) -> None:
         """Disaggregated admission (ISSUE 19 leg 2): instead of running
@@ -1774,6 +1825,8 @@ class ServingEngine:
         self._refuse_with_latent(
             "prefill_export", "the frames are K/V frames; latent rows have "
             "no frame format yet")
+        self._refuse_with_passes(
+            "prefill_export", "a frame holds one pass's page")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -1837,7 +1890,8 @@ class ServingEngine:
                 batch = self._decode_prep()
             if batch is None:
                 sp.note(rows=0, n_tok=0, live_pages=0, table_pages=0,
-                        state_rows=0, rode=0)
+                        state_rows=0, rode=0, passes=self.passes,
+                        kv_tokens=0)
                 for r in list(self._active.values()):
                     self._retire_if_done(r)
                 return None
@@ -1854,16 +1908,19 @@ class ServingEngine:
             sp.note(rows=len(stepped), n_tok=int(n_tok.sum()),
                     live_pages=int(live.sum()), table_pages=tables.size,
                     state_rows=len(stepped) if self.state is not None
-                    else 0, rode=int(rode))
+                    else 0, rode=int(rode), passes=self.passes)
             _faults.fire("serve_decode", f"step{self.steps_total}")
             _faults.fire("slow_serve", f"{self.fault_scope}/decode")
             self.meter.decode_step(rode=rode)
             if rode:
                 # what the rows will see is counted here; what the riding
-                # launch's layers note, on that launch's own span
+                # launch's layers note and read, on that launch's own span
                 if self._latent is not None:
                     sp.note(**self._step_facts([], int(live.sum()), seen))
                 return batch
+            # kv_tokens: the cached tokens the rows' queries read, over
+            # every attention layer and pass
+            sp.note(kv_tokens=seen * self._kv_layer_passes)
             with _span("serve.decode.dispatch"):
                 choice = self._run_decode(jnp.asarray(tokens),
                                           jnp.asarray(positions),
@@ -2434,7 +2491,10 @@ class ServingEngine:
         zeroed first where ``fresh``; None (decode): row r is slot r.
         ``ride`` (a riding launch): ``tokens`` is the one row of the prompt
         part and the decode part, which the model sees at
-        ``ride.positions``; only ``ride.head``'s tokens reach the head."""
+        ``ride.positions``; only ``ride.head``'s tokens reach the head.
+        A looped model's walk (``passes`` > 1) runs inside one
+        ``fori_loop`` with the arenas in its carry."""
+        import jax
         import jax.numpy as jnp
 
         from ..autograd import no_grad
@@ -2451,15 +2511,47 @@ class ServingEngine:
             valid = jnp.arange(tokens.shape[1])[None, :] < n_valid[:, None]
         else:
             at, valid = ride.positions, ride.valid
+
+        def walk(x, arenas, tables, ride, notes, kept):
+            for li, spec in enumerate(self._layers):
+                io = _LayerIO(self, spec, arenas, self._family_index[li],
+                              tables, positions, n_tok, n_valid, row, fresh,
+                              notes, kept, valid, ride)
+                x = model.serve_layer(li, x, shared, io)
+            return x
+
+        def one_pass(t, carry):
+            """Pass ``t`` of a looped model: every layer once, over pages
+            ``t * num_pages + page`` (both parts' tables of a riding
+            launch), then the model's step between passes."""
+            xv, arenas = carry
+            arenas = {key: list(arrs) for key, arrs in arenas.items()}
+            off = t * self.num_pages
+            inner: Dict[str, object] = {}
+            y = walk(Tensor(xv), arenas, tables + off,
+                     None if ride is None
+                     else ride._replace(tables=ride.tables + off),
+                     inner, inner)
+            if inner:
+                raise TypeError(
+                    f"io.note / io.keep ({sorted(inner)}) inside a walk "
+                    f"that repeats ({self.passes} passes): what a layer "
+                    f"counts or keeps is not carried out of the loop")
+            y = model.serve_pass_end(y)
+            return y._value.astype(xv.dtype), arenas
+
         with _StateSwap(self._params, param_arrays), \
                 _StateSwap(self._buffers, buffer_arrays), no_grad():
             x, shared = model.serve_begin(Tensor(tokens), at)
-            for li, spec in enumerate(self._layers):
-                io = _LayerIO(self, spec, new_arenas,
-                              self._family_index[li], tables, positions,
-                              n_tok, n_valid, row, fresh, notes, kept,
-                              valid, ride)
-                x = model.serve_layer(li, x, shared, io)
+            if self.passes == 1:
+                x = walk(x, new_arenas, tables, ride, notes, kept)
+                if hasattr(model, "serve_pass_end"):
+                    x = model.serve_pass_end(x)
+            else:
+                # ONE copy of the layers in the program, the arenas carried
+                xv, new_arenas = jax.lax.fori_loop(
+                    0, self.passes, one_pass, (x._value, new_arenas))
+                x = Tensor(xv)
             if ride is not None:
                 x = Tensor(jnp.take(x._value, ride.head, axis=1))
             logits = model.serve_end(x)

@@ -76,6 +76,18 @@ class LatentLayersUnsupported(NotImplementedError):
             f"layers: {why}")
 
 
+class PassesUnsupported(NotImplementedError):
+    """A serving feature that is not built for a model whose layers run
+    several times a step (``serve_passes()`` > 1, each pass's K/V in pages
+    of its own) was asked for: it is refused by name, never run wrong."""
+
+    def __init__(self, feature: str, why: str):
+        self.feature = feature
+        super().__init__(
+            f"{feature} is not supported for a model whose layers run "
+            f"several times a step: {why}")
+
+
 class PagedKVPool:
     """Page allocator over ``num_pages`` fixed blocks of ``page_tokens``
     token slots each.  Page 0 is the reserved trash page and is never

@@ -202,6 +202,8 @@ class SLOMeter:
         self.spec_verify_steps = 0
         self.spec_rows_total = 0
         self.kv_bytes_per_token: Optional[float] = None
+        self.passes = 1     # times the engine walks the model's layers a
+        # step (a looped model's passes)
         # host-RAM KV offload tier (long-context ladder): swap traffic in
         # pages and bytes, plus the token denominator the recall-MBU
         # gauge divides by (replays excluded — recall exists precisely so
@@ -630,6 +632,7 @@ class SLOMeter:
                 round(self.effective_tokens_per_step(), 4)
                 if self.spec_verify_steps else None),
             "kv_bytes_per_token": self.kv_bytes_per_token,
+            "passes": self.passes,
             "kv_offloads": self.offloads_total,
             "kv_recalls": self.recalls_total,
             "kv_offload_stalls": self.offload_stalls_total,

@@ -7,6 +7,8 @@ A small engine on an INJECTED clock that only the test moves: each phase of
 clock, and every delivered token's gap must equal the account of the cycles
 that delivered it, exactly.  CPU only; no real time is asserted."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -326,12 +328,15 @@ def test_the_flush_writes_no_ring_event_and_the_lifecycle_stays(model):
     request a flush used to push a request's own story out of the ring a
     crash dump is for, before the request had finished."""
     ring = telemetry.get_flight_recorder()
+    # this test's events only: an earlier test of the process (a fleet's
+    # own rids) may have left events under the same request names
+    since = time.perf_counter_ns()      # the ring stamps this clock
     h = Harness(model, page_tokens=8, max_pages_per_seq=16, num_pages=64)
     rids = [h.eng.submit(p, max_new_tokens=100)
             for p in _prompts(7, (5, 7, 6, 4, 8, 5))]
     h.run()
     assert sum(len(t) for t in h.tokens.values()) == 600 > ring._events.maxlen
-    events = ring.events()
+    events = ring.events(since_mono_ns=since)
     assert not [e for e in events if e["kind"] == "serve_deliver"]
     for rid in rids:        # the last to finish was submitted 600 tokens ago
         mine = [e["kind"] for e in events if e["name"] == str(rid)]

@@ -30,12 +30,13 @@ SPANS = {
     "serve.admit": ("serve.step", {"admitted"}),
     "serve.prefill": ("serve.step", {"rid", "trace", "prompt_tokens",
                                      "chunks", "launches",
-                                     "cached_tokens"}),
+                                     "cached_tokens", "passes",
+                                     "kv_tokens"}),
     "serve.prefill.dispatch": ("serve.prefill", set()),
     "serve.prefill.to_host": ("serve.prefill", set()),
     "serve.prefill.sample": ("serve.prefill", set()),
     "serve.decode": ("serve.step", {"rows", "n_tok", "live_pages",
-                                    "table_pages", "rode"}),
+                                    "table_pages", "rode", "passes"}),
     "serve.decode.prep": ("serve.decode", set()),
     "serve.decode.dispatch": ("serve.decode", set()),
     "serve.decode.to_host": ("serve.decode", {"bytes"}),

@@ -800,3 +800,57 @@ class TestOnePartABlockProgramsLower:
         assert not _copies_of(text, "bf16[16,1856,2688]")
         assert telemetry.counters().get("kernel_fallback.total", 0) == before
 
+
+
+@pytest.mark.usefixtures("on_tpu")
+class TestLoopedProgramsLower:
+    """The serving programs of a model whose layers run several times a step
+    (Ouro-2.6B's widths: MHA 16 heads of 128, SwiGLU 5632; two layers run
+    twice, a small vocabulary), compiled for a described v5e."""
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        from paddle_tpu.models import OuroConfig, OuroForCausalLM
+        from paddle_tpu.serving import ServingEngine
+
+        paddle.seed(0)
+        model = OuroForCausalLM(OuroConfig(
+            vocab_size=1024, num_hidden_layers=2, total_ut_steps=2,
+            max_position_embeddings=4096))
+        model.eval()
+        model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+        return ServingEngine(model, max_batch=16, page_tokens=128,
+                             num_pages=9, max_pages_per_seq=20)
+
+    def test_decode_walks_each_layer_once_over_pages_of_every_pass(
+            self, engine, one_chip):
+        """One loop over the passes; the page walk a Mosaic call a layer (at
+        16 KV heads, a query group of one), not a layer and pass; every
+        pass's pages aliased in one arena a layer, and none copied."""
+        from paddle_tpu import telemetry
+        from paddle_tpu.jit import named_program
+        from paddle_tpu.serving.engine import DECODE_PROGRAM
+
+        eng = engine
+        pa, ba = eng._param_arrays()
+        R, MP = eng.max_batch, eng.max_pages_per_seq
+        args = (pa, ba, eng._arenas, jnp.zeros((R, 1), jnp.int32),
+                jnp.zeros((R,), jnp.int32), jnp.zeros((R, MP), jnp.int32),
+                jnp.ones((R,), jnp.int32))
+        args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), args)
+        before = telemetry.counters().get("kernel_fallback.total", 0)
+        compiled = _compile_uncached(
+            jax.jit(named_program(eng._decode_fn, DECODE_PROGRAM),
+                    donate_argnums=(2,)), *args)
+        text = compiled.as_text()
+        assert eng._arena_shape == (2 * 9, 128, 16, 128)
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            eng._arena_bytes == 2 * 2 * 18 * 128 * 16 * 128 * 2
+        assert _mosaic_calls(text, "paged_decode_attention") == 2
+        assert text.count(" while(") == 1
+        # none copied in HBM (what fits the chip's VMEM may be prefetched
+        # there: memory space S(1))
+        assert not [c for c in _copies_of(text, "bf16[18,128,16,128]")
+                    if "S(1)" not in c]
+        assert telemetry.counters().get("kernel_fallback.total", 0) == before
