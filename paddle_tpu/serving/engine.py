@@ -89,6 +89,7 @@ import numpy as np
 from ..distributed.checkpoint import faults as _faults
 from ..distributed.checkpoint.replicator import env_int as _env_int
 from ..distributed.fleet.fault_domain import _env_float
+from ..framework.jax_compat import default_layout, persistent_cache_off
 from ..jit import named_program
 from ..profiler import span as _span
 from ..telemetry import record_event as _event
@@ -863,7 +864,18 @@ class ServingEngine:
         self.rides_prefill_refusal: Optional[str] = refusal
         if refusal is not None:
             _event("serve_rides_prefill", refusal, rides_prefill=False)
-        self._idle_ride = None          # the decode part of a launch that
+        # the decode program, compiled before any other, chooses the layouts
+        # the weights lie in, and they move there once (:meth:`_compile`):
+        # a weight laid out otherwise is copied on every launch.  A mesh's
+        # programs are partitioned by GSPMD and keep the layouts as they
+        # are; so does an engine whose model already lies in layouts
+        # another engine's programs chose ("laid_out": moving them again
+        # would break that engine's programs).  Both are named here.
+        self.param_layout_refusal: Optional[str] = \
+            "mesh" if self._mesh is not None else None
+        if self._mesh is not None:
+            _event("serve_param_layouts", "mesh", relaid=0)
+        self._idle_ride = None         # the decode part of a launch that
         # carries no rows (:meth:`_ride_args`)
         self._ride_choice = None        # the riding launch's [R, S] choice
 
@@ -1712,10 +1724,6 @@ class ServingEngine:
                 logits, launches = self._prefill_chunks(prompt, table, c0,
                                                         r.row, ride)
             self.meter.prefill_launched(launches)
-            if self.rides_prefill and self._decode_exec is None:
-                # the row's next step runs the decode program: it compiles
-                # with the prefill widths, in the same cycle
-                self._decode_program(self._decode_args(*self._ride_args()))
         with _span("serve.prefill.to_host"):
             # the launches' notes come with the logits, and the riding
             # rows' choice: one sync point
@@ -2701,11 +2709,15 @@ class ServingEngine:
         return jax.device_put(x, NamedSharding(self._mesh,
                                                PartitionSpec()))
 
-    def _compile(self, fn, args, name: str):
+    def _compile(self, fn, args, name: str, *, choose_layouts: bool = False):
         """Lower and compile one engine program (arenas donated) as the
         module ``jit_<name>``.  Under a TP / CP mesh GSPMD partitions it
         over a mesh the kernel wrappers do not know, which the Pallas
-        dispatchers are told."""
+        dispatchers are told.  Every argument is taken in the layout it
+        lies in; with ``choose_layouts`` the compiler picks the layout of
+        each parameter of ``args[0]`` (``Layout.AUTO``) and the engine's
+        arrays move there (:meth:`_relay_params`): the span notes how many
+        moved (``relaid``) and their bytes (``relaid_bytes``)."""
         import contextlib
 
         import jax
@@ -2714,10 +2726,65 @@ class ServingEngine:
 
         scope = gspmd_program() if self._mesh is not None \
             else contextlib.nullcontext()
+        jit_kw = {"donate_argnums": (2,)}
+        pa = args[0]
+        if choose_layouts:
+            jit_kw["in_shardings"] = (self._param_formats(pa),) \
+                + (None,) * (len(args) - 1)
+            args = ([jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pa],) \
+                + tuple(args[1:])
         self._cycle.compiled = True
-        with _span("serve.compile", program=name), _SWAP_LOCK, scope:
-            return jax.jit(named_program(fn, name), donate_argnums=(2,)) \
+        with _span("serve.compile", program=name) as sp, _SWAP_LOCK, scope:
+            compiled = jax.jit(named_program(fn, name), **jit_kw) \
                 .lower(*args).compile()
+            if choose_layouts:
+                moved, nbytes = self._relay_params(
+                    pa, compiled.input_formats[0][0])
+                sp.note(relaid=moved, relaid_bytes=nbytes)
+                self.meter.params_relaid += moved
+                self.meter.params_relaid_bytes += nbytes
+            return compiled
+
+    @staticmethod
+    def _param_formats(pa):
+        """What a program that chooses the layouts asks of each parameter
+        of ``pa``: the compiler's choice, on the parameter's own
+        sharding."""
+        from jax.experimental.layout import Format, Layout
+
+        return [Format(Layout.AUTO, a.sharding) for a in pa]
+
+    def _relay_params(self, pa, formats):
+        """Move each parameter whose array lies otherwise into the layout
+        of ``formats`` (one a parameter of ``pa``, as a compiled program
+        takes them), one at a time: the new array replaces the old in the
+        ``Parameter`` and in ``pa``, so the old one is gone before the next
+        moves, and the weights are never held twice.  Entries that are no
+        arrays (a compile for a described chip) stay as they are.  Returns
+        the parameters moved and their bytes.  The moves compile afresh
+        (``persistent_cache_off``): a move loaded from the persistent cache
+        leaves its result where it was."""
+        import jax
+
+        moved = nbytes = 0
+        with persistent_cache_off():
+            for i, (p, fmt) in enumerate(zip(self._params, formats)):
+                old = pa[i]
+                # a parameter the program does not read has no layout there
+                if not isinstance(old, jax.Array) or fmt.layout is None or \
+                        old.format.layout == fmt.layout:
+                    continue
+                new = jax.device_put(old, fmt)
+                if new.format.layout != fmt.layout:
+                    raise RuntimeError(
+                        f"parameter {i} {old.shape} did not move into the "
+                        f"layout its program takes: {new.format.layout}, "
+                        f"not {fmt.layout}")
+                pa[i] = p._value = new
+                moved += 1
+                nbytes += old.nbytes
+                del old, new
+        return moved, nbytes
 
     def _decode_args(self, tokens, positions, tables, n_tok):
         pa, ba = self._param_arrays()
@@ -2727,11 +2794,18 @@ class ServingEngine:
 
     def _decode_program(self, args):
         """The decode program, compiled (and held to the donation gate) the
-        first time it is asked for."""
+        first time it is asked for.  It chooses the parameters' layouts
+        unless :attr:`param_layout_refusal` names why not; the parameters
+        in ``args`` are then the moved ones."""
         if self._decode_exec is None:
             self._decode_compiles += 1
-            self._decode_exec = self._compile(self._decode_fn, args,
-                                               DECODE_PROGRAM)
+            if self.param_layout_refusal is None and not all(
+                    default_layout(p._value) for p in self._params):
+                self.param_layout_refusal = "laid_out"
+                _event("serve_param_layouts", "laid_out", relaid=0)
+            self._decode_exec = self._compile(
+                self._decode_fn, args, DECODE_PROGRAM,
+                choose_layouts=self.param_layout_refusal is None)
             if self._lint:
                 self.lint_report = check_decode_donation(
                     self._decode_exec, self._arena_bytes,
@@ -2760,22 +2834,32 @@ class ServingEngine:
             return tuple(self._repl(jnp.asarray(a))
                          for a in (tokens, positions, tables, n_tok))
         if self._idle_ride is None:
-            R, S = self.max_batch, self._spec_width
-            self._idle_ride = tuple(self._repl(a) for a in (
-                jnp.zeros((R, S), jnp.int32), jnp.zeros((R,), jnp.int32),
-                jnp.full((R, self.max_pages_per_seq), TRASH_PAGE, jnp.int32),
-                jnp.zeros((R,), jnp.int32)))
+            self._idle_ride = self._idle_rows()
         return self._idle_ride
+
+    def _idle_rows(self):
+        """The decode program's four inputs with every row idle: tokens,
+        positions, tables (the trash page) and ``n_tok`` (0)."""
+        import jax.numpy as jnp
+
+        R, S = self.max_batch, self._spec_width
+        return tuple(self._repl(a) for a in (
+            jnp.zeros((R, S), jnp.int32), jnp.zeros((R,), jnp.int32),
+            jnp.full((R, self.max_pages_per_seq), TRASH_PAGE, jnp.int32),
+            jnp.zeros((R,), jnp.int32)))
 
     def _run_prefill(self, tokens, chunk_start, tables, take_idx, row,
                      n_valid, ride=None):
         """Launch the prefill program of ``tokens``' width.  The first
-        launch compiles every width of the ladder, so none compiles once
+        launch compiles the decode program, which chooses the weights'
+        layouts, then every width of the ladder, so none compiles once
         requests are being served.  A launch of the width that carries a
         decode part (:meth:`_carries_rows`) carries ``ride``'s rows, or
         idle slots; one that carried rows leaves their choice for the
         step's one fetch and their logits where the decode program's would
         be."""
+        if self._decode_exec is None:
+            self._decode_program(self._decode_args(*self._idle_rows()))
         pa, ba = self._param_arrays()
         args = (pa, ba, self._arenas, self._repl(tokens),
                 self._repl(chunk_start), self._repl(tables),

@@ -204,6 +204,9 @@ class SLOMeter:
         self.kv_bytes_per_token: Optional[float] = None
         self.passes = 1     # times the engine walks the model's layers a
         # step (a looped model's passes)
+        self.params_relaid = 0          # parameters the engine moved, once,
+        self.params_relaid_bytes = 0    # into the layouts its decode
+        # program chose (``ServingEngine.param_layout_refusal``)
         # host-RAM KV offload tier (long-context ladder): swap traffic in
         # pages and bytes, plus the token denominator the recall-MBU
         # gauge divides by (replays excluded — recall exists precisely so
@@ -633,6 +636,8 @@ class SLOMeter:
                 if self.spec_verify_steps else None),
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "passes": self.passes,
+            "params_relaid": self.params_relaid,
+            "params_relaid_bytes": self.params_relaid_bytes,
             "kv_offloads": self.offloads_total,
             "kv_recalls": self.recalls_total,
             "kv_offload_stalls": self.offload_stalls_total,

@@ -224,19 +224,21 @@ def test_the_engine_compiles_the_decode_program_and_one_a_width(llama):
     eng = engine(llama)
     compiled, compile_ = [], eng._compile
 
-    def spy(fn, args, name):
+    def spy(fn, args, name, **kw):
         compiled.append((name, args[3].shape, len(args)))
-        return compile_(fn, args, name)
+        return compile_(fn, args, name, **kw)
 
     eng._compile = spy
     stream(eng)
     ladder = eng._prefill_widths
     assert ladder == PREFILL_WIDTHS
-    # the narrowest width takes the decode part as one more argument; the
-    # wider ones are the plain program
-    assert compiled == [(serving_engine.PREFILL_PROGRAM, (1, w * P),
-                         9 + (w == ladder[0])) for w in ladder] + \
-        [(serving_engine.DECODE_PROGRAM, (KNOBS["max_batch"], 1), 7)]
+    # the decode program first (it chooses the weights' layouts); the
+    # narrowest width takes the decode part as one more argument, the wider
+    # ones are the plain program
+    assert compiled == \
+        [(serving_engine.DECODE_PROGRAM, (KNOBS["max_batch"], 1), 7)] + \
+        [(serving_engine.PREFILL_PROGRAM, (1, w * P), 9 + (w == ladder[0]))
+         for w in ladder]
     assert [eng._carries_rows(w) for w in ladder] == \
         [w == ladder[0] for w in ladder]
     assert eng._decode_compiles == 1 and sorted(eng._prefill_exec) == \

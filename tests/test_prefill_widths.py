@@ -258,8 +258,8 @@ def test_every_width_compiles_once_on_the_first_prefill(which, request):
     eng = engine(request.getfixturevalue(which))
     compiled, compile_ = [], eng._compile
 
-    def spy(fn, args, name):
-        exe = compile_(fn, args, name)
+    def spy(fn, args, name, **kw):
+        exe = compile_(fn, args, name, **kw)
         compiled.append((name, args[3].shape, exe))
         return exe
 
@@ -267,13 +267,12 @@ def test_every_width_compiles_once_on_the_first_prefill(which, request):
     eng.submit(prompt(3), max_new_tokens=1)
     eng.run()                   # one page, one token: prefill only
     ladder = eng._prefill_widths
-    # where the rows ride the launches, the decode program compiles with
-    # the widths: a prompt's row runs it from the step after its prefill
+    # the decode program compiles first, whether or not the rows ride the
+    # launches (it chooses the weights' layouts), then every width
     assert eng.rides_prefill == (which == "llama")
     assert [(name, shape) for name, shape, _ in compiled] == \
-        [(serving_engine.PREFILL_PROGRAM, (1, w * P)) for w in ladder] + \
-        [(serving_engine.DECODE_PROGRAM, (KNOBS["max_batch"], 1))] \
-        * eng.rides_prefill
+        [(serving_engine.DECODE_PROGRAM, (KNOBS["max_batch"], 1))] + \
+        [(serving_engine.PREFILL_PROGRAM, (1, w * P)) for w in ladder]
     assert sorted(eng._prefill_exec) == list(ladder)
     for name, _, exe in compiled:
         assert exe.as_text().splitlines()[0].split()[1].rstrip(",") == \
@@ -282,8 +281,8 @@ def test_every_width_compiles_once_on_the_first_prefill(which, request):
         eng.submit(prompt(n, 9), max_new_tokens=3)
     eng.run()
     assert [name for name, _, _ in compiled] == \
-        [serving_engine.PREFILL_PROGRAM] * len(ladder) \
-        + [serving_engine.DECODE_PROGRAM]
+        [serving_engine.DECODE_PROGRAM] \
+        + [serving_engine.PREFILL_PROGRAM] * len(ladder)
     assert len(ladder) <= len(PREFILL_WIDTHS)
 
 

@@ -304,10 +304,17 @@ def test_prefill_span_carries_the_requests_trace_id(recorded):
 
 
 def test_compile_span_says_which_program_compiled(recorded):
-    programs = [s[2]["program"] for s in recorded["spans"]["serve.compile"]]
-    # the prefill program once a width, all before the decode program
-    assert programs == [serving_engine.PREFILL_PROGRAM] * len(
-        serving_engine.PREFILL_WIDTHS) + [serving_engine.DECODE_PROGRAM]
+    spans = recorded["spans"]["serve.compile"]
+    programs = [s[2]["program"] for s in spans]
+    # the decode program first, which chooses the weights' layouts, then
+    # the prefill program once a width
+    assert programs == [serving_engine.DECODE_PROGRAM] + \
+        [serving_engine.PREFILL_PROGRAM] * len(serving_engine.PREFILL_WIDTHS)
+    # how many weights moved into them, and their bytes: the choosing
+    # program's span alone says (none move on the CPU, where the compiler
+    # keeps every weight as it lies)
+    assert [(s[2].get("relaid"), s[2].get("relaid_bytes")) for s in spans] \
+        == [(0, 0)] + [(None, None)] * len(serving_engine.PREFILL_WIDTHS)
 
 
 def test_train_span_counts_and_program(recorded):
@@ -390,8 +397,8 @@ def test_the_engine_compiles_under_those_names(engine_model, monkeypatch):
                         num_pages=16, max_pages_per_seq=4)
     eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=2)
     eng.run()
-    assert seen == [serving_engine.PREFILL_PROGRAM] * len(
-        serving_engine.PREFILL_WIDTHS) + [serving_engine.DECODE_PROGRAM]
+    assert seen == [serving_engine.DECODE_PROGRAM] + \
+        [serving_engine.PREFILL_PROGRAM] * len(serving_engine.PREFILL_WIDTHS)
 
 
 @pytest.mark.parametrize("variant, name", [

@@ -25,8 +25,8 @@ def _record(model, tmp_path_factory, **knobs):
                         max_pages_per_seq=8, **knobs)
     compiled = []
     compile_ = eng._compile
-    eng._compile = lambda fn, args, name: (
-        compiled.append(name), compile_(fn, args, name))[1]
+    eng._compile = lambda fn, args, name, **kw: (
+        compiled.append(name), compile_(fn, args, name, **kw))[1]
     rng = np.random.default_rng(0)
     out = str(tmp_path_factory.mktemp("xplane"))
     jax.profiler.start_trace(out)
@@ -94,8 +94,8 @@ def test_a_model_without_state_layers_reports_none(llama):
 @pytest.mark.parametrize("which", ["hybrid", "llama"])
 def test_both_programs_compile_once_under_their_names(which, request):
     eng, _, compiled = request.getfixturevalue(which)
-    assert compiled == [serving_engine.PREFILL_PROGRAM] * len(
-        serving_engine.PREFILL_WIDTHS) + [serving_engine.DECODE_PROGRAM]
+    assert compiled == [serving_engine.DECODE_PROGRAM] + \
+        [serving_engine.PREFILL_PROGRAM] * len(serving_engine.PREFILL_WIDTHS)
     assert (serving_engine.DECODE_PROGRAM, serving_engine.PREFILL_PROGRAM) \
         == ("serve_decode_fn", "serve_prefill_fn")
     assert eng._decode_compiles == 1

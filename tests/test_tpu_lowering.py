@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.framework.jax_compat import persistent_cache_off
 from paddle_tpu.ops.pallas import (decode_attention,
                                    decode_attention_supported,
                                    flash_attention, flash_attention_supported,
@@ -76,22 +77,33 @@ def sds(*shape, dtype=BF16):
 def _compile_uncached(jitted, *args):
     """Compile for the described chip with the persistent cache off (what
     is compiled here for a TPU cannot be read back without one)."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with persistent_cache_off():
         return jitted.lower(*args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
 
 
 def _copies_of(text: str, shape: str):
     return [line.strip()[:160] for line in text.splitlines()
             if shape in line.split(" = ", 1)[-1][:len(shape) + 2]
             and (" copy(" in line or " copy-start(" in line)]
+
+
+def _decode_sds(eng, sharding):
+    """The decode program's arguments as the engine passes them, described
+    on ``sharding``."""
+    pa, ba = eng._param_arrays()
+    R, MP = eng.max_batch, eng.max_pages_per_seq
+    args = (pa, ba, eng._arenas, jnp.zeros((R, 1), jnp.int32),
+            jnp.zeros((R,), jnp.int32), jnp.zeros((R, MP), jnp.int32),
+            jnp.ones((R,), jnp.int32))
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), args)
+
+
+def _engine_decode(eng, sharding):
+    """The engine's decode program as ``_decode_program`` builds it (the
+    program that chooses the weights' layouts), for the described chip."""
+    with persistent_cache_off():
+        return eng._decode_program(_decode_sds(eng, sharding))
 
 
 # the page-walking decode kernel at the benchmark's serving cells (Mistral:
@@ -854,3 +866,76 @@ class TestLoopedProgramsLower:
         assert not [c for c in _copies_of(text, "bf16[18,128,16,128]")
                     if "S(1)" not in c]
         assert telemetry.counters().get("kernel_fallback.total", 0) == before
+
+    def test_decode_takes_q_k_v_as_it_reads_them(self, engine, one_chip):
+        """Built as the engine builds it, the decode program chooses the
+        weights' layouts: no q / k / v matrix is copied on a launch (at the
+        default layouts, six ``bf16[2048,2048]`` copies a step ahead of the
+        loop over the passes, and 54 MB of temporaries)."""
+        eng = engine
+        assert eng.param_layout_refusal is None
+        compiled = _engine_decode(eng, one_chip)
+        assert not [c for c in _copies_of(compiled.as_text(),
+                                          "bf16[2048,2048]")
+                    if "S(1)" not in c]
+        assert compiled.memory_analysis().temp_size_in_bytes < 10 * 2 ** 20
+
+
+@pytest.mark.usefixtures("on_tpu")
+class TestWeightLayoutsLower:
+    """The serving programs of an engine at Mistral-7B's widths (hidden
+    4096, GQA 32/8 heads of 128, SwiGLU 14336; two layers, a small
+    vocabulary), compiled for a described v5e as the engine builds them: the
+    decode program chooses the weights' layouts, and both prefill widths —
+    the riding one and the plain one — take the weights in them.  At the
+    default layouts every one of them copied q (``bf16[4096,4096]``) and k
+    and v (``bf16[1024,4096]``) on each launch."""
+
+    @pytest.fixture(scope="class")
+    def engine(self):
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.serving import ServingEngine
+
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=1024, hidden_size=4096, intermediate_size=14336,
+            num_hidden_layers=2, num_attention_heads=32,
+            num_key_value_heads=8, max_position_embeddings=4096,
+            recompute=False))
+        model.eval()
+        model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+        return ServingEngine(model, max_batch=64, page_tokens=128,
+                             num_pages=65, max_pages_per_seq=4)
+
+    def test_no_program_copies_a_q_k_or_v_matrix(self, engine, one_chip):
+        from paddle_tpu.serving.engine import PREFILL_PROGRAM
+
+        eng = engine
+        R, MP, P = eng.max_batch, eng.max_pages_per_seq, eng.page_tokens
+        decode = _engine_decode(eng, one_chip)
+        # the weights as the engine's arrays lie once moved
+        pa = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=f) for a, f in
+              zip(eng._param_arrays()[0], decode.input_formats[0][0])]
+
+        def prefill(width):
+            rest = (eng._param_arrays()[1], eng._arenas,
+                    jnp.zeros((1, width * P), jnp.int32), jnp.int32(0),
+                    jnp.zeros((1, MP), jnp.int32), jnp.int32(P - 1),
+                    jnp.int32(0), jnp.int32(P - 3))
+            if eng._carries_rows(width):
+                rest += ((jnp.zeros((R, 1), jnp.int32),
+                          jnp.zeros((R,), jnp.int32),
+                          jnp.zeros((R, MP), jnp.int32),
+                          jnp.ones((R,), jnp.int32)),)
+            rest = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip), rest)
+            with persistent_cache_off():
+                return eng._compile(eng._prefill_fn, (pa,) + rest,
+                                    PREFILL_PROGRAM)
+
+        assert eng.rides_prefill and eng._prefill_widths == (1, 4)
+        assert eng._carries_rows(1) and not eng._carries_rows(4)
+        for compiled in (decode, prefill(1), prefill(4)):
+            text = compiled.as_text()
+            assert not _copies_of(text, "bf16[4096,4096]")
+            assert not _copies_of(text, "bf16[1024,4096]")
